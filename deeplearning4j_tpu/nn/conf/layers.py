@@ -526,11 +526,7 @@ class Subsampling(LayerConfig):
         strides = (1, sh, sw, 1)
         pad = self.padding.upper()
         if self.pooling is PoolingType.MAX:
-            from deeplearning4j_tpu.runtime.backend import maxpool_fusion_barrier
-
-            y = lax.reduce_window(
-                maxpool_fusion_barrier(x), -jnp.inf, lax.max, dims, strides, pad
-            )
+            y = lax.reduce_window(x, -jnp.inf, lax.max, dims, strides, pad)
         elif self.pooling is PoolingType.SUM:
             y = lax.reduce_window(x, 0.0, lax.add, dims, strides, pad)
         elif self.pooling is PoolingType.AVG:

@@ -144,11 +144,7 @@ def _pool_nd(x, kind: PoolingType, window, strides, padding: str,
     strd = (1, *strides, 1)
     pad = padding.upper()
     if kind == PoolingType.MAX:
-        from deeplearning4j_tpu.runtime.backend import maxpool_fusion_barrier
-
-        return lax.reduce_window(
-            maxpool_fusion_barrier(x), -jnp.inf, lax.max, dims, strd, pad
-        )
+        return lax.reduce_window(x, -jnp.inf, lax.max, dims, strd, pad)
     if kind == PoolingType.SUM:
         return lax.reduce_window(x, 0.0, lax.add, dims, strd, pad)
     if kind == PoolingType.AVG:

@@ -18,11 +18,11 @@ measures what that time bought.  Three pieces:
   ``lower().compile().memory_analysis()`` adds peak/argument/output
   bytes but costs a real XLA compile (AOT executables don't share the
   jit dispatch cache), so it sits behind ``memory=True``.  Every field
-  is guarded — jax 0.4.37 on CPU omits several — and an analysis
+  is guarded — the CPU backend omits several — and an analysis
   failure is recorded as a reason, never raised into training.
 - **MFU / roofline accounting**: once a program's FLOPs are known, every
-  `StepScope` exit derives achieved FLOP/s, MFU against a per-backend
-  peak table (`DL4J_TPU_PEAK_FLOPS` / `DL4J_TPU_PEAK_MEMBW` override),
+  `StepScope` exit derives achieved FLOP/s, MFU against the per-device
+  peak table (`PEAKS_BY_DEVICE_KIND`; an unlisted accelerator raises),
   bytes/s against peak HBM bandwidth, and a compute- vs memory-bound
   classification (arithmetic intensity vs the machine's ridge point) —
   pushed to the ``dl4jtpu_step_*`` gauges and stamped onto the
@@ -40,7 +40,6 @@ analysis is requested; until then the gauges simply stay unset.
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 import weakref
@@ -50,11 +49,16 @@ log = logging.getLogger("deeplearning4j_tpu")
 
 # -- per-backend peak table -------------------------------------------------
 #
-# (dense peak FLOP/s, peak HBM bytes/s) PER DEVICE.  TPU numbers are the
-# published bf16 peaks; the CPU row is a deliberately rough nominal
-# (one modern x86 core's f32 FMA throughput) so CPU MFU reads as an
-# indicative ratio, not a hardware claim — override with
-# DL4J_TPU_PEAK_FLOPS / DL4J_TPU_PEAK_MEMBW (per-device values).
+# (dense bf16 peak FLOP/s, peak HBM bytes/s) PER DEVICE, keyed by the
+# `device_kind` string jax reports — the ONE peaks table (bench.py reads
+# it too).  Source: Google Cloud TPU documentation, the "System
+# architecture" page of each generation (v5e: 197 TFLOP/s bf16, 819 GB/s;
+# jax reports a v5e chip as "TPU v5 lite", a v6e chip as "TPU v6 lite").
+# The "cpu" row is a deliberately rough nominal (one modern x86 core's
+# f32 FMA throughput) so CPU test runs read as an indicative ratio, not
+# a hardware claim.  A device that is not in the table is an error
+# (`UnknownDeviceKind`), never a default: MFU against the wrong peak is
+# a wrong number with a right-looking name.
 PEAKS_BY_DEVICE_KIND = {
     "TPU v2": (45.0e12, 7.0e11),
     "TPU v3": (123.0e12, 9.0e11),
@@ -62,51 +66,33 @@ PEAKS_BY_DEVICE_KIND = {
     "TPU v5 lite": (197.0e12, 8.19e11),
     "TPU v5e": (197.0e12, 8.19e11),
     "TPU v5p": (459.0e12, 2.765e12),
+    "TPU v6 lite": (918.0e12, 1.64e12),
     "cpu": (1.0e11, 5.0e10),
 }
 
-_peaks_lock = threading.Lock()
-_peaks_cache: dict = {}
+
+class UnknownDeviceKind(LookupError):
+    """The local device's `device_kind` has no row in
+    `PEAKS_BY_DEVICE_KIND` — add its datasheet peaks there."""
 
 
-def peaks(refresh: bool = False) -> tuple[float, float]:
-    """(peak FLOP/s, peak bytes/s) for THIS process's local devices:
-    per-device peak (env override first, then the device-kind table,
-    then the CPU nominal) times jax.local_device_count().  Cached per
-    (kind, count, env) — refresh=True re-reads."""
+def peaks() -> tuple[float, float]:
+    """(peak FLOP/s, peak bytes/s) for THIS process's local devices: the
+    device-kind table's per-device row times jax.local_device_count().
+    Raises `UnknownDeviceKind` for a device the table does not list."""
     import jax
 
     devs = jax.local_devices()
     kind = str(getattr(devs[0], "device_kind", devs[0].platform))
-    env_f = os.environ.get("DL4J_TPU_PEAK_FLOPS")
-    env_b = os.environ.get("DL4J_TPU_PEAK_MEMBW")
-    key = (kind, len(devs), env_f, env_b)
-    with _peaks_lock:
-        if not refresh and key in _peaks_cache:
-            return _peaks_cache[key]
-    if kind in PEAKS_BY_DEVICE_KIND:
+    try:
         flops, membw = PEAKS_BY_DEVICE_KIND[kind]
-    else:
-        # unknown accelerator: the CPU nominal would make MFU read
-        # ~1000x wrong on a real chip — say so loudly, once per kind
-        flops, membw = PEAKS_BY_DEVICE_KIND["cpu"]
-        with _peaks_lock:
-            if ("warned", kind) not in _peaks_cache:
-                _peaks_cache[("warned", kind)] = True
-                log.warning(
-                    "device kind %r is not in cost.PEAKS_BY_DEVICE_KIND;"
-                    " MFU/roofline will use the CPU nominal peaks — set "
-                    "DL4J_TPU_PEAK_FLOPS / DL4J_TPU_PEAK_MEMBW to this "
-                    "part's datasheet numbers", kind,
-                )
-    if env_f:
-        flops = float(env_f)
-    if env_b:
-        membw = float(env_b)
-    out = (flops * len(devs), membw * len(devs))
-    with _peaks_lock:
-        _peaks_cache[key] = out
-    return out
+    except KeyError:
+        raise UnknownDeviceKind(
+            f"device kind {kind!r} is not in cost.PEAKS_BY_DEVICE_KIND; "
+            "add this part's datasheet peaks (with their source) to the "
+            "table"
+        ) from None
+    return flops * len(devs), membw * len(devs)
 
 
 def _key_repr(key: Any) -> str:
@@ -346,13 +332,7 @@ class ProgramRecord:
         ai = self.arithmetic_intensity()
         if ai is None:
             return None
-        try:
-            pk_f, pk_b = peaks()
-        except Exception as e:             # backend not initializable
-            log.debug("peak lookup failed: %s", e)
-            return None
-        if not pk_b:
-            return None
+        pk_f, pk_b = peaks()
         return "compute-bound" if ai >= pk_f / pk_b else "memory-bound"
 
     def as_dict(self) -> dict:
@@ -569,7 +549,7 @@ def analyze_signature(fn, sig) -> SignatureAnalysis:
     program`` product — its ``__wrapped__`` jitted inner is used), a
     raw jitted function, or anything exposing ``.lower``.
 
-    Failures (jax 0.4.37/CPU omissions, untraceable signatures) come
+    Failures (CPU-backend omissions, untraceable signatures) come
     back as a reason string on the result — the planner records them as
     per-candidate rejection reasons instead of pricing garbage."""
     import warnings
@@ -677,18 +657,12 @@ def note_step(rec: ProgramRecord, dur: float, span_args: dict,
         return
     ach = work / dur
     achieved.set(ach)
-    try:
-        pk_f, pk_b = peaks()
-    except Exception as e:
-        log.debug("peak lookup failed: %s", e)
-        return
-    if pk_f:
-        mfu.set(ach / pk_f)
+    pk_f, pk_b = peaks()
+    mfu.set(ach / pk_f)
     if rec.bytes_accessed:
         bps = rec.bytes_accessed * n / dur
         bytes_ps.set(bps)
-        if pk_b:
-            membw.set(bps / pk_b)
+        membw.set(bps / pk_b)
     cls = rec.roofline()
     if cls:
         span_args["roofline"] = cls

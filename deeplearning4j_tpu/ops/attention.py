@@ -22,15 +22,52 @@ shard_map/pjit with the named `axis` present in the mesh.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.runtime.mesh import axis_size
 
 
 def _scale(d: int) -> float:
     return 1.0 / (d**0.5)
+
+
+def _flash_per_shard(flash, q, k, v):
+    """Run the flash kernel on each device's own shard when the step is
+    traced under a >1-device mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned" — found on four real chips; interpret-mode
+    CPU runs lower to plain XLA ops and never hit it), so every mesh axis
+    that is still GSPMD-auto here goes manual around the call: batch
+    split over "data" and heads over "model" where they divide (attention
+    is independent per example and per head), replicated over any other
+    axis.  Axes an enclosing shard_map already made manual (a pipeline
+    stage, ulysses' "seq") are left alone."""
+    from jax.sharding import PartitionSpec as P
+
+    from deeplearning4j_tpu.runtime.mesh import (
+        DATA_AXIS, MODEL_AXIS, active_mesh, shard_map,
+    )
+
+    mesh = active_mesh()
+    if mesh is None:
+        return flash(q, k, v)
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    auto = {a for a in mesh.axis_names
+            if mesh.shape[a] > 1 and a not in manual}
+    if not auto:
+        return flash(q, k, v)
+
+    def over(axis, dim):
+        return axis if axis in auto and dim % mesh.shape[axis] == 0 else None
+
+    spec = P(over(DATA_AXIS, q.shape[0]), None, over(MODEL_AXIS, q.shape[2]),
+             None)
+    return shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, axis_names=auto, check_vma=False)(q, k, v)
 
 
 def mha(
@@ -66,8 +103,10 @@ def mha(
         if flash_eligible(q, k, mask):
             from deeplearning4j_tpu.runtime.backend import backend
 
-            return flash_attention(
-                q, k, v, causal=causal, interpret=not backend().is_tpu
+            return _flash_per_shard(
+                functools.partial(flash_attention, causal=causal,
+                                  interpret=not backend().is_tpu),
+                q, k, v,
             )
     d = q.shape[-1]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * _scale(d)
@@ -109,7 +148,7 @@ def ring_attention(
     an inner scan — peak logits memory is O(B*H*T_local*block) instead of
     O(B*H*T_local*T_local).  block_size=None disables inner chunking.
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     b, t_local, h, d = q.shape
     scale = _scale(d)
@@ -174,14 +213,9 @@ def ring_attention(
 
     # the accumulators depend on this rank's q, so they VARY over the manual
     # axis — scan requires carry in/out types (incl. vma) to match
-    if hasattr(lax, "pcast"):
-        _vary = lambda x: lax.pcast(x, (axis,), to="varying")
-    elif hasattr(lax, "pvary"):
-        _vary = lambda x: lax.pvary(x, (axis,))
-    else:
-        # 0.4.x shard_map has no varying-manual-axes typing at all
-        # (check_rep=False is the only mode we run): nothing to cast
-        _vary = lambda x: x
+    def _vary(x):
+        return lax.pcast(x, (axis,), to="varying")
+
     o0 = _vary(jnp.zeros((b, h, t_local, d), jnp.float32))
     m0 = _vary(jnp.full((b, h, t_local), -jnp.inf, jnp.float32))
     l0 = _vary(jnp.zeros((b, h, t_local), jnp.float32))
@@ -209,7 +243,7 @@ def ulysses_attention(
     q,k,v local: (B, T_local, H, D) -> returns (B, T_local, H, D).
     mask: local (B, T_local) keep-mask (all-gathered internally).
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     h = q.shape[2]
     if h % n:
         raise ValueError(f"ulysses needs heads ({h}) divisible by axis size ({n})")

@@ -432,15 +432,20 @@ def flash_autotune(*, seq_len: int, n_heads: int, head_dim: int,
     jit) on the current default device and cache the winner; later
     flash_attention() calls with the same (Tq, Tk, D, causal) pick it up
     statically at trace time.  Call once before building a model (bench.py
-    does for the long-context config).  Returns the winning (bq, bk)."""
+    does for the long-context config).  Returns the winning (bq, bk).  A
+    candidate the compiler refuses is logged and skipped; when NONE
+    compiles this raises — there is no silent default."""
+    import logging
     import time as _time
 
+    log = logging.getLogger("deeplearning4j_tpu")
     t = seq_len
     bh = batch * n_heads
     d = head_dim
     key = jax.random.key(0)
     q = jax.random.normal(key, (bh, t, d), jnp.float32)
     best = None
+    refused = []
     for bq, bk in candidates:
         if t % min(bq, t) or t % min(bk, t):
             continue
@@ -453,17 +458,25 @@ def flash_autotune(*, seq_len: int, n_heads: int, head_dim: int,
             f = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
             g = f(q, q, q)
             float(jnp.sum(g[0]))            # compile + sync
-            t0 = _time.perf_counter()
-            for _ in range(reps):
-                g = f(q, q, q)
-            float(jnp.sum(g[0]))
-            dt = _time.perf_counter() - t0
-        except Exception:
+        except Exception as exc:    # the compiler refusing a block shape
+            # (VMEM, layout) disqualifies that candidate, not the search
+            log.warning("flash_autotune: blocks (%d, %d) refused: %s: %s",
+                        bq, bk, type(exc).__name__, exc)
+            refused.append((bq, bk))
             continue
+        t0 = _time.perf_counter()
+        for _ in range(reps):
+            g = f(q, q, q)
+        float(jnp.sum(g[0]))
+        dt = _time.perf_counter() - t0
         if best is None or dt < best[0]:
             best = (dt, (min(bq, t), min(bk, t)))
     if best is None:
-        return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
+        raise RuntimeError(
+            f"flash_autotune: no candidate block shape compiled and ran for "
+            f"T={t}, D={d} (refused: {refused}) — the kernel cannot serve "
+            "this shape on this device"
+        )
     _BLOCK_CACHE[(t, t, d, causal)] = best[1]
     return best[1]
 

@@ -17,8 +17,10 @@ one dispatch, mirroring ``ops/dequant_matmul.py``:
   table rides PrefetchScalarGridSpec so each grid step DMAs ONE pool
   page into VMEM (HBM never sees a gathered dense copy), and the
   softmax is accumulated online (running max / normalizer / weighted
-  sum in VMEM scratch) across a slot's pages.  CPU tier-1 runs the
-  SAME kernel with ``interpret=True``.
+  sum in VMEM scratch) across a slot's pages.  The body is VPU-only
+  (one query row per head is a matrix-vector product — nothing for the
+  MXU to do).  CPU tier-1 runs the SAME kernel with ``interpret=True``;
+  ``tests/test_tpu_lowering.py`` holds it to Mosaic's TPU lowering.
 - ``pallas_int8`` — the fused int8-KV variant: pages are int8 with
   per-page scale blocks (``serving/kv_cache.py``'s layout); the kernel
   dequantizes each page IN VMEM (HBM reads ~1 byte per KV element) and
@@ -118,15 +120,39 @@ def _xla_paged_attention(q, k_pages, v_pages, page_tbl, seq_lens,
 
 # -- pallas (TPU; interpret on CPU) ----------------------------------------
 
-def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref, *, page_size: int, n_pages: int,
-               quant: bool, ks_ref=None, vs_ref=None):
+def _scale_col(row, eye):
+    """(1, H) lane-major scale row -> (H, 1) sublane-major column, the
+    orientation of the per-head score/weight columns below.  Written as
+    mask-and-lane-reduce (broadcast the row down the sublanes, keep the
+    (H, H) diagonal `eye`) because Mosaic has no relayout that moves a
+    lane axis onto sublanes for an (8-wide) unaligned shape."""
+    return jnp.sum(jnp.where(eye, jnp.broadcast_to(row, eye.shape), 0.0),
+                   axis=-1, keepdims=True)
+
+
+def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+               page_size: int, n_pages: int, quant: bool):
     """Grid (slots, pages), pages innermost (sequential): online-softmax
     accumulation of one slot's query row over its page-table-indexed
     pages.  Scalar-prefetched ``tbl_ref``/``len_ref`` drive the page
-    DMAs via the BlockSpec index maps; this body only needs the mask."""
+    DMAs via the BlockSpec index maps; this body only needs the mask.
+
+    One query row per head makes the score a matrix-VECTOR product, so
+    the body stays on the VPU: every page row ``p`` is one (H, Dh) tile
+    (heads on sublanes, head_dim on lanes — the pool's own layout, no
+    transpose), its score column is ``sum(q * k_p, lanes)`` -> (H, 1),
+    and the weighted sum broadcasts that column back over the lanes.
+    The loop over the page's rows is unrolled (``page_size`` is static
+    and small), which keeps every value a whole (H, Dh) or (H, 1) tile —
+    the batched ``hd,phd->hp`` einsum this replaces has no Mosaic
+    lowering (no lhs free dimension, batch dimension mid-rhs)."""
+    if quant:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
     s = pl.program_id(0)
     j = pl.program_id(1)
+    h, dh = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -134,27 +160,40 @@ def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)              # (H, Dh)
-    k = k_ref[0].astype(jnp.float32)              # (ps, H, Dh)
-    v = v_ref[0].astype(jnp.float32)
-    if quant:
-        k = k * ks_ref[0].astype(jnp.float32)[..., None]
-        v = v * vs_ref[0].astype(jnp.float32)[..., None]
-    dh = q.shape[-1]
-    # (H, ps) scores for this page
-    scores = jnp.einsum("hd,phd->hp", q, k) / np.sqrt(dh)
+    length = len_ref[s]
     base = j * page_size
-    pos = base + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1
-    )                                             # (1, ps)
-    scores = jnp.where(pos < len_ref[s], scores, _MASK)
-    m_prev = m_ref[...]                           # (H, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)                   # (H, ps); masked -> 0.0
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.einsum("hp,phd->hd", p, v)
-    m_ref[...] = m_new
+
+    # pages wholly past seq_len (the table's scratch-page tail, or every
+    # page of an idle slot) contribute exact zeros: skip their compute
+    @pl.when(base < length)
+    def _page():
+        q = q_ref[0].astype(jnp.float32) * (1.0 / np.sqrt(dh))   # (H, Dh)
+        if quant:
+            eye = (jax.lax.broadcasted_iota(jnp.int32, (h, h), 0)
+                   == jax.lax.broadcasted_iota(jnp.int32, (h, h), 1))
+        scores = []
+        for p in range(page_size):
+            sc = jnp.sum(q * k_ref[0, p].astype(jnp.float32),
+                         axis=-1, keepdims=True)                 # (H, 1)
+            if quant:
+                # the row scale commutes with the contraction over Dh
+                sc = sc * _scale_col(ks_ref[0, pl.ds(p, 1), :], eye)
+            pos = jnp.full((h, 1), base + p, jnp.int32)
+            scores.append(jnp.where(pos < length, sc, _MASK))
+        m_prev = m_ref[...]                                      # (H, 1)
+        m_new = functools.reduce(jnp.maximum, scores, m_prev)
+        alpha = jnp.exp(m_prev - m_new)
+        ell = l_ref[...] * alpha
+        acc = acc_ref[...] * alpha                               # (H, Dh)
+        for p in range(page_size):
+            w = jnp.exp(scores[p] - m_new)        # (H, 1); masked -> 0.0
+            ell = ell + w
+            if quant:
+                w = w * _scale_col(vs_ref[0, pl.ds(p, 1), :], eye)
+            acc = acc + w * v_ref[0, p].astype(jnp.float32)
+        m_ref[...] = m_new
+        l_ref[...] = ell
+        acc_ref[...] = acc
 
     @pl.when(j == n_pages - 1)
     def _done():
@@ -170,9 +209,6 @@ def _pallas_paged_attention(q, k_pages, v_pages, page_tbl, seq_lens,
     n_pages = page_tbl.shape[1]
     page_size = k_pages.shape[1]
     quant = k_scale is not None
-    kernel = functools.partial(
-        _pa_kernel, page_size=page_size, n_pages=n_pages, quant=quant,
-    )
     # page blocks are selected by the scalar-prefetched table: grid step
     # (s, j) DMAs pool page page_tbl[s, j] — the gather never exists in
     # HBM
@@ -182,45 +218,35 @@ def _pallas_paged_attention(q, k_pages, v_pages, page_tbl, seq_lens,
     scale_spec = pl.BlockSpec(
         (1, page_size, h), lambda s_, j, tbl, lens: (tbl[s_, j], 0, 0),
     )
-    in_specs = [
-        pl.BlockSpec((1, h, dh), lambda s_, j, tbl, lens: (s_, 0, 0)),
-        page_spec, page_spec,
-    ]
+    row_spec = pl.BlockSpec((1, h, dh), lambda s_, j, tbl, lens: (s_, 0, 0))
+    in_specs = [row_spec, page_spec, page_spec]
     args = [q, k_pages, v_pages]
     if quant:
         in_specs += [scale_spec, scale_spec]
         args += [k_scale, v_scale]
-
-    def body(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest):
-        if quant:
-            ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-            kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, ks_ref=ks_ref, vs_ref=vs_ref)
-        else:
-            o_ref, m_ref, l_ref, acc_ref = rest
-            kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s, n_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, h, dh), lambda s_, j, tbl, lens: (s_, 0, 0),
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        )
+    return pl.pallas_call(
+        functools.partial(_pa_kernel, page_size=page_size,
+                          n_pages=n_pages, quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s, n_pages),
+            in_specs=in_specs,
+            out_specs=row_spec,
+            scratch_shapes=[
+                pltpu.VMEM((h, 1), jnp.float32),       # running max
+                pltpu.VMEM((h, 1), jnp.float32),       # running normalizer
+                pltpu.VMEM((h, dh), jnp.float32),      # weighted-sum acc
+            ],
         ),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),       # running max
-            pltpu.VMEM((h, 1), jnp.float32),       # running normalizer
-            pltpu.VMEM((h, dh), jnp.float32),      # weighted-sum acc
-        ],
-    )
-    out = pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, dh), jnp.float32),
         interpret=interpret,
+        **kwargs,
     )(page_tbl.astype(jnp.int32), seq_lens.astype(jnp.int32), *args)
-    return out
 
 
 # -- dispatch ---------------------------------------------------------------

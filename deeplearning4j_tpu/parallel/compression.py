@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.runtime.mesh import axis_size
 
 
 def _quantize_stochastic(x, inv_scale, key):
@@ -48,7 +47,7 @@ def quantized_psum(x, *, axis: str, key, n_shards=None):
     `local_error = x - dequantized(local contribution)` is this shard's
     quantization error for error feedback.
     """
-    n = n_shards if n_shards is not None else axis_size(axis)
+    n = n_shards if n_shards is not None else lax.axis_size(axis)
     absmax = jnp.max(jnp.abs(x)).astype(jnp.float32)
     scale = lax.pmax(absmax, axis) / 127.0
     inv = jnp.where(scale > 0, 1.0 / scale, 0.0)

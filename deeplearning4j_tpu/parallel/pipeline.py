@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deeplearning4j_tpu.runtime.mesh import axis_size, shard_map
+from deeplearning4j_tpu.runtime.mesh import shard_map
 
 
 def pipeline_apply(
@@ -42,7 +42,7 @@ def pipeline_apply(
     Returns (n_micro, B_micro, ...) outputs valid on the LAST stage
     (read them with an out_spec that takes the last pipe shard).
     """
-    n_stages = axis_size(axis)
+    n_stages = lax.axis_size(axis)
     stage = lax.axis_index(axis)
     n_micro = x_micro.shape[0]
     total = n_micro + n_stages - 1
@@ -127,7 +127,7 @@ def pipeline_train_1f1b(
     segment), and — iff loss_grad_fn returns a third element — the
     accumulated extra grads, averaged over microbatches.
     """
-    n_stages = axis_size(axis)
+    n_stages = lax.axis_size(axis)
     stage = lax.axis_index(axis)
     n_micro = x_micro.shape[0]
     total = n_micro + 2 * n_stages - 2

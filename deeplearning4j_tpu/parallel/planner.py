@@ -8,10 +8,9 @@ a cost model; this module is that derivation for the strategy space
 
 1. **enumerate** candidate `ParallelConfig`s over the divisors of the
    mesh width (data x pipe x seq x expert, zero in {0,1,2}), filtering
-   by divisibility and legality — including the jax 0.4.x "no >1
-   GSPMD-auto axis around a manual shard_map body" pipeline constraint
-   and the uneven-shard restrictions — with every rejection RECORDED as
-   a reason, never a crash;
+   by divisibility and legality — including the uneven-shard
+   restrictions — with every rejection RECORDED as a reason, never a
+   crash;
 2. **price** each survivor WITHOUT a device run: the model's step
    program is lowered ONCE from an abstract signature
    (`observe.cost.analyze_signature` — no dispatch, no backend
@@ -345,12 +344,6 @@ def _check_legal(model, cand: Candidate, B: int, feat_ndim: int,
                 f"{type(model).__name__} has no pipelineable segment "
                 "(pipeline runs over a SequentialModel's repeated "
                 "blocks)"
-            )
-        if d > 1 and not hasattr(jax, "shard_map"):
-            return (
-                "jax 0.4.x cannot keep a >1 GSPMD-auto data axis "
-                "around the manual pipeline shard_map body (needs "
-                "jax >= 0.6)"
             )
         from deeplearning4j_tpu.parallel.pipeline import (
             plan_sequential_pipeline,

@@ -103,32 +103,11 @@ def initialize(config: DistributedConfig | None = None) -> None:
     multiprocess = not single_process
 
     if config.platform == "cpu" or config.local_device_count:
-        # authoritative platform selection: env-var JAX_PLATFORMS can be
-        # shadowed by experimental PJRT plugins, the config update cannot
         jax.config.update("jax_platforms", "cpu")
         if config.local_device_count:
-            n = int(config.local_device_count)
-            try:
-                jax.config.update("jax_num_cpu_devices", n)
-            except AttributeError:
-                # older jax (<= 0.4.x) has no runtime option for the CPU
-                # device count; fall back to the XLA flag, which is still
-                # honored as long as the backends aren't up yet (true in a
-                # fresh worker process — initialize() runs first).  A
-                # stale count already in XLA_FLAGS is REPLACED — keeping
-                # it would silently ignore the requested device count.
-                import re
-
-                prev = os.environ.get("XLA_FLAGS", "")
-                flag = f"--xla_force_host_platform_device_count={n}"
-                if "xla_force_host_platform_device_count" in prev:
-                    new = re.sub(
-                        r"--?xla_force_host_platform_device_count=\d+",
-                        flag, prev,
-                    )
-                else:
-                    new = (prev + " " + flag).strip()
-                os.environ["XLA_FLAGS"] = new
+            jax.config.update(
+                "jax_num_cpu_devices", int(config.local_device_count)
+            )
         # cross-process CPU collectives need an explicit implementation —
         # but ONLY in a real multi-process world: the gloo factory needs a
         # distributed client, and a single-process world (which skips
@@ -142,13 +121,7 @@ def initialize(config: DistributedConfig | None = None) -> None:
 
     kwargs = {}
     if config.heartbeat_timeout_seconds is not None:
-        import inspect
-
-        sig = inspect.signature(jax.distributed.initialize)
-        if "heartbeat_timeout_seconds" in sig.parameters:
-            kwargs["heartbeat_timeout_seconds"] = config.heartbeat_timeout_seconds
-        # else: older jax exposes no failure-detection knob — run with its
-        # default timeout rather than refusing to form the world
+        kwargs["heartbeat_timeout_seconds"] = config.heartbeat_timeout_seconds
     jax.distributed.initialize(
         coordinator_address=config.coordinator_address,
         num_processes=config.num_processes,

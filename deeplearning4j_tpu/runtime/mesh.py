@@ -94,70 +94,19 @@ def make_mesh(
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs, axis_names=None,
-              check_vma=None, check_rep=None):
-    """`jax.shard_map` compatibility shim — the ONE entry point the
-    framework (and its tests) use for per-shard SPMD bodies.
-
-    jax >= 0.6 exposes ``jax.shard_map(f, mesh=..., in_specs=...,
-    out_specs=..., axis_names=..., check_vma=...)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(f, mesh, in_specs,
-    out_specs, check_rep=..., auto=...)`` — same GSPMD lowering, older
-    spelling.  This shim maps between them:
-
-    - ``check_vma`` (new name) / ``check_rep`` (old name) are the same
-      replication-checking knob; whichever is given is forwarded under
-      the API's own name.
-    - ``axis_names`` restricts which mesh axes the body is manual over;
-      the legacy API expresses the complement via ``auto``.
-    """
-    native = getattr(jax, "shard_map", None)
-    rep = check_vma if check_vma is not None else check_rep
-    if native is not None:
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        if rep is not None:
-            kwargs["check_vma"] = bool(rep)
-        return native(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if rep is not None:
-        kwargs["check_rep"] = bool(rep)
+              check_vma=None):
+    """The ONE entry point the framework (and its tests) use for
+    per-shard SPMD bodies: `jax.shard_map`, with the optional arguments
+    forwarded only when given so jax's own defaults apply otherwise.
+    ``axis_names`` restricts which mesh axes the body is manual over;
+    the rest stay GSPMD-auto around it."""
+    kwargs = {}
     if axis_names is not None:
-        # Axes outside `axis_names` would be "auto" (GSPMD-partitioned
-        # around the manual body).  Legacy shard_map's auto support is
-        # broken under jit — the SPMD partitioner hits an UNIMPLEMENTED
-        # PartitionId / a CHECK abort — so: size-1 leftovers fold into
-        # the manual set (semantically free: nothing is sharded over
-        # them), and a real >1 auto axis raises HERE, actionably,
-        # instead of aborting the process inside XLA.
-        auto = frozenset(
-            a for a in mesh.axis_names
-            if a not in axis_names and mesh.shape[a] > 1
-        )
-        if auto:
-            raise NotImplementedError(
-                f"this jax ({jax.__version__}) cannot run a shard_map "
-                f"manual over {sorted(axis_names)} while axes "
-                f"{sorted(auto)} (size > 1) stay GSPMD-auto; shrink the "
-                "auto axes to size 1 or upgrade jax for partial-auto "
-                "shard_map"
-            )
-    return _legacy(f, **kwargs)
-
-
-def axis_size(name: str):
-    """Size of a named mesh axis from INSIDE a traced per-shard body.
-
-    ``jax.lax.axis_size`` only exists on newer jax; the 0.4.x spelling
-    is the idiomatic ``lax.psum(1, name)``, which constant-folds to the
-    static axis size.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    return jax.lax.psum(1, name)
+        kwargs["axis_names"] = axis_names
+    if check_vma is not None:
+        kwargs["check_vma"] = bool(check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def virtual_cpu_devices(n: int) -> str:

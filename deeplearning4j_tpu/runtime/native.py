@@ -33,28 +33,32 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
-    """Attempt an in-place `make` (g++ is part of the supported toolchain).
-    Announced via logging so a slow first call is explainable; skipped
-    outright when the toolchain is missing."""
+def _build() -> None:
+    """Run `make -C native` whenever the toolchain is there, so the
+    library that loads is always built from the tracked source (make's
+    own timestamps make this a no-op when the .so is current; a stale or
+    foreign .so on disk is rebuilt instead of trusted).  Without the
+    toolchain nothing is built and an existing library loads as is."""
     import logging
     import shutil
 
     if shutil.which("make") is None or shutil.which(
         os.environ.get("CXX", "g++")
     ) is None:
-        return False
-    logging.getLogger(__name__).info(
-        "building native IO library (one-time, %s)", _NATIVE_DIR
-    )
+        return
+    log = logging.getLogger(__name__)
     try:
         proc = subprocess.run(
             ["make", "-C", str(_NATIVE_DIR)],
-            capture_output=True, timeout=60,
+            capture_output=True, timeout=120,
         )
-        return proc.returncode == 0
-    except Exception:
-        return False
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        log.warning("native IO library build failed: %s", exc)
+        return
+    if proc.returncode != 0:
+        log.warning("native IO library build failed (rc=%d): %s",
+                    proc.returncode,
+                    proc.stderr.decode(errors="replace")[-400:])
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -66,8 +70,7 @@ def _load() -> Optional[ctypes.CDLL]:
         if os.environ.get(ENV_DISABLE, "") not in ("", "0"):
             return None
         path = _NATIVE_DIR / _LIB_NAME
-        if not path.exists() and not _build():
-            return None
+        _build()
         if not path.exists():
             return None
         try:
@@ -95,28 +98,19 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.dl4jtpu_free.restype = None
         lib.dl4jtpu_free.argtypes = [ctypes.c_void_p]
         lib.dl4jtpu_io_version.restype = ctypes.c_char_p
-        try:
-            lib.dl4jtpu_has_jpeg.restype = ctypes.c_int
-            lib.dl4jtpu_jpeg_batch.restype = ctypes.c_int
-            lib.dl4jtpu_jpeg_batch.argtypes = [
-                ctypes.POINTER(ctypes.c_char_p), ctypes.c_long,
-                ctypes.c_int, ctypes.c_int, ctypes.c_int,
-                ctypes.POINTER(ctypes.c_float), ctypes.c_int,
-            ]
-        except AttributeError:
-            pass   # pre-1.1 library on disk; jpeg path reports unavailable
-        try:
-            lib.dl4jtpu_jpeg_batch_u8.restype = ctypes.c_int
-            lib.dl4jtpu_jpeg_batch_u8.argtypes = [
-                ctypes.POINTER(ctypes.c_char_p), ctypes.c_long,
-                ctypes.c_int, ctypes.c_int, ctypes.c_int,
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_int,
-            ]
-            lib._dl4jtpu_has_u8 = True
-        except AttributeError:
-            # pre-1.2 library: f32 decode works, uint8 wire path needs a
-            # rebuild (make -C native) — jpeg_batch_decode raises clearly
-            lib._dl4jtpu_has_u8 = False
+        lib.dl4jtpu_has_jpeg.restype = ctypes.c_int
+        lib.dl4jtpu_jpeg_batch.restype = ctypes.c_int
+        lib.dl4jtpu_jpeg_batch.argtypes = [
+            ctypes.POINTER(ctypes.c_char_p), ctypes.c_long,
+            ctypes.c_int, ctypes.c_int, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_float), ctypes.c_int,
+        ]
+        lib.dl4jtpu_jpeg_batch_u8.restype = ctypes.c_int
+        lib.dl4jtpu_jpeg_batch_u8.argtypes = [
+            ctypes.POINTER(ctypes.c_char_p), ctypes.c_long,
+            ctypes.c_int, ctypes.c_int, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int,
+        ]
         _lib = lib
         return _lib
 
@@ -199,8 +193,7 @@ def u8_to_f32_scaled(src: np.ndarray, scale: float = 1.0 / 255.0,
 def has_jpeg() -> bool:
     """True when the library was compiled against libjpeg."""
     lib = _load()
-    return bool(lib is not None and hasattr(lib, "dl4jtpu_has_jpeg")
-                and lib.dl4jtpu_has_jpeg())
+    return bool(lib is not None and lib.dl4jtpu_has_jpeg())
 
 
 def jpeg_batch_decode(paths, height: int, width: int, channels: int = 3,
@@ -229,11 +222,6 @@ def jpeg_batch_decode(paths, height: int, width: int, channels: int = 3,
     out = np.empty((n, height, width, channels), dtype)
     arr = (ctypes.c_char_p * n)(*(p.encode() for p in paths))
     if dtype == np.uint8:
-        if not getattr(lib, "_dl4jtpu_has_u8", False):
-            raise RuntimeError(
-                "uint8 JPEG decode needs dl4jtpu_io >= 1.2 — rebuild the "
-                "native library (make -C native)"
-            )
         fails = lib.dl4jtpu_jpeg_batch_u8(
             arr, n, height, width, channels,
             out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),

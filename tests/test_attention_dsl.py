@@ -280,11 +280,10 @@ def test_seq_parallel_matches_dense_training(mode):
 
 
 def test_seq_parallel_with_data_parallel_combo():
-    """seq x data mesh over the 8-device CPU platform.  jax 0.4.x's
-    shard_map cannot leave a >1 data axis GSPMD-auto around the manual
-    ring-attention body (runtime/mesh.py shim raises), so legacy jax
-    runs the combo with a size-1 data axis; newer jax runs 2 x 4."""
-    data = 2 if hasattr(jax, "shard_map") else 1
+    """seq x data mesh (4 x 2) over the 8-device CPU platform: the
+    ring-attention body is manual over "seq", the data axis stays
+    GSPMD-auto around it."""
+    data = 2
     ds = _lm_batch()
     model = _tiny_transformer("ring")
     distribute(model, ParallelConfig(data=data, seq=4),

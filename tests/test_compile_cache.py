@@ -156,6 +156,14 @@ _WARMSTART_SCRIPT = r"""
 import json, os, sys
 import jax
 jax.config.update("jax_platforms", "cpu")
+# record every later config update: with JAX_COMPILATION_CACHE_DIR set the
+# package must leave jax_compilation_cache_dir to jax
+_updates = []
+_orig_update = jax.config.update
+def _recording_update(name, value):
+    _updates.append(name)
+    return _orig_update(name, value)
+jax.config.update = _recording_update
 import numpy as np
 from deeplearning4j_tpu.data.dataset import DataSet
 from deeplearning4j_tpu.models import SequentialModel
@@ -166,7 +174,8 @@ from deeplearning4j_tpu.nn.conf import (
 )
 from deeplearning4j_tpu.runtime import compile_stats, init_compile_cache
 
-assert init_compile_cache() == os.environ["DL4J_TPU_COMPILE_CACHE"]
+assert init_compile_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
+assert "jax_compilation_cache_dir" not in _updates, _updates
 conf = (
     NeuralNetConfiguration.builder().seed(0).updater(Sgd(0.1))
     .list()
@@ -192,12 +201,11 @@ def test_second_process_warm_starts_from_persistent_cache(tmp_path):
     env = dict(os.environ)
     env.update({
         "JAX_PLATFORMS": "cpu",
-        "DL4J_TPU_COMPILE_CACHE": cache,
+        "JAX_COMPILATION_CACHE_DIR": cache,
         # persist EVERYTHING: the threshold exists for prod hygiene, the
         # test needs determinism
         "DL4J_TPU_CACHE_MIN_COMPILE_SECS": "0",
     })
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
 
     def run():
         proc = subprocess.run(
@@ -214,6 +222,59 @@ def test_second_process_warm_starts_from_persistent_cache(tmp_path):
     assert warm["backend_compiles"] > 0             # same programs needed
     assert warm["fresh_backend_compiles"] == 0      # all served from disk
     assert warm["persistent_cache_hits"] == warm["backend_compiles"]
+    assert os.listdir(cache)                        # ...from THAT directory
+
+
+# -- where the cache lives (placed from outside) ----------------------------
+
+@pytest.fixture
+def restore_cache_config():
+    """These tests run `init_compile_cache`'s body (the public function is
+    memoized per process) against a chosen jax-level setting; put the
+    process's own setting back afterwards."""
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_unset_cache_dir_defaults_to_the_checkout(restore_cache_config):
+    """Nothing configured -> `<checkout>/.jax_cache`, resolved from the
+    package's own path: never `~`, XDG_CACHE_HOME, a tempdir, a pid or a
+    time (the path is part of the cache key, so a directory that moves
+    never hits)."""
+    import jax
+
+    from deeplearning4j_tpu.runtime import init_compile_cache
+
+    jax.config.update("jax_compilation_cache_dir", None)
+    want = os.path.join(REPO, ".jax_cache")
+    assert init_compile_cache.__wrapped__() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.isdir(want)
+
+
+def test_configured_cache_dir_is_left_to_jax(restore_cache_config, tmp_path,
+                                             monkeypatch):
+    """A directory set from outside (JAX_COMPILATION_CACHE_DIR lands in
+    this very config value at import) is used as is: the package performs
+    NO `jax_compilation_cache_dir` update of its own."""
+    import jax
+
+    from deeplearning4j_tpu.runtime import init_compile_cache
+
+    outside = str(tmp_path / "placed_from_outside")
+    jax.config.update("jax_compilation_cache_dir", outside)
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, value: (updates.append(name), real_update(name, value)))
+    assert init_compile_cache.__wrapped__() == outside
+    monkeypatch.undo()
+    assert "jax_compilation_cache_dir" not in updates
+    assert jax.config.jax_compilation_cache_dir == outside
 
 
 # -- CachedDataSetIterator -------------------------------------------------

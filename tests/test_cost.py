@@ -50,6 +50,30 @@ def train_records(model):
     return [r for r in cost.analyze_model(model) if r.kind == "train"]
 
 
+def set_cpu_peaks(monkeypatch, flops, membw):
+    """Pin the table's per-device CPU row (there is no env override)."""
+    monkeypatch.setitem(cost.PEAKS_BY_DEVICE_KIND, "cpu", (flops, membw))
+
+
+class TestPeaksTable:
+    def test_cpu_row_times_local_device_count(self, monkeypatch):
+        import jax
+
+        set_cpu_peaks(monkeypatch, 2e12, 3e11)
+        n = jax.local_device_count()
+        assert cost.peaks() == (2e12 * n, 3e11 * n)
+
+    def test_unknown_device_kind_raises(self, monkeypatch):
+        """A device the table does not list is an error, never a
+        default peak."""
+        monkeypatch.delitem(cost.PEAKS_BY_DEVICE_KIND, "cpu")
+        with pytest.raises(cost.UnknownDeviceKind, match="cpu"):
+            cost.peaks()
+
+    def test_v5e_reports_as_v5_lite(self):
+        assert cost.PEAKS_BY_DEVICE_KIND["TPU v5 lite"] == (197.0e12, 8.19e11)
+
+
 class TestProgramRegistry:
     def test_flops_match_hand_computed_dense_matmul(self):
         """Acceptance: XLA cost-analysis FLOPs for a known dense-matmul
@@ -79,7 +103,7 @@ class TestProgramRegistry:
         rec = train_records(m)[0]
         rec.ensure_analysis(memory=True)
         d = rec.as_dict()
-        # on CPU jax 0.4.37 these are present; the contract is "present
+        # on the CPU backend these are present; the contract is "present
         # or None, never a raised analysis"
         if rec._memory_done and rec.argument_bytes is not None:
             assert d["argument_bytes"] > 0
@@ -135,8 +159,7 @@ class TestProgramRegistry:
 
 class TestStepGauges:
     def test_mfu_and_flops_gauges_flow_after_analysis(self, monkeypatch):
-        monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "1e12")
-        monkeypatch.setenv("DL4J_TPU_PEAK_MEMBW", "1e11")
+        set_cpu_peaks(monkeypatch, 1e12, 1e11)
         m = dense_model()
         m.fit([batch()], epochs=1)
         rec = train_records(m)[0]     # triggers analysis
@@ -185,17 +208,15 @@ class TestStepGauges:
         ai = rec.arithmetic_intensity()
         assert ai > 0
         # ridge far below AI -> compute-bound; far above -> memory-bound
-        monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "1e12")
-        monkeypatch.setenv("DL4J_TPU_PEAK_MEMBW", str(1e12 / (ai / 10)))
+        set_cpu_peaks(monkeypatch, 1e12, 1e12 / (ai / 10))
         assert rec.roofline() == "compute-bound"
-        monkeypatch.setenv("DL4J_TPU_PEAK_MEMBW", str(1e12 / (ai * 10)))
+        set_cpu_peaks(monkeypatch, 1e12, 1e12 / (ai * 10))
         assert rec.roofline() == "memory-bound"
 
     def test_roofline_stamped_on_step_span(self, monkeypatch):
         from deeplearning4j_tpu.observe import tracer
 
-        monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "1e12")
-        monkeypatch.setenv("DL4J_TPU_PEAK_MEMBW", "1e11")
+        set_cpu_peaks(monkeypatch, 1e12, 1e11)
         m = dense_model()
         m.fit([batch()], epochs=1)
         train_records(m)              # analyze

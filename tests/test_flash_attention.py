@@ -191,3 +191,18 @@ class TestPallasBackward:
         assert fa._block_choice(512, 512, 64, True, None, None) == (128, 128)
         monkeypatch.setenv("DL4JTPU_FLASH_BLOCK", "256")
         assert fa._block_choice(512, 512, 64, True, None, None) == (128, 128)
+
+    def test_autotune_raises_when_no_candidate_compiles(self, caplog):
+        """The compiled (non-interpret) kernel cannot build on the CPU
+        backend: every candidate is refused, each refusal is logged, and
+        the search raises instead of returning default blocks."""
+        import logging
+
+        from deeplearning4j_tpu.ops import flash_attention as fa
+
+        with caplog.at_level(logging.WARNING, logger="deeplearning4j_tpu"):
+            with pytest.raises(RuntimeError, match="no candidate"):
+                fa.flash_autotune(seq_len=128, n_heads=1, head_dim=16,
+                                  candidates=((128, 128),), reps=1)
+        assert sum("refused" in r.message for r in caplog.records) == 1
+        assert (128, 128, 16, True) not in fa._BLOCK_CACHE

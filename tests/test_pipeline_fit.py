@@ -18,12 +18,9 @@ from deeplearning4j_tpu.zoo.transformer import TransformerEncoder
 VOCAB, D, HEADS, LAYERS = 16, 16, 2, 4
 BATCH, SEQ = 8, 8
 
-# jax 0.4.x's experimental shard_map cannot leave a >1 mesh axis
-# GSPMD-auto around a manual pipeline body (runtime/mesh.py shim raises
-# there), so legacy jax runs the pipeline over pipe alone (data=1 on 4
-# devices); newer jax composes it with a 2-wide data axis.
-PARTIAL_AUTO = hasattr(jax, "shard_map")
-DATA = 2 if PARTIAL_AUTO else 1
+# the pipeline body is manual over "pipe" only; a 2-wide data axis stays
+# GSPMD-auto around it (partial-auto shard_map)
+DATA = 2
 
 
 def pipe_devices():

@@ -671,7 +671,7 @@ from deeplearning4j_tpu.runtime import compile_stats, init_compile_cache
 from deeplearning4j_tpu.serving import InferenceServer, ServingConfig
 from deeplearning4j_tpu.train.checkpoint import ModelSerializer
 
-assert init_compile_cache() == os.environ["DL4J_TPU_COMPILE_CACHE"]
+assert init_compile_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
 ckpt = os.environ["QUANT_CKPT"]
 if not os.path.exists(ckpt):
     conf = (NeuralNetConfiguration.builder().seed(0).list()
@@ -697,11 +697,10 @@ def test_quantized_second_boot_warm_starts_with_zero_fresh_compiles(
     env = dict(os.environ)
     env.update({
         "JAX_PLATFORMS": "cpu",
-        "DL4J_TPU_COMPILE_CACHE": str(tmp_path / "xla_cache"),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla_cache"),
         "DL4J_TPU_CACHE_MIN_COMPILE_SECS": "0",
         "QUANT_CKPT": str(tmp_path / "quant.zip"),
     })
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.pop("XLA_FLAGS", None)
 
     def run():

@@ -1,0 +1,122 @@
+"""Cross-lower every Pallas kernel for TPU from the CPU, at the full-width
+shapes `chip_smoke.py` runs.
+
+`jax.export.export(jax.jit(f), platforms=["tpu"])` runs Pallas' Mosaic
+LOWERING without a chip.  It is the check that found the paged-attention
+kernel's batched in-kernel einsum (no TPU lowering) while that kernel had
+only ever run in interpret mode; it keeps a later kernel change from
+shipping something the lowering refuses.  It proves lowering only — the
+Mosaic compile inside libtpu (VMEM limits, layouts) and the numerics are
+`chip_smoke.py`'s kernels phase, on the chip.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.ops import dequant_matmul as dm
+from deeplearning4j_tpu.ops import flash_attention as fa
+from deeplearning4j_tpu.ops import paged_attention as pa
+
+# the smoke's shapes: flash (B, T, H, D); paged S slots of H x Dh heads
+# over 16-row pages, 34 pages/seq; dequant (M, K) @ (K, N)
+B, T, H, D = 4, 2048, 8, 128
+S, PS, MP, P = 8, 16, 34, 300
+K, N = 1024, 4096
+
+
+def lower_for_tpu(f, *specs):
+    exported = jax.export.export(jax.jit(f), platforms=["tpu"])(*specs)
+    # a Pallas TPU kernel reaches the module as a Mosaic custom call
+    assert "tpu_custom_call" in exported.mlir_module()
+    return exported
+
+
+def sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def test_flash_forward_bf16():
+    q = sds((B, T, H, D), jnp.bfloat16)
+    lower_for_tpu(lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+                  q, q, q)
+
+
+def test_flash_backward_bf16():
+    q = sds((B, T, H, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+def test_paged_attention(kv_dtype):
+    store = jnp.int8 if kv_dtype == "int8" else jnp.float32
+    specs = [sds((S, H, D), jnp.float32), sds((P, PS, H, D), store),
+             sds((P, PS, H, D), store), sds((S, MP), jnp.int32),
+             sds((S,), jnp.int32)]
+    if kv_dtype == "int8":
+        specs += [sds((P, PS, H), jnp.float32)] * 2
+
+    def f(q, k, v, tbl, lens, ks=None, vs=None):
+        return pa.paged_attention(q, k, v, tbl, lens, k_scale=ks, v_scale=vs,
+                                  impl="pallas", interpret=False)
+
+    lower_for_tpu(f, *specs)
+
+
+def test_paged_attention_chunk_c5():
+    """The speculative verify route: C=5 queries per slot ride the decode
+    kernel by pseudo-slot expansion."""
+    c = 5
+
+    def f(q, k, v, tbl, attend):
+        return pa.paged_attention_chunk(q, k, v, tbl, attend, impl="pallas",
+                                        interpret=False)
+
+    lower_for_tpu(f, sds((S, c, H, D), jnp.float32),
+                  sds((P, PS, H, D), jnp.float32),
+                  sds((P, PS, H, D), jnp.float32),
+                  sds((S, MP), jnp.int32), sds((S, c), jnp.int32))
+
+
+@pytest.mark.parametrize("m", [1, 8, 256])
+def test_dequant_matmul(m):
+    lower_for_tpu(
+        lambda x, q, s: dm.dequant_matmul(x, q, s, impl="pallas",
+                                          interpret=False),
+        sds((m, K), jnp.float32), sds((K, N), jnp.int8),
+        sds((N,), jnp.float32))
+
+
+def test_flash_under_a_data_mesh_runs_per_shard():
+    """GSPMD cannot partition a Mosaic kernel: a data-parallel step that
+    reaches the flash kernel must wrap it per shard (found on four real
+    chips — the interpret-mode CPU runs lower to plain XLA ops and never
+    hit it).  The bare kernel under sharded inputs is refused at
+    lowering; `ops.attention`'s per-shard wrapper lowers."""
+    import functools
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.ops.attention import _flash_per_shard
+    from deeplearning4j_tpu.runtime.mesh import (
+        MeshSpec, active_mesh_scope, make_mesh,
+    )
+
+    mesh = make_mesh(MeshSpec.of(data=4), jax.devices()[:4])
+    q = jax.ShapeDtypeStruct((16, T, H, D), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+    flash = functools.partial(fa.flash_attention, causal=True,
+                              interpret=False)
+    with pytest.raises(NotImplementedError, match="partitioned"):
+        jax.export.export(jax.jit(flash), platforms=["tpu"])(q, q, q)
+    def loss(q, k, v):
+        return jnp.sum(_flash_per_shard(flash, q, k, v).astype(jnp.float32))
+
+    with active_mesh_scope(mesh):
+        exported = lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert exported.nr_devices == 4

@@ -399,20 +399,21 @@ class TestAttribution:
         assert c.value(mode="replicated") > before
 
 
-class TestShardMapShim:
-    """runtime/mesh.py's jax.shard_map compatibility shim (the 31
-    tier-1 un-failures ride on it)."""
+class TestShardMapEntryPoint:
+    """runtime/mesh.shard_map — the framework's one call of
+    `jax.shard_map`."""
 
     def test_psum_and_axis_size(self):
         from jax.sharding import PartitionSpec as P
 
         from deeplearning4j_tpu.runtime.mesh import (
-            MeshSpec, axis_size, make_mesh, shard_map,
+            MeshSpec, make_mesh, shard_map,
         )
 
         mesh = make_mesh(MeshSpec.data_parallel())
         f = shard_map(
-            lambda x: jax.lax.psum(x, DATA_AXIS) * 0 + axis_size(DATA_AXIS),
+            lambda x: (jax.lax.psum(x, DATA_AXIS) * 0
+                       + jax.lax.axis_size(DATA_AXIS)),
             mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P(DATA_AXIS),
             check_vma=False,
         )
@@ -435,17 +436,20 @@ class TestShardMapShim:
             np.asarray(jax.jit(f)(jnp.arange(4.0))), np.arange(4.0) * 2
         )
 
-    def test_legacy_partial_auto_raises_actionably(self):
+    def test_partial_auto_axis_stays_gspmd(self):
+        """A body manual over "pipe" with a 2-wide data axis left
+        GSPMD-auto around it — the layout `distribute(pipe=k, data>1)`
+        and the planner's pipe x data candidates rely on."""
         from jax.sharding import PartitionSpec as P
 
         from deeplearning4j_tpu.runtime.mesh import MeshSpec, make_mesh, shard_map
 
-        if hasattr(jax, "shard_map"):
-            pytest.skip("native partial-auto shard_map available")
         mesh = make_mesh(MeshSpec.of(data=2, pipe=4))
-        with pytest.raises(NotImplementedError, match="auto"):
-            shard_map(
-                lambda x: x, mesh=mesh, in_specs=(P("pipe"),),
-                out_specs=P("pipe"), axis_names={"pipe"},
-                check_vma=False,
-            )
+        f = shard_map(
+            lambda x: x + jax.lax.axis_index("pipe").astype(x.dtype),
+            mesh=mesh, in_specs=(P("pipe"),), out_specs=P("pipe"),
+            axis_names={"pipe"}, check_vma=False,
+        )
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(f)(jnp.zeros(4))), np.arange(4.0)
+        )
