@@ -737,6 +737,23 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "Tokens emitted by the continuous-batching decode "
                 "engine (prefill first-tokens included) — the "
                 "aggregate tokens/s numerator")
+    reg.counter("dl4jtpu_decode_steps_total",
+                "Decode dispatches of the generation engines (plain "
+                "steps and speculative verify chunks), counted where "
+                "the step is built and bridged by a pull collector")
+    reg.counter("dl4jtpu_decode_slot_steps_total",
+                "Live slots summed over the decode dispatches: over "
+                "dl4jtpu_decode_steps_total it is the mean batch "
+                "occupancy per step (the occupancy gauge is a "
+                "last-value sample)")
+    reg.counter("dl4jtpu_decode_rows_attended_total",
+                "KV rows the decode dispatches attended, summed over "
+                "their live slots, from seq_lens at dispatch: a plain "
+                "step counts a slot's rows INCLUDING the one it writes "
+                "(seq_len + 1); a verify chunk counts each slot's rows "
+                "once (seq_len + spec_k + 1, capped at the page "
+                "table's span).  Times layers x 2 x heads x head size x "
+                "bytes per element it is the KV bytes demanded")
     reg.gauge("dl4jtpu_kv_pages_used",
               "KV pool pages currently owned by live streams "
               "(page 0, the scratch page, never counts)")

@@ -8,11 +8,22 @@ PROFILE.md could only ESTIMATE that gap (~7% on the ResNet config, from
 bench-wall minus device-time); this module measures it.
 
 `TraceRecorder` is a low-overhead ring-buffer span store (fixed
-capacity, oldest spans evicted) with a context-manager + decorator API,
-emitting Chrome trace-event JSON (`chrome://tracing` / Perfetto `Load
-trace`).  Disabled (the default) it costs one attribute check per
-call site; enabled it costs two `perf_counter` reads and a deque append
-per span — no locks on the hot path beyond the GIL-atomic append.
+capacity, oldest spans evicted) with a context-manager API, emitting
+Chrome trace-event JSON (`chrome://tracing` / Perfetto `Load trace`).
+
+**One span, two sinks.**  `span()` always enters a
+`jax.profiler.TraceAnnotation` for the span's life, so whenever a
+profiler session is recording (`jax.profiler.start_trace`) every span of
+the program is an event on the session's `/host:CPU` plane, on the
+line of the thread that ran it and on the clock the device planes share
+— the idle gaps of the device can be read against the program's own
+phases.  No flag switches this: a `TraceMe` outside a session does
+nothing.  With the ring enabled the same enter/exit also lands in the
+ring.  Ring disabled (the default) a span costs the annotation and two
+`perf_counter` reads (one to two microseconds); enabled, a deque append
+more — no locks on the hot path beyond the GIL-atomic append.
+`add_complete` (already-measured spans) is ring-only: the profiler has
+no retroactive API.
 
 The fit loops of `Model`/`SequentialModel`/`GraphModel` instrument each
 step with five spans: ``etl_wait`` -> ``host_stage`` -> ``dispatch`` ->
@@ -49,8 +60,9 @@ import os
 import threading
 import time
 from collections import deque
-from functools import wraps
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -120,39 +132,35 @@ def chain_coverage(chain: list) -> Optional[float]:
     return min(1.0, covered / root["dur"])
 
 
-class _NullSpan:
-    """Shared no-op context manager — the disabled-tracer fast path."""
+class _Span(TraceAnnotation):
+    """One span, two sinks: the profiler annotation itself (an event of
+    the running session for the span's life, or nothing without one)
+    and, when the ring is enabled, a ring entry from the same
+    enter/exit.  After exit `t0` and `dur` (perf_counter seconds) hold
+    the one timing of the phase, for call sites that need the number
+    too."""
 
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class _Span:
-    __slots__ = ("_rec", "name", "cat", "args", "_t0")
+    __slots__ = ("_rec", "name", "cat", "args", "t0", "dur")
 
     def __init__(self, rec: "TraceRecorder", name: str, cat: str, args):
+        super().__init__(name, **args)
         self._rec = rec
         self.name = name
         self.cat = cat
         self.args = args
+        self.dur = None
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        super().__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._rec.add_complete(
-            self.name, self._t0, time.perf_counter() - self._t0,
-            cat=self.cat, **(self.args or {}),
-        )
+        self.dur = time.perf_counter() - self.t0
+        super().__exit__(*exc)
+        if self._rec._enabled:
+            self._rec.add_complete(self.name, self.t0, self.dur,
+                                   cat=self.cat, **self.args)
         return False
 
 
@@ -195,10 +203,10 @@ class TraceRecorder:
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, cat: str = "step", **args):
-        """Context manager recording one complete ("X") span.  Returns a
-        shared no-op when disabled — call sites don't branch."""
-        if not self._enabled:
-            return _NULL_SPAN
+        """Context manager around one phase: a profiler annotation
+        always (`args` become the event's stats), a complete ("X") ring
+        span too while the ring is enabled — call sites don't branch.
+        The returned object keeps the phase's `t0` / `dur` after exit."""
         return _Span(self, name, cat, args)
 
     def add_complete(self, name: str, t0: float, dur: float,
@@ -217,23 +225,6 @@ class TraceRecorder:
         self._spans.append((
             name, cat, t0, dur, threading.get_ident(), args or None,
         ))
-
-    def traced(self, name: Optional[str] = None, cat: str = "func"):
-        """Decorator form: `@tracer().traced()` wraps a function in a
-        span named after it."""
-        def deco(fn):
-            span_name = name or fn.__qualname__
-
-            @wraps(fn)
-            def wrapper(*a, **kw):
-                if not self._enabled:
-                    return fn(*a, **kw)
-                with self.span(span_name, cat=cat):
-                    return fn(*a, **kw)
-
-            return wrapper
-
-        return deco
 
     # -- exposition --------------------------------------------------------
     def _event(self, span) -> dict:
@@ -520,9 +511,12 @@ class StepScope:
     - always: observes `dl4jtpu_step_latency_seconds` (host wall per
       program) and `dl4jtpu_train_steps_total` (+n_steps) — the scrape
       path's step-rate signal costs two perf_counter reads per program;
-    - tracing enabled: `.phase(name)` sub-spans land in the ring buffer
-      and `.sync(x)` blocks on the step's output so `device_sync` is a
-      real measured span instead of async-dispatch noise.
+    - `.phase(name)` sub-spans are recorder spans: profiler annotations
+      always (a `jax.profiler` session shows the five phases on the fit
+      thread's line), ring entries while the ring is enabled;
+    - ring enabled: `.sync(x)` blocks on the step's output so
+      `device_sync` is a real measured span instead of async-dispatch
+      noise.  A profiler session alone never makes it block.
     """
 
     __slots__ = ("_rec", "_hist", "_steps", "_n", "_iteration", "_t0",

@@ -56,6 +56,19 @@ stream (args carry the batch composition: co-resident rids and
 per-stream token counts) -> the ``generation.stream`` root.  The trace
 context rides the `prefill_detached` handoff dict, so a disaggregated
 stream is one causal chain across replicas on ``/api/trace/cluster``.
+
+The engine THREAD's time is tiled by recorder spans of its own
+(`_span`; each a `jax.profiler` annotation whenever a profiler session
+records, ring or no ring): ``generation.wait_for_work`` (no live slot:
+asleep in the queue) | ``generation.refill`` (an admission: every live
+stream waits) > ``generation.admit_to_slot`` per request >
+``generation.prefill`` + ``generation.kv_handoff`` | then, per step and
+with no span around them, ``generation.decode_prepare`` ->
+``generation.decode_dispatch`` (args ``slots``/``rows``) ->
+``generation.decode_readback`` -> ``generation.harvest``.  Each phase
+is timed ONCE, by its span: `req.lat` and the per-stream chain read the
+span's ``dur``.  Every step also counts what it served —
+``dl4jtpu_decode_{steps,slot_steps,rows_attended}_total``.
 """
 
 from __future__ import annotations
@@ -63,6 +76,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Optional
@@ -130,6 +144,26 @@ def _gen_breakdown_families() -> dict:
             for seg in GEN_BREAKDOWN_SEGMENTS
         }
     return _GEN_BREAKDOWN_FAMILIES
+
+
+#: what the decode steps served, process totals: the registry families
+#: behind the engine's `_steps`, `_slot_steps` and `_rows_attended`
+DECODE_COUNT_FAMILIES = ("dl4jtpu_decode_steps_total",
+                         "dl4jtpu_decode_slot_steps_total",
+                         "dl4jtpu_decode_rows_attended_total")
+
+_ENGINES: "weakref.WeakSet[GenerationEngine]" = weakref.WeakSet()
+_ENGINES_LOCK = threading.Lock()
+
+
+def _collect_decode_counts() -> None:
+    """Pull collector: the engines count their steps in plain ints on
+    their own thread (no registry lock per step); a scrape moves what
+    was added since the last one into the registry's counters."""
+    with _ENGINES_LOCK:
+        engines = list(_ENGINES)
+    for eng in engines:
+        eng._flush_decode_counts()
 
 
 @dataclass
@@ -374,7 +408,13 @@ class GenerationEngine:
             cold_floor_s=cfg.watchdog_cold_floor_s,
             k=cfg.watchdog_k, abort=self._on_wedged, name="generation",
         )
+        # what the steps served, counted where a step is built (engine
+        # thread only): dispatches, live slots summed over them, and KV
+        # rows attended summed over them
         self._steps = 0
+        self._slot_steps = 0
+        self._rows_attended = 0
+        self._counts_flushed = (0, 0, 0)
         self._tokens_out = 0
         self._step_fn = None
         self._prefill_fns: dict[int, Callable] = {}
@@ -413,6 +453,14 @@ class GenerationEngine:
         self.flight.attach_slo_trigger()
         if server is not None:
             server.generation_engine = self
+        with _ENGINES_LOCK:
+            _ENGINES.add(self)
+        try:
+            from deeplearning4j_tpu.observe.metrics import registry
+
+            registry().register_collector(_collect_decode_counts)
+        except Exception as e:
+            log.debug("decode count collector install failed: %s", e)
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> "GenerationEngine":
@@ -443,6 +491,7 @@ class GenerationEngine:
                 outcome="shutdown",
             )
         self.flight.detach_slo_trigger()
+        self._flush_decode_counts()
 
     # -- admission ---------------------------------------------------------
     def submit(self, prompt, max_new_tokens: Optional[int] = None, *,
@@ -539,6 +588,14 @@ class GenerationEngine:
             **args,
         )
 
+    def _span(self, name: str, **args):
+        """One phase of the engine thread: a recorder span, so a
+        profiler annotation always (the loop's phases on the session's
+        clock, next to the device's idle gaps) and a ring span while the
+        ring is enabled.  Its `t0` / `dur` after exit are the phase's
+        one timing — `req.lat` and the per-stream chain read them."""
+        return self._rec.span(name, cat="engine", **args)
+
     def generate(self, prompt, max_new_tokens: int, *,
                  temperature: float = 0.0, top_k: int = 0, seed: int = 0,
                  stop_tokens: tuple = (),
@@ -575,10 +632,10 @@ class GenerationEngine:
             faults.maybe_fail("serving.prefill")
         except Exception as exc:
             raise ServingError(f"injected prefill fault: {exc}") from exc
-        t_pre0 = time.perf_counter()
-        k, v, first, ttft_anchor = self._run_prefill(req)
-        pre_s = time.perf_counter() - t_pre0
-        self._trace_segment(req, "generation.prefill", t_pre0, pre_s,
+        with self._span("generation.prefill", detached=True) as sp:
+            k, v, first, ttft_anchor = self._run_prefill(req)
+        pre_s = sp.dur
+        self._trace_segment(req, "generation.prefill", sp.t0, pre_s,
                             bucket=int(k.shape[1]), detached=True)
         out = {
             "prompt": req.prompt, "k": np.asarray(k), "v": np.asarray(v),
@@ -901,11 +958,24 @@ class GenerationEngine:
             return
         if self.queue.depth == 0 and not block:
             return
-        batch = self.queue.take_batch(
-            len(free), linger_s=0.0, stop=self._stop,
-            poll_s=self.config.poll_s,
-        )
-        t_taken = time.perf_counter()
+        if block:
+            # no slot is live: the engine thread sleeps in the queue
+            with self._span("generation.wait_for_work"):
+                batch = self._take(len(free))
+        else:
+            batch = self._take(len(free))
+        if batch:
+            # every live stream waits for this span: no decode step runs
+            # until the last admission returns
+            with self._span("generation.refill", taken=len(batch)) as sp:
+                self._admit_batch(my_gen, batch, sp.t0)
+
+    def _take(self, n: int) -> list:
+        return self.queue.take_batch(
+            n, linger_s=0.0, stop=self._stop, poll_s=self.config.poll_s)
+
+    def _admit_batch(self, my_gen: int, batch: list,
+                     t_taken: float) -> None:
         for req in batch:
             q0 = req.t_offer if req.t_offer is not None else req.t_submit
             wait = max(0.0, t_taken - q0)
@@ -923,7 +993,8 @@ class GenerationEngine:
             if not slot:                  # more takes than slots freed
                 self._offer_back(req)
                 continue
-            self._admit_to_slot(my_gen, slot[0], req)
+            with self._span("generation.admit_to_slot", slot=slot[0]):
+                self._admit_to_slot(my_gen, slot[0], req)
 
     def _offer_back(self, req: GenerationRequest) -> None:
         if not self.queue.offer(req):
@@ -961,20 +1032,19 @@ class GenerationEngine:
         try:
             if req.prefilled is None:
                 faults.maybe_fail("serving.prefill")
-                t_pre0 = time.perf_counter()
-                k, v, first, _ = self._run_prefill(req)
-                t_pre1 = time.perf_counter()
-                req.lat["prefill"] = t_pre1 - t_pre0
+                with self._span("generation.prefill", bucket=t_b) as sp:
+                    k, v, first, _ = self._run_prefill(req)
+                req.lat["prefill"] = sp.dur
                 self._trace_segment(req, "generation.prefill",
-                                    t_pre0, t_pre1 - t_pre0, bucket=t_b)
+                                    sp.t0, sp.dur, bucket=t_b)
                 hand_t0 = None
             else:
                 k, v = req.prefilled["k"], req.prefilled["v"]
                 first = req.prefilled["first_token"]
                 hand_t0 = req.prefilled.get("t_done_pc")
-            t_w0 = time.perf_counter()
-            tbl = self.kv.write_prefill(req.rid, k, v)
-            t_w1 = time.perf_counter()
+            with self._span("generation.kv_handoff") as sp:
+                tbl = self.kv.write_prefill(req.rid, k, v)
+            t_w1 = sp.t0 + sp.dur
             # cross-replica handoff spans from the PREFILL replica's
             # completion mark (perf_counter is comparable in-process);
             # the lat entry excludes the decode-side queue wait the
@@ -982,8 +1052,8 @@ class GenerationEngine:
             transfer = (max(0.0, req.t_offer - hand_t0)
                         if hand_t0 is not None and req.t_offer is not None
                         else 0.0)
-            req.lat["handoff"] = transfer + (t_w1 - t_w0)
-            span_t0 = hand_t0 if hand_t0 is not None else t_w0
+            req.lat["handoff"] = transfer + sp.dur
+            span_t0 = hand_t0 if hand_t0 is not None else sp.t0
             self._trace_segment(req, "generation.kv_handoff", span_t0,
                                 max(0.0, t_w1 - span_t0), pages=len(tbl))
         except Exception as exc:
@@ -1020,55 +1090,116 @@ class GenerationEngine:
         self._gauge_occupancy()
 
     def _decode_step(self, my_gen: int) -> None:
-        """One token for every live slot: fault consult -> params
-        snapshot (hot-swap boundary) -> watchdog-armed dispatch ->
-        harvest (stop conditions, page release, slot free)."""
+        """One dispatch for every live slot, as four spans in a row on
+        the engine thread with NO span around them (a gap of the device
+        between two steps straddles all four; a parent would take every
+        such gap for itself): ``generation.decode_prepare`` (fault
+        consult, drafts, argument copies, params snapshot — the hot-swap
+        boundary — watchdog arm) -> ``generation.decode_dispatch`` (the
+        jit call alone; annotated with the live slots and the KV rows
+        the step attends) -> ``generation.decode_readback`` (the
+        blocking ``np.asarray``) -> ``generation.harvest`` (stop
+        conditions, callbacks, page release, slot free, gauges)."""
+        with self._span("generation.decode_prepare"):
+            plan = self._prepare_step(my_gen)
+        if plan is None:
+            return
+        fn, params, args, n_live, rows, harvest = plan
+        try:
+            with self._span("generation.decode_dispatch",
+                            slots=n_live, rows=rows) as disp:
+                out = fn(params, self.kv.k_pages, self.kv.v_pages,
+                         self.kv.k_scales, self.kv.v_scales, *args)
+            with self._span("generation.decode_readback") as rb:
+                toks = np.asarray(out[4])
+        except Exception as exc:
+            self.watchdog.disarm(None)
+            self._step_failed(my_gen, exc)
+            return
+        with self._span("generation.harvest") as hv:
+            # decode_compute is exactly dispatch + readback
+            step_s = disp.dur + rb.dur
+            self.watchdog.disarm(step_s)
+            harvest(my_gen, out, toks, disp.t0, step_s, hv.t0)
+
+    def _prepare_step(self, my_gen: int):
+        """Everything between the loop's decision to step and the jit
+        call: returns ``(program, params, host args, live slots, KV rows
+        attended, harvest)`` — or None when a fault, a stale loop
+        generation or an injected failure ends the step here.  The
+        speculative verify program is picked when any stream drafted;
+        otherwise the plain one-token program (both are warm, so the mix
+        never compiles)."""
         try:
             faults.maybe_fail("serving.decode")
         except Exception as exc:
             self._step_failed(my_gen, exc)
-            return
+            return None
+        drafts = None
         if self.drafter is not None:
             drafts = self._gather_drafts(my_gen)
-            if drafts is not None:
-                self._verify_step(my_gen, drafts)
-                return
-            # nothing drafted (cold streams, rejection streak, per-
-            # request opt-outs, fault fallback): ride the plain
-            # one-token program — both programs are warm, so the mix
-            # never compiles
-            with self._stats_lock:
-                self._spec_counts["plain_dispatches"] += 1
-        if self._step_fn is None:
-            self._step_fn = self._make_step()
+            if drafts is None:
+                # nothing drafted (cold streams, rejection streak, per-
+                # request opt-outs, fault fallback)
+                with self._stats_lock:
+                    self._spec_counts["plain_dispatches"] += 1
+        c = 1 if drafts is None else self.spec_k + 1
         with self._mu:
             if self._loop_gen != my_gen:
-                return
-            args = (self._page_tbl.copy(), self._seq_lens.copy(),
-                    self._last_tok.copy(), self._seeds.copy(),
-                    self._gen_counts.copy(), self._temps.copy(),
+                return None
+            seq_lens = self._seq_lens.copy()
+            gen0 = self._gen_counts.copy()
+            if drafts is None:
+                toks_in = self._last_tok.copy()
+            else:
+                toks_in = np.zeros((self.config.slots, c), np.int32)
+                toks_in[:, 0] = self._last_tok
+                dl = np.zeros(self.config.slots, np.int32)
+                for s, d in enumerate(drafts):
+                    if d is None or d.size == 0:
+                        continue
+                    m = min(int(d.size), self.spec_k)
+                    toks_in[s, 1:1 + m] = d[:m]
+                    dl[s] = m
+            args = (self._page_tbl.copy(), seq_lens, toks_in,
+                    self._seeds.copy(), gen0, self._temps.copy(),
                     self._top_ks.copy())
+        if drafts is None:
+            if self._step_fn is None:
+                self._step_fn = self._make_step()
+            fn, harvest = self._step_fn, self._harvest_plain
+        else:
+            if self._verify_fn is None:
+                self._verify_fn = self._make_verify()
+            fn = self._verify_fn
+
+            def harvest(*a):
+                self._harvest_verify(*a, toks_in, dl, gen0)
+
         with self._weights_lock:
             # the hot-swap boundary: push_weights installs under this
             # lock, so a swap lands BETWEEN decode steps and in-flight
             # streams continue (on the new weights) with zero drops
             params = self.model.params
+        # what the step serves, from the arrays it is dispatched with:
+        # a slot is live where seq_len > 0, and attends its seq_len rows
+        # plus the c it writes (a verify chunk's union, capped at the
+        # page table's span)
+        live = seq_lens[seq_lens > 0]
+        n_live = int(live.size)
+        cap = self.config.max_pages_per_seq * self.kv.page_size
+        rows = int(np.minimum(live + c, cap).sum())
         self._steps += 1
-        self.watchdog.arm(self._steps)
-        t0 = time.perf_counter()
-        try:
-            out = self._step_fn(
-                params, self.kv.k_pages, self.kv.v_pages,
-                self.kv.k_scales, self.kv.v_scales, *args,
-            )
-            nxt = np.asarray(out[4])
-        except Exception as exc:
-            self.watchdog.disarm(None)
-            self._step_failed(my_gen, exc)
-            return
-        step_s = time.perf_counter() - t0
-        self.watchdog.disarm(step_s)
-        t_h0 = time.perf_counter()
+        self._slot_steps += n_live
+        self._rows_attended += rows
+        # the watchdog arms with the chunk width so the EWMA deadline
+        # stays per-token-normalized
+        self.watchdog.arm(self._steps, n_steps=c)
+        return fn, params, args, n_live, rows, harvest
+
+    def _harvest_plain(self, my_gen: int, out, nxt, t0: float,
+                       step_s: float, t_h0: float) -> None:
+        """One token for every live slot of a plain step."""
         with self._mu:
             if self._loop_gen != my_gen:
                 return                     # wedged + respawned: stale
@@ -1107,17 +1238,25 @@ class GenerationEngine:
                         step=self._steps, batch=rids,
                         batch_tokens=counts,
                     )
+        self._charge_step([r for r, _ in stepped], step_s, t_h0)
+        self._count_tokens(n_live)
+        self._settle_finished(finished)
+
+    def _charge_step(self, reqs: list, step_s: float, t_h0: float) -> None:
+        """Each co-resident stream is charged the full step wall (like
+        the shared dispatch segment of /v1/infer; the per-token view
+        divides by tokens_generated in stats()) plus the host-side
+        sampling bookkeeping: the harvest from its start (`t_h0`) to
+        here, before the finishing work the harvest span also covers."""
         samp_s = max(0.0, time.perf_counter() - t_h0)
-        for req, _ in stepped:
-            # each co-resident stream is charged the full step wall
-            # (like the shared dispatch segment of /v1/infer) plus the
-            # host-side harvest/sampling bookkeeping
+        for req in reqs:
             req.lat["decode_compute"] = (
                 req.lat.get("decode_compute", 0.0) + step_s)
             req.lat["sampling"] = req.lat.get("sampling", 0.0) + samp_s
         if self.breaker is not None:
             self.breaker.record_success()
-        self._count_tokens(n_live)
+
+    def _settle_finished(self, finished: list) -> None:
         for req, ok in finished:
             self.kv.release(req.rid)
             if ok:
@@ -1208,53 +1347,13 @@ class GenerationEngine:
                 if self._slot_req[s] is req:
                     self._page_tbl[s, req.pages:] = SCRATCH_PAGE
 
-    def _verify_step(self, my_gen: int, drafts: list) -> None:
-        """One verify-once dispatch: score the (spec_k + 1)-token chunk
-        for every live slot, then emit each stream's accepted draft
-        prefix plus the corrected/bonus sample — 1..k+1 tokens per
-        stream, byte-identical to sequential plain decode.  Mirrors
-        `_decode_step`'s structure (fault consult already happened);
-        the watchdog arms with the chunk width so the EWMA deadline
-        stays per-token-normalized."""
-        if self._verify_fn is None:
-            self._verify_fn = self._make_verify()
-        c = self.spec_k + 1
-        n_slots = self.config.slots
-        with self._mu:
-            if self._loop_gen != my_gen:
-                return
-            chunk = np.zeros((n_slots, c), np.int32)
-            chunk[:, 0] = self._last_tok
-            dl = np.zeros(n_slots, np.int32)
-            for s in range(n_slots):
-                d = drafts[s]
-                if d is None or d.size == 0:
-                    continue
-                m = min(int(d.size), self.spec_k)
-                chunk[s, 1:1 + m] = d[:m]
-                dl[s] = m
-            gen0 = self._gen_counts.copy()
-            args = (self._page_tbl.copy(), self._seq_lens.copy(),
-                    chunk, self._seeds.copy(), gen0,
-                    self._temps.copy(), self._top_ks.copy())
-        with self._weights_lock:
-            params = self.model.params
-        self._steps += 1
-        self.watchdog.arm(self._steps, n_steps=c)
-        t0 = time.perf_counter()
-        try:
-            out = self._verify_fn(
-                params, self.kv.k_pages, self.kv.v_pages,
-                self.kv.k_scales, self.kv.v_scales, *args,
-            )
-            tgt = np.asarray(out[4])
-        except Exception as exc:
-            self.watchdog.disarm(None)
-            self._step_failed(my_gen, exc)
-            return
-        step_s = time.perf_counter() - t0
-        self.watchdog.disarm(step_s)
-        t_h0 = time.perf_counter()
+    def _harvest_verify(self, my_gen: int, out, tgt, t0: float,
+                        step_s: float, t_h0: float, chunk, dl,
+                        gen0) -> None:
+        """The verify-once dispatch scored the (spec_k + 1)-token chunk
+        of every live slot: emit each stream's accepted draft prefix
+        plus the corrected/bonus sample — 1..k+1 tokens per stream,
+        byte-identical to sequential plain decode."""
         sp = {"drafted": 0, "accepted": 0, "rejected": 0, "bonus": 0}
         emitted_total = 0
         with self._mu:
@@ -1319,26 +1418,10 @@ class GenerationEngine:
                         batch_tokens=counts, emitted=emits,
                         speculative=True,
                     )
-        samp_s = max(0.0, time.perf_counter() - t_h0)
-        for req, _, _ in stepped:
-            # same attribution semantics as the plain step: every co-
-            # resident stream is charged the full dispatch wall (the
-            # per-token view divides by tokens_generated in stats())
-            req.lat["decode_compute"] = (
-                req.lat.get("decode_compute", 0.0) + step_s)
-            req.lat["sampling"] = req.lat.get("sampling", 0.0) + samp_s
-        if self.breaker is not None:
-            self.breaker.record_success()
+        self._charge_step([r for r, _, _ in stepped], step_s, t_h0)
         self._count_tokens(emitted_total)
         self._count_spec(sp, emitted_total)
-        for req, ok in finished:
-            self.kv.release(req.rid)
-            if ok:
-                self._finish(req, "ok")
-            else:
-                self._finish(req, "cancelled",
-                             ServingRejected("shutdown", "cancelled"))
-        self._gauge_occupancy()
+        self._settle_finished(finished)
 
     def _count_spec(self, sp: dict, emitted: int) -> None:
         """One verify dispatch's speculative accounting: host counters
@@ -1594,6 +1677,8 @@ class GenerationEngine:
             "active_streams": active,
             "queue_depth": self.queue.depth,
             "decode_steps": self._steps,
+            "decode_slot_steps": self._slot_steps,
+            "decode_rows_attended": self._rows_attended,
             "tokens_generated": self._tokens_out,
             "tokens_per_s": round(self.tokens_per_s(), 4),
             "streams": {"settled": settled, "outcomes": outcomes},
@@ -1676,6 +1761,23 @@ class GenerationEngine:
                 round(self.tokens_per_s(), 4))
         except Exception as e:
             log.debug("decode token metric failed: %s", e)
+
+    def _flush_decode_counts(self) -> None:
+        """Move what this engine counted since the last flush into the
+        process-total counters (scrape time, and once more at stop())."""
+        try:
+            from deeplearning4j_tpu.observe.metrics import registry
+
+            reg = registry()
+            with self._stats_lock:
+                now = (self._steps, self._slot_steps, self._rows_attended)
+                delta = [a - b for a, b in zip(now, self._counts_flushed)]
+                self._counts_flushed = now
+            for family, d in zip(DECODE_COUNT_FAMILIES, delta):
+                if d > 0:
+                    reg.counter(family).inc(d)
+        except Exception as e:
+            log.debug("decode count flush failed: %s", e)
 
     def _count_stream(self, outcome: str) -> None:
         """One settled (or synchronously rejected) stream, by outcome —

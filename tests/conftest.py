@@ -51,3 +51,55 @@ def _crash_artifacts_dir(tmp_path, monkeypatch):
     post-mortem dumps by design now, including from tests that induce
     them."""
     monkeypatch.setenv("DL4JTPU_CRASH_DIR", str(tmp_path / "crash"))
+
+
+class HostProfile:
+    """A CPU `jax.profiler` session around a block, read back as the
+    host plane's lines: ``with host_profile() as prof: ...`` then
+    `prof.lines` is a list (one entry per thread line of "/host:CPU") of
+    event dicts ``{"name", "start", "end", "stats"}`` (nanoseconds),
+    sorted by start.  Runtime TraceMe events only, as the benchmark's
+    traced runs record them (`python_tracer_level = 0`)."""
+
+    def __init__(self, log_dir: str):
+        self.log_dir = log_dir
+        self.lines: list = []
+
+    def __enter__(self):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(self.log_dir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        import glob
+        import warnings
+
+        jax.profiler.stop_trace()
+        path = sorted(glob.glob(os.path.join(
+            self.log_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        with warnings.catch_warnings():
+            # this jaxlib's stats iterator warns about its own type
+            warnings.simplefilter("ignore", DeprecationWarning)
+            for plane in jax.profiler.ProfileData.from_file(path).planes:
+                if not plane.name.startswith("/host:CPU"):
+                    continue
+                for line in plane.lines:
+                    self.lines.append(sorted(
+                        ({"name": e.name, "start": e.start_ns,
+                          "end": e.start_ns + e.duration_ns,
+                          "stats": dict(e.stats)} for e in line.events),
+                        key=lambda e: e["start"]))
+        return False
+
+    def line_with(self, name: str) -> list:
+        """The one thread line that holds events called `name`."""
+        found = [ln for ln in self.lines
+                 if any(e["name"] == name for e in ln)]
+        assert len(found) == 1, (name, len(found))
+        return found[0]
+
+
+@pytest.fixture
+def host_profile(tmp_path):
+    return lambda: HostProfile(str(tmp_path / "profile"))

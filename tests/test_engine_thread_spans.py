@@ -1,0 +1,301 @@
+"""ISSUE 25 — the engine thread on the profiler's clock.
+
+Every phase of the decode loop is a recorder span, so a `jax.profiler`
+session (here on the CPU, ring DISABLED) shows them on the engine
+thread's line of "/host:CPU": the admission spans nested, the step's
+four spans in a row, and the thread's time tiled without holes.  Each
+step also counts the slots and the KV rows it served."""
+
+import time
+
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.observe import tracer
+from deeplearning4j_tpu.observe.metrics import registry
+from deeplearning4j_tpu.serving import generation as gen_mod
+from deeplearning4j_tpu.serving.generation import (
+    DECODE_COUNT_FAMILIES,
+    GEN_BREAKDOWN_SEGMENTS,
+    GenerationConfig,
+    GenerationEngine,
+)
+from deeplearning4j_tpu.zoo.transformer import TransformerEncoder
+
+pytestmark = pytest.mark.generation
+
+VOCAB = 31
+CFG = dict(slots=4, page_size=8, num_pages=64, max_pages_per_seq=4,
+           max_queue=16, default_max_new=8)
+
+STEP_SPANS = ["generation.decode_prepare", "generation.decode_dispatch",
+              "generation.decode_readback", "generation.harvest"]
+ADMIT_SPANS = ["generation.refill", "generation.admit_to_slot",
+               "generation.prefill", "generation.kv_handoff"]
+TOP_LEVEL = set(STEP_SPANS) | {"generation.wait_for_work",
+                               "generation.refill"}
+
+
+@pytest.fixture(scope="module")
+def model():
+    return TransformerEncoder(
+        vocab_size=VOCAB, d_model=16, n_heads=2, n_layers=2,
+        causal=True, seed=5,
+    ).init_model()
+
+
+def _engine(model, **over):
+    return GenerationEngine(
+        model=model, config=GenerationConfig(**{**CFG, **over}))
+
+
+def _prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, VOCAB, n).astype(np.int32)
+
+
+def _inside(inner, outer):
+    return outer["start"] <= inner["start"] and inner["end"] <= outer["end"]
+
+
+@pytest.fixture(scope="module")
+def engine_line(model, tmp_path_factory):
+    """The engine thread's line of one profiler session: a warm engine
+    (no compile inside the session) serves 3 streams with the ring
+    disabled, then stops."""
+    from conftest import HostProfile
+
+    assert not tracer().enabled
+    # streams long enough (39 steps) that one preemption of the thread
+    # between two spans stays a small share of the interval
+    eng = _engine(model, max_pages_per_seq=8).start()
+    try:
+        eng.generate(_prompt(5, seed=9), 3, timeout=120.0)     # warm
+        with HostProfile(str(tmp_path_factory.mktemp("prof"))) as prof:
+            reqs = [eng.submit(_prompt(4 + i, seed=i), 20 + 10 * i)
+                    for i in range(3)]
+            for r in reqs:
+                r.result(120.0)
+            time.sleep(0.05)        # the idle loop goes back to sleep
+            eng.stop()
+    finally:
+        eng.stop()
+    # ONE line holds every generation span: the engine thread's (named
+    # after the process, not the thread — found by what it holds)
+    line = prof.line_with("generation.decode_dispatch")
+    for other in prof.lines:
+        if other is not line:
+            assert not any(e["name"].startswith("generation.")
+                           for e in other)
+    return [e for e in line if e["name"].startswith("generation.")]
+
+
+class TestEngineThreadOnTheProfilersClock:
+    def test_every_span_of_the_loop_is_on_the_engine_line(self, engine_line):
+        names = {e["name"] for e in engine_line}
+        assert names == set(STEP_SPANS) | set(ADMIT_SPANS) | {
+            "generation.wait_for_work"}
+
+    def test_step_spans_come_in_order_and_disjoint(self, engine_line):
+        steps = [e for e in engine_line if e["name"] in STEP_SPANS]
+        assert len(steps) >= 4 * 39         # the longest stream: 39 steps
+        assert len(steps) % 4 == 0
+        for i, e in enumerate(steps):
+            assert e["name"] == STEP_SPANS[i % 4]
+            if i:
+                assert steps[i - 1]["end"] <= e["start"]
+        # no span lies around a step: its four are top-level
+        for e in steps:
+            assert not any(o is not e and _inside(e, o)
+                           for o in engine_line)
+
+    def test_admission_spans_nest(self, engine_line):
+        by = {n: [e for e in engine_line if e["name"] == n]
+              for n in ADMIT_SPANS}
+        assert len(by["generation.admit_to_slot"]) == 3
+        assert len(by["generation.prefill"]) == 3
+        assert len(by["generation.kv_handoff"]) == 3
+        for adm in by["generation.admit_to_slot"]:
+            assert sum(_inside(adm, r) for r in by["generation.refill"]) == 1
+            pre = [p for p in by["generation.prefill"] if _inside(p, adm)]
+            hand = [h for h in by["generation.kv_handoff"]
+                    if _inside(h, adm)]
+            assert len(pre) == 1 and len(hand) == 1
+            assert pre[0]["end"] <= hand[0]["start"]
+            assert "slot" in adm["stats"]
+        assert all("bucket" in p["stats"] for p in by["generation.prefill"])
+        assert all(r["stats"]["taken"] >= 1
+                   for r in by["generation.refill"])
+
+    def test_dispatch_says_what_the_step_served(self, engine_line):
+        disp = [e for e in engine_line
+                if e["name"] == "generation.decode_dispatch"]
+        for e in disp:
+            slots, rows = int(e["stats"]["slots"]), int(e["stats"]["rows"])
+            assert 1 <= slots <= CFG["slots"]
+            # every live slot attends its prompt and the row it writes
+            assert rows >= slots * (4 + 1)
+        assert max(int(e["stats"]["slots"]) for e in disp) >= 2
+
+    def test_top_level_spans_tile_the_thread(self, engine_line):
+        """The guard against a later unmarked phase: between the first
+        admission and the last finish, what no top-level span covers is
+        under 5 % of the interval."""
+        top = [e for e in engine_line if e["name"] in TOP_LEVEL]
+        first = min(e["start"] for e in top
+                    if e["name"] == "generation.refill")
+        last = max(e["end"] for e in top
+                   if e["name"] == "generation.harvest")
+        inside = [e for e in top if e["start"] >= first and e["end"] <= last]
+        holes = sum(max(0.0, b["start"] - a["end"])
+                    for a, b in zip(inside, inside[1:]))
+        assert inside[0]["start"] == first and inside[-1]["end"] == last
+        assert holes < 0.05 * (last - first), (holes, last - first)
+
+
+class TestRingAndLatencyKeepTheirMeaning:
+    def test_chains_and_six_segments_with_the_ring_on(self, model):
+        rec = tracer()
+        eng = _engine(model).start()
+        try:
+            eng.generate(_prompt(5, seed=9), 3, timeout=120.0)  # warm
+            rec.enable()
+            rec.clear()
+            reqs = [eng.submit(_prompt(4 + i, seed=i), 6 + 2 * i)
+                    for i in range(3)]
+            for r in reqs:
+                r.result(120.0)
+            alone = eng.submit(_prompt(6, seed=7), 8)
+            alone.result(120.0)
+            reqs.append(alone)
+            wall = {e["rid"]: e["latency_s"]
+                    for e in eng.slow_streams(spans=False)}
+        finally:
+            eng.stop()
+            rec.disable()
+        ring = [e for e in rec.to_chrome_trace()["traceEvents"]
+                if e["ph"] == "X"]
+        rec.clear()
+        for r in reqs:
+            chain = rec_chain(ring, r.trace_id)
+            names = [e["name"] for e in chain]
+            assert names.count("generation.stream") == 1
+            assert names.count("generation.admit") == 1
+            assert names.count("generation.prefill") == 1
+            assert names.count("generation.kv_handoff") == 1
+            assert names.count("generation.decode_step") == r.max_new - 1
+            assert set(GEN_BREAKDOWN_SEGMENTS) == set(r.lat)
+            # the six segments never claim more than the stream's wall
+            assert sum(r.lat.values()) <= wall[r.rid] + 1e-3
+        # ... and account for it where the stream was admitted alone
+        # (in a shared refill a stream also waits, unattributed, for
+        # the admissions before its own)
+        assert sum(alone.lat.values()) >= 0.9 * wall[alone.rid]
+        # one timing per phase: the engine-thread span, the stream's
+        # chain entry and its `lat` segment are the same number
+        for name, seg in (("generation.prefill", "prefill"),
+                          ("generation.kv_handoff", "handoff")):
+            on_thread = sorted(e["dur"] for e in ring if e["name"] == name
+                               and e["cat"] == "engine")
+            in_chains = sorted(e["dur"] for e in ring if e["name"] == name
+                               and e["cat"] == "generation")
+            assert len(on_thread) == 4 and on_thread == in_chains
+            assert sorted(round(r.lat[seg] * 1e6, 3)
+                          for r in reqs) == on_thread
+        # decode_compute = dispatch + readback of the steps it rode
+        disp = sum(e["dur"] for e in ring if e["name"] in (
+            "generation.decode_dispatch", "generation.decode_readback"))
+        steps = {e["args"]["step"]: e["dur"] for e in ring
+                 if e["name"] == "generation.decode_step"}
+        assert abs(sum(steps.values()) - disp) < 1.0      # microseconds
+
+
+def rec_chain(ring, trace_id):
+    return [e for e in ring
+            if (e.get("args") or {}).get("trace") == trace_id]
+
+
+class _Oracle:
+    """A drafter that knows the greedy continuation of every stream."""
+
+    name = "oracle"
+
+    def __init__(self, rows):
+        self.rows = [np.asarray(r, np.int32) for r in rows]
+
+    def draft(self, hist, k):
+        for row in self.rows:
+            if len(row) > len(hist) and np.array_equal(
+                    row[:len(hist)], hist):
+                return row[len(hist):len(hist) + k]
+        return np.zeros(0, np.int32)
+
+
+def _run_scripted(eng, prompts, max_news):
+    """Both streams are queued BEFORE the loop starts, so one refill
+    admits both and the schedule is fixed by the lengths alone."""
+    reg = registry()
+    reg.collect()
+    before = [reg.counter(f).value() for f in DECODE_COUNT_FAMILIES]
+    reqs = [eng.submit(p, n) for p, n in zip(prompts, max_news)]
+    eng.start()
+    try:
+        rows = [np.asarray(r.result(120.0)) for r in reqs]
+        st = eng.stats()
+    finally:
+        eng.stop()
+    reg.collect()
+    delta = [reg.counter(f).value() - b
+             for f, b in zip(DECODE_COUNT_FAMILIES, before)]
+    counted = (st["decode_steps"], st["decode_slot_steps"],
+               st["decode_rows_attended"])
+    assert tuple(delta) == counted
+    return rows, counted
+
+
+class TestStepCounts:
+    PROMPTS = (5, 3)
+    MAX_NEW = (9, 5)
+
+    def test_plain_and_speculative_steps_against_a_hand_count(self, model):
+        prompts = [_prompt(n, seed=20 + n) for n in self.PROMPTS]
+        rows, counted = _run_scripted(_engine(model), prompts, self.MAX_NEW)
+        # plain: a stream of max_new n rides n - 1 steps; the step at
+        # seq_len t attends t + 1 rows (the row it writes counts).
+        # A: 8 steps, rows 6..13 = 76.  B: 4 steps, rows 4..7 = 22.
+        assert counted == (8, 8 + 4, 76 + 22)
+
+        eng = _engine(model, spec_k=3)
+        eng.drafter = _Oracle(rows)
+        spec_rows, counted = _run_scripted(eng, prompts, self.MAX_NEW)
+        for a, b in zip(rows, spec_rows):
+            assert np.array_equal(a, b)
+        # every draft is accepted, so a chunk of C = 4 emits 4 tokens.
+        # step 1: A at seq_len 5 and B at 3 attend 5 + 4 and 3 + 4 rows
+        # (each slot's rows once); B is done (1 + 4 = 5 tokens).
+        # step 2: A alone at seq_len 9 attends 13; done (1 + 4 + 4).
+        assert counted == (2, 2 + 1, (9 + 7) + 13)
+        assert eng.stats()["speculative"]["accepted"] == 9
+
+    def test_counts_are_declared_and_engines_sum(self, model):
+        reg = registry()
+        text = reg.to_prometheus_text()
+        for fam in DECODE_COUNT_FAMILIES:
+            assert f"# TYPE {fam} counter" in text
+        reg.collect()
+        before = [reg.counter(f).value() for f in DECODE_COUNT_FAMILIES]
+        engines = [_engine(model).start() for _ in range(2)]
+        try:
+            for eng in engines:
+                eng.generate(_prompt(4, seed=1), 3, timeout=120.0)
+            reg.collect()       # live engines: the collector pulls
+            mid = [reg.counter(f).value() for f in DECODE_COUNT_FAMILIES]
+        finally:
+            for eng in engines:
+                eng.stop()
+        reg.collect()           # stopped engines flushed, nothing twice
+        after = [reg.counter(f).value() for f in DECODE_COUNT_FAMILIES]
+        # each engine: one stream of 3 tokens = 2 one-slot steps, 5 + 6
+        assert [m - b for m, b in zip(mid, before)] == [4, 4, 22]
+        assert after == mid
+        assert gen_mod._collect_decode_counts in reg._collectors

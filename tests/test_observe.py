@@ -253,19 +253,127 @@ class TestTraceRecorder:
         rec.add_complete("nope", 0.0, 1.0)
         assert len(rec) == 0
 
-    def test_decorator_and_save(self, tmp_path):
+    def test_save_roundtrip(self, tmp_path):
         rec = TraceRecorder().enable()
-
-        @rec.traced()
-        def work():
-            return 7
-
-        assert work() == 7
+        with rec.span("work", cat="func"):
+            pass
         path = rec.save(str(tmp_path / "trace.json"))
         import pathlib
 
         obj = json.loads(pathlib.Path(path).read_text())
         assert any("work" in e["name"] for e in obj["traceEvents"])
+
+    def test_span_keeps_its_one_timing(self):
+        """A call site that needs the number reads it off the span: the
+        ring entry is that same timing, and a disabled ring still
+        times."""
+        rec = TraceRecorder()
+        with rec.span("quiet") as sp:
+            pass
+        assert len(rec) == 0 and sp.dur >= 0.0 and sp.t0 > 0.0
+        rec.enable()
+        with rec.span("loud", cat="test", n=3) as sp:
+            pass
+        (ev,) = rec.to_chrome_trace()["traceEvents"]
+        assert ev["ts"] == round(sp.t0 * 1e6, 3)
+        assert ev["dur"] == round(sp.dur * 1e6, 3)
+        assert ev["args"] == {"n": 3}
+
+    def test_span_records_on_error_and_does_not_swallow(self):
+        rec = TraceRecorder().enable()
+        with pytest.raises(KeyError):
+            with rec.span("boom") as sp:
+                raise KeyError("x")
+        assert sp.dur is not None and len(rec) == 1
+
+
+class TestSpansAreProfilerAnnotations:
+    """One span, two sinks: whenever a `jax.profiler` session records,
+    a recorder span is an event on its host plane — ring or no ring,
+    and nothing to switch."""
+
+    def test_ring_disabled_span_is_a_profiler_event(self, host_profile):
+        rec = TraceRecorder()
+
+        def work():
+            with rec.span("outer_phase", cat="t", bucket=8):
+                with rec.span("inner_phase"):
+                    pass
+
+        with host_profile() as prof:
+            t = threading.Thread(target=work)
+            t.start()
+            t.join()
+            with rec.span("main_thread_phase"):
+                pass
+        assert len(rec) == 0
+        line = prof.line_with("outer_phase")
+        outer = next(e for e in line if e["name"] == "outer_phase")
+        inner = next(e for e in line if e["name"] == "inner_phase")
+        # the name is bare, the span's args are the event's stats
+        assert outer["stats"] == {"bucket": 8}
+        assert outer["start"] <= inner["start"] <= inner["end"] \
+            <= outer["end"]
+        # a line per thread: the main thread's span is on another
+        assert prof.line_with("main_thread_phase") is not line
+
+    def test_ring_enabled_span_feeds_both_sinks(self, host_profile):
+        rec = TraceRecorder().enable()
+        with host_profile() as prof:
+            with rec.span("both_sinks", cat="t", k=2) as sp:
+                pass
+        (ev,) = rec.to_chrome_trace()["traceEvents"]
+        assert ev["name"] == "both_sinks" and ev["args"] == {"k": 2}
+        (pe,) = [e for e in prof.line_with("both_sinks")
+                 if e["name"] == "both_sinks"]
+        assert pe["stats"] == {"k": 2}
+        # one enter/exit: the two sinks agree on the duration to within
+        # the few hundred nanoseconds between their clock reads
+        assert abs((pe["end"] - pe["start"]) * 1e-9 - sp.dur) < 1e-4
+
+    def test_add_complete_stays_ring_only(self, host_profile):
+        rec = TraceRecorder().enable()
+        with host_profile() as prof:
+            rec.add_complete("measured_elsewhere", 1.0, 0.5)
+            with rec.span("marker"):
+                pass
+        assert len(rec) == 2
+        assert not any(e["name"] == "measured_elsewhere"
+                       for ln in prof.lines for e in ln)
+
+    def test_fit_phases_appear_with_the_ring_off(self, host_profile,
+                                                 monkeypatch):
+        import jax
+
+        rec = tracer()
+        assert not rec.enabled
+        blocked = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: blocked.append(1) or real(x))
+        from deeplearning4j_tpu.observe.trace import StepScope
+
+        m = small_model()
+        m.fit([batch(0)], epochs=1)            # compile outside
+        with host_profile() as prof:
+            m.fit([batch(i) for i in range(3)], epochs=1)
+            # a session alone never makes the step synchronous
+            with StepScope(0) as scope:
+                scope.sync(np.zeros(1))
+        assert len(rec) == 0 and not blocked
+        line = prof.line_with("device_sync")
+        names = [e["name"] for e in line]
+        for phase in ("host_stage", "dispatch", "device_sync"):
+            assert names.count(phase) == 3, phase
+        # ... the ring does
+        rec.enable()
+        try:
+            with StepScope(0) as scope:
+                scope.sync(np.zeros(1))
+        finally:
+            rec.disable()
+            rec.clear()
+        assert blocked == [1]
 
 
 class TestStepTimeline:
