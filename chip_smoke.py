@@ -434,26 +434,50 @@ def phase_kernels(*, flash_shape, paged, dequant_kn, dequant_ms=(1, 8, 256),
         return pa.paged_attention(*a, impl="pallas", interpret=interpret,
                                   **kw)
 
-    close("paged f32", jax.jit(paged_kernel)(pq, kp, vp, tbl, lens),
-          highest(pa._xla_paged_attention, pq, kp, vp, tbl, lens),
+    # each case twice: the (P, ps, H, Dh) pool, and the form the serving
+    # step uses — layer 1 of a whole (L, P, ps, H, Dh) stack, read in
+    # place (layer 0 holds the OTHER pool's values)
+    def stacked(a, b):
+        return jnp.stack([b, a])
+
+    want = highest(pa._xla_paged_attention, pq, kp, vp, tbl, lens)
+    close("paged f32", jax.jit(paged_kernel)(pq, kp, vp, tbl, lens), want,
           atol=PAGED_ATOL)
+    close("paged f32 layer-indexed",
+          jax.jit(lambda *a: paged_kernel(*a, layer=1))(
+              pq, stacked(kp, vp), stacked(vp, kp), tbl, lens),
+          want, atol=PAGED_ATOL)
     (kq, ks), (vq, vs) = quantize_page_rows(kp), quantize_page_rows(vp)
+
+    def int8_kernel(q, k, v, t, n, a, b, **kw):
+        return paged_kernel(q, k, v, t, n, k_scale=a, v_scale=b, **kw)
+
+    want = highest(pa._xla_paged_attention, pq, kq, vq, tbl, lens, ks, vs)
     close("paged int8",
-          jax.jit(lambda q, k, v, t, n, a, b: paged_kernel(
-              q, k, v, t, n, k_scale=a, v_scale=b))(
-                  pq, kq, vq, tbl, lens, ks, vs),
-          highest(pa._xla_paged_attention, pq, kq, vq, tbl, lens, ks, vs),
+          jax.jit(int8_kernel)(pq, kq, vq, tbl, lens, ks, vs), want,
           atol=PAGED_ATOL)
+    close("paged int8 layer-indexed",
+          jax.jit(lambda *a: int8_kernel(*a, layer=1))(
+              pq, stacked(kq, vq), stacked(vq, kq), tbl, lens,
+              stacked(ks, vs), stacked(vs, ks)),
+          want, atol=PAGED_ATOL)
     cq = jnp.asarray(rng.normal(size=(s_, chunk, hp, dh)), jnp.float32)
     attend = jnp.where(
         lens[:, None] > 0,
         jnp.minimum(lens[:, None] + jnp.arange(chunk)[None, :] + 1, cap), 0)
+
+    def chunk_kernel(*a, **kw):
+        return pa.paged_attention_chunk(*a, impl="pallas",
+                                        interpret=interpret, **kw)
+
+    want = highest(pa._xla_paged_attention_chunk, cq, kp, vp, tbl, attend)
     close(f"paged chunk C={chunk}",
-          jax.jit(lambda *a: pa.paged_attention_chunk(
-              *a, impl="pallas", interpret=interpret))(
-                  cq, kp, vp, tbl, attend),
-          highest(pa._xla_paged_attention_chunk, cq, kp, vp, tbl, attend),
+          jax.jit(chunk_kernel)(cq, kp, vp, tbl, attend), want,
           atol=PAGED_ATOL)
+    close(f"paged chunk C={chunk} layer-indexed",
+          jax.jit(lambda *a: chunk_kernel(*a, layer=1))(
+              cq, stacked(kp, vp), stacked(vp, kp), tbl, attend),
+          want, atol=PAGED_ATOL)
 
     # fused dequant-matmul
     kk, nn = dequant_kn

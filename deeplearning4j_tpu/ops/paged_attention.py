@@ -84,27 +84,37 @@ def select_impl() -> str:
 
 # -- xla gather reference ---------------------------------------------------
 
-def _gather_pages(pages, page_tbl):
+def _gather_pages(pages, page_tbl, layer=None):
     """(P, ps, ...) pool + (S, maxP) table -> (S, maxP*ps, ...) dense
     view of each slot's sequence (garbage rows past seq_len are masked
-    by the caller)."""
-    g = pages[page_tbl]                       # (S, maxP, ps, ...)
-    s, mp, ps = g.shape[0], g.shape[1], g.shape[2]
+    by the caller).  With ``layer`` the pool is the whole (L, P, ps, ...)
+    stack and the ONE gather takes that layer's pages out of it: the
+    layer is never sliced out first."""
+    g = pages[page_tbl] if layer is None else pages[layer, page_tbl]
+    s, mp, ps = g.shape[0], g.shape[1], g.shape[2]   # (S, maxP, ps, ...)
     return g.reshape((s, mp * ps) + g.shape[3:])
 
 
-def _xla_paged_attention(q, k_pages, v_pages, page_tbl, seq_lens,
-                         k_scale=None, v_scale=None):
-    """Gather-then-attend: `_block_step`'s exact numerics against the
-    page-table-indexed view.  q: (S, H, Dh); pools: (P, ps, H, Dh);
-    int8 pools carry (P, ps, H) per-row scale blocks."""
-    dh = q.shape[-1]
-    k = _gather_pages(k_pages, page_tbl).astype(jnp.float32)
-    v = _gather_pages(v_pages, page_tbl).astype(jnp.float32)
+def _gather_kv(k_pages, v_pages, page_tbl, k_scale, v_scale, layer):
+    """Both pools' dense f32 views, int8 pages dequantized by their
+    per-row scale blocks."""
+    k = _gather_pages(k_pages, page_tbl, layer).astype(jnp.float32)
+    v = _gather_pages(v_pages, page_tbl, layer).astype(jnp.float32)
     if k_scale is not None:
-        k = k * _gather_pages(k_scale, page_tbl)[..., None]
+        k = k * _gather_pages(k_scale, page_tbl, layer)[..., None]
     if v_scale is not None:
-        v = v * _gather_pages(v_scale, page_tbl)[..., None]
+        v = v * _gather_pages(v_scale, page_tbl, layer)[..., None]
+    return k, v
+
+
+def _xla_paged_attention(q, k_pages, v_pages, page_tbl, seq_lens,
+                         k_scale=None, v_scale=None, layer=None):
+    """Gather-then-attend: `_block_step`'s exact numerics against the
+    page-table-indexed view.  q: (S, H, Dh); pools: (P, ps, H, Dh), or
+    (L, P, ps, H, Dh) with ``layer``; int8 pools carry (..., P, ps, H)
+    per-row scale blocks."""
+    dh = q.shape[-1]
+    k, v = _gather_kv(k_pages, v_pages, page_tbl, k_scale, v_scale, layer)
     ell = k.shape[1]
     scores = jnp.einsum(
         "shd,slhd->shl", q.astype(jnp.float32), k
@@ -203,20 +213,28 @@ def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _pallas_paged_attention(q, k_pages, v_pages, page_tbl, seq_lens,
-                            k_scale=None, v_scale=None, *,
+                            k_scale=None, v_scale=None, layer=None, *,
                             interpret: bool):
     s, h, dh = q.shape
     n_pages = page_tbl.shape[1]
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[-3]
     quant = k_scale is not None
     # page blocks are selected by the scalar-prefetched table: grid step
     # (s, j) DMAs pool page page_tbl[s, j] — the gather never exists in
-    # HBM
+    # HBM.  Given the whole (L, P, ...) stack, the static `layer` is one
+    # more block index (a squeezed leading dim): the operand is the pool
+    # itself, the body sees the same (1, page_size, H, Dh) block
+    if layer is None:
+        lead, at = (), ()
+    else:
+        lead, at = (None,), (layer,)
     page_spec = pl.BlockSpec(
-        (1, page_size, h, dh), lambda s_, j, tbl, lens: (tbl[s_, j], 0, 0, 0),
+        lead + (1, page_size, h, dh),
+        lambda s_, j, tbl, lens: at + (tbl[s_, j], 0, 0, 0),
     )
     scale_spec = pl.BlockSpec(
-        (1, page_size, h), lambda s_, j, tbl, lens: (tbl[s_, j], 0, 0),
+        lead + (1, page_size, h),
+        lambda s_, j, tbl, lens: at + (tbl[s_, j], 0, 0),
     )
     row_spec = pl.BlockSpec((1, h, dh), lambda s_, j, tbl, lens: (s_, 0, 0))
     in_specs = [row_spec, page_spec, page_spec]
@@ -251,8 +269,22 @@ def _pallas_paged_attention(q, k_pages, v_pages, page_tbl, seq_lens,
 
 # -- dispatch ---------------------------------------------------------------
 
+def _check_call(k_pages, k_scale, v_scale, layer) -> bool:
+    """Shared argument check of the two entry points; returns whether
+    the pool is int8 (carries scales)."""
+    quant = k_scale is not None
+    if quant != (v_scale is not None):
+        raise ValueError("int8 pages need BOTH k_scale and v_scale")
+    if k_pages.ndim != (4 if layer is None else 5):
+        raise ValueError(
+            f"pool of rank {k_pages.ndim}: pass (P, page_size, H, Dh), or "
+            "the (L, P, page_size, H, Dh) stack together with layer=")
+    return quant
+
+
 def _xla_paged_attention_chunk(q, k_pages, v_pages, page_tbl,
-                               attend_lens, k_scale=None, v_scale=None):
+                               attend_lens, k_scale=None, v_scale=None,
+                               layer=None):
     """Chunk-native gather-then-attend: each slot's pages are gathered
     ONCE and all C chunk queries attend against that view — C× less
     gather traffic than expanding to S*C pseudo-slots, which is what
@@ -261,12 +293,7 @@ def _xla_paged_attention_chunk(q, k_pages, v_pages, page_tbl,
     exactly (f32 einsum scores over the same contraction, -inf mask,
     f32 softmax), just batched over the chunk dim."""
     dh = q.shape[-1]
-    k = _gather_pages(k_pages, page_tbl).astype(jnp.float32)
-    v = _gather_pages(v_pages, page_tbl).astype(jnp.float32)
-    if k_scale is not None:
-        k = k * _gather_pages(k_scale, page_tbl)[..., None]
-    if v_scale is not None:
-        v = v * _gather_pages(v_scale, page_tbl)[..., None]
+    k, v = _gather_kv(k_pages, v_pages, page_tbl, k_scale, v_scale, layer)
     ell = k.shape[1]
     scores = jnp.einsum(
         "schd,slhd->schl", q.astype(jnp.float32), k
@@ -282,6 +309,7 @@ def _xla_paged_attention_chunk(q, k_pages, v_pages, page_tbl,
 
 def paged_attention_chunk(q, k_pages, v_pages, page_tbl, attend_lens, *,
                           k_scale=None, v_scale=None,
+                          layer: int | None = None,
                           impl: str | None = None,
                           interpret: bool | None = None):
     """Speculative verify-once attention: a C-token CHUNK per slot
@@ -309,28 +337,28 @@ def paged_attention_chunk(q, k_pages, v_pages, page_tbl, attend_lens, *,
     Returns (S, C, H, Dh) f32.
     """
     s, c, h, dh = q.shape
-    quant = k_scale is not None
-    if quant != (v_scale is not None):
-        raise ValueError("int8 pages need BOTH k_scale and v_scale")
+    quant = _check_call(k_pages, k_scale, v_scale, layer)
     chosen = impl or select_impl()
     if chosen == "xla":
         _count_selection("xla_chunk_int8" if quant else "xla_chunk")
         return _xla_paged_attention_chunk(
             q, k_pages, v_pages, page_tbl, attend_lens,
-            k_scale=k_scale, v_scale=v_scale,
+            k_scale=k_scale, v_scale=v_scale, layer=layer,
         )
     out = paged_attention(
         q.reshape(s * c, h, dh),
         k_pages, v_pages,
         jnp.repeat(page_tbl, c, axis=0),
         attend_lens.reshape(s * c),
-        k_scale=k_scale, v_scale=v_scale, impl=impl, interpret=interpret,
+        k_scale=k_scale, v_scale=v_scale, layer=layer, impl=impl,
+        interpret=interpret,
     )
     return out.reshape(s, c, h, dh)
 
 
 def paged_attention(q, k_pages, v_pages, page_tbl, seq_lens, *,
                     k_scale=None, v_scale=None,
+                    layer: int | None = None,
                     impl: str | None = None,
                     interpret: bool | None = None):
     """One decode step of attention against paged K/V.
@@ -341,13 +369,15 @@ def paged_attention(q, k_pages, v_pages, page_tbl, seq_lens, *,
     (S, maxP) int32 pool-page indices; ``seq_lens``: (S,) int32 live
     positions per slot (position ``p`` of slot ``s`` lives at row
     ``p % page_size`` of pool page ``page_tbl[s, p // page_size]``).
+    With a static ``layer`` the pools (and scales) are a whole stack's,
+    (L, P, page_size, H, Dh), and layer ``layer`` of them is read IN
+    PLACE — what the serving step passes, so that no program ever holds
+    a per-layer piece of the pool.
     Returns (S, H, Dh) f32.  ``impl`` forces an implementation;
     ``interpret`` forces/suppresses Pallas interpret mode (None =
     interpret off-TPU).
     """
-    quant = k_scale is not None
-    if quant != (v_scale is not None):
-        raise ValueError("int8 pages need BOTH k_scale and v_scale")
+    quant = _check_call(k_pages, k_scale, v_scale, layer)
     chosen = impl or select_impl()
     _count_selection(f"{chosen}_int8" if quant else chosen)
     if chosen == "pallas":
@@ -357,9 +387,10 @@ def paged_attention(q, k_pages, v_pages, page_tbl, seq_lens, *,
             interpret = not backend().is_tpu
         return _pallas_paged_attention(
             q, k_pages, v_pages, page_tbl, seq_lens,
-            k_scale=k_scale, v_scale=v_scale, interpret=interpret,
+            k_scale=k_scale, v_scale=v_scale, layer=layer,
+            interpret=interpret,
         )
     return _xla_paged_attention(
         q, k_pages, v_pages, page_tbl, seq_lens,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, layer=layer,
     )

@@ -73,6 +73,7 @@ span's ``dur``.  Every step also counts what it served —
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -743,7 +744,10 @@ class GenerationEngine:
         interp = self.config.attention_interpret
         n_slots = self.config.slots
 
-        @jax.jit
+        # the pool is DONATED and every layer of it read and written in
+        # place (`layer=li`, `.at[li, ...]`): the step never holds a
+        # second pool or a per-layer piece of one
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
         def step(params, k_pages, v_pages, k_scales, v_scales,
                  page_tbl, seq_lens, last_tok, seeds, gen_counts,
                  temps, top_ks):
@@ -773,20 +777,17 @@ class GenerationEngine:
                     v_pages = v_pages.at[li, page_of, row_of].set(vq)
                     k_scales = k_scales.at[li, page_of, row_of].set(ksc)
                     v_scales = v_scales.at[li, page_of, row_of].set(vsc)
-                    attn = paged_attention(
-                        q.astype(jnp.float32), k_pages[li], v_pages[li],
-                        page_tbl, attend, k_scale=k_scales[li],
-                        v_scale=v_scales[li], impl=impl, interpret=interp,
-                    )
                 else:
                     k_pages = k_pages.at[li, page_of, row_of].set(
                         k_t.astype(k_pages.dtype))
                     v_pages = v_pages.at[li, page_of, row_of].set(
                         v_t.astype(v_pages.dtype))
-                    attn = paged_attention(
-                        q.astype(jnp.float32), k_pages[li], v_pages[li],
-                        page_tbl, attend, impl=impl, interpret=interp,
-                    )
+                # the scales are None for an f32 pool
+                attn = paged_attention(
+                    q.astype(jnp.float32), k_pages, v_pages, page_tbl,
+                    attend, k_scale=k_scales, v_scale=v_scales, layer=li,
+                    impl=impl, interpret=interp,
+                )
                 out = attn.reshape(n_slots, h_ * dh).astype(dt)
                 x_t = x_t + out @ ap["Wo"].astype(dt)
                 hh = _ln(lp["ln2"], x_t)
@@ -835,7 +836,7 @@ class GenerationEngine:
         c = self.spec_k + 1
         cap = mp * ps
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
         def verify(params, k_pages, v_pages, k_scales, v_scales,
                    page_tbl, seq_lens, chunk_toks, seeds, gen_counts,
                    temps, top_ks):
@@ -886,20 +887,16 @@ class GenerationEngine:
                     v_pages = v_pages.at[li, page_of, row_of].set(vq)
                     k_scales = k_scales.at[li, page_of, row_of].set(ksc)
                     v_scales = v_scales.at[li, page_of, row_of].set(vsc)
-                    attn = paged_attention_chunk(
-                        qc, k_pages[li], v_pages[li], page_tbl, attend,
-                        k_scale=k_scales[li], v_scale=v_scales[li],
-                        impl=impl, interpret=interp,
-                    )
                 else:
                     k_pages = k_pages.at[li, page_of, row_of].set(
                         k_t.astype(k_pages.dtype))
                     v_pages = v_pages.at[li, page_of, row_of].set(
                         v_t.astype(v_pages.dtype))
-                    attn = paged_attention_chunk(
-                        qc, k_pages[li], v_pages[li], page_tbl, attend,
-                        impl=impl, interpret=interp,
-                    )
+                attn = paged_attention_chunk(
+                    qc, k_pages, v_pages, page_tbl, attend,
+                    k_scale=k_scales, v_scale=v_scales, layer=li,
+                    impl=impl, interpret=interp,
+                )
                 out = attn.reshape(n, h_ * dh).astype(dt)
                 x_t = x_t + out @ ap["Wo"].astype(dt)
                 hh = _ln(lp["ln2"], x_t)
@@ -1096,8 +1093,9 @@ class GenerationEngine:
         such gap for itself): ``generation.decode_prepare`` (fault
         consult, drafts, argument copies, params snapshot — the hot-swap
         boundary — watchdog arm) -> ``generation.decode_dispatch`` (the
-        jit call alone; annotated with the live slots and the KV rows
-        the step attends) -> ``generation.decode_readback`` (the
+        jit call and the donated pool's rebinding from its result;
+        annotated with the live slots and the KV rows the step attends)
+        -> ``generation.decode_readback`` (the
         blocking ``np.asarray``) -> ``generation.harvest`` (stop
         conditions, callbacks, page release, slot free, gauges)."""
         with self._span("generation.decode_prepare"):
@@ -1108,8 +1106,14 @@ class GenerationEngine:
         try:
             with self._span("generation.decode_dispatch",
                             slots=n_live, rows=rows) as disp:
-                out = fn(params, self.kv.k_pages, self.kv.v_pages,
-                         self.kv.k_scales, self.kv.v_scales, *args)
+                out = fn(params, *self.kv.pool(), *args)
+                # the pool was donated: what `kv` held is dead from here
+                # on, so the result becomes the pool before anything can
+                # fail.  A loop that `_on_wedged` replaced meanwhile has
+                # had its pool revived and drops this one
+                with self._mu:
+                    if self._loop_gen == my_gen:
+                        self.kv.rebind(*out[:4])
             with self._span("generation.decode_readback") as rb:
                 toks = np.asarray(out[4])
         except Exception as exc:
@@ -1120,7 +1124,7 @@ class GenerationEngine:
             # decode_compute is exactly dispatch + readback
             step_s = disp.dur + rb.dur
             self.watchdog.disarm(step_s)
-            harvest(my_gen, out, toks, disp.t0, step_s, hv.t0)
+            harvest(my_gen, toks, disp.t0, step_s, hv.t0)
 
     def _prepare_step(self, my_gen: int):
         """Everything between the loop's decision to step and the jit
@@ -1197,14 +1201,12 @@ class GenerationEngine:
         self.watchdog.arm(self._steps, n_steps=c)
         return fn, params, args, n_live, rows, harvest
 
-    def _harvest_plain(self, my_gen: int, out, nxt, t0: float,
+    def _harvest_plain(self, my_gen: int, nxt, t0: float,
                        step_s: float, t_h0: float) -> None:
         """One token for every live slot of a plain step."""
         with self._mu:
             if self._loop_gen != my_gen:
                 return                     # wedged + respawned: stale
-            self.kv.k_pages, self.kv.v_pages = out[0], out[1]
-            self.kv.k_scales, self.kv.v_scales = out[2], out[3]
             finished: list[tuple[GenerationRequest, bool]] = []
             stepped: list[tuple[GenerationRequest, int]] = []
             n_live = 0
@@ -1347,7 +1349,7 @@ class GenerationEngine:
                 if self._slot_req[s] is req:
                     self._page_tbl[s, req.pages:] = SCRATCH_PAGE
 
-    def _harvest_verify(self, my_gen: int, out, tgt, t0: float,
+    def _harvest_verify(self, my_gen: int, tgt, t0: float,
                         step_s: float, t_h0: float, chunk, dl,
                         gen0) -> None:
         """The verify-once dispatch scored the (spec_k + 1)-token chunk
@@ -1359,8 +1361,6 @@ class GenerationEngine:
         with self._mu:
             if self._loop_gen != my_gen:
                 return                     # wedged + respawned: stale
-            self.kv.k_pages, self.kv.v_pages = out[0], out[1]
-            self.kv.k_scales, self.kv.v_scales = out[2], out[3]
             finished: list[tuple[GenerationRequest, bool]] = []
             stepped: list[tuple[GenerationRequest, int, int]] = []
             for s, req in enumerate(self._slot_req):
@@ -1472,6 +1472,9 @@ class GenerationEngine:
         with self._mu:
             if self._loop_gen != my_gen:
                 return
+            # a dispatch that raised may have consumed the donated pool;
+            # no stream learns of the failure before the pool is usable
+            self.kv.revive(wait=True)
             self._fail_active_locked(
                 ServingError(f"decode step failed: {exc}"))
         self._gauge_occupancy()
@@ -1507,6 +1510,9 @@ class GenerationEngine:
         with self._mu:
             self._loop_gen += 1
             gen = self._loop_gen
+            # the wedged dispatch holds the donated pool: the respawned
+            # loop gets a new one (no waiting on a device that is stuck)
+            self.kv.revive()
             self._fail_active_locked(
                 ServingError(f"decode step wedged: {event.get('stage')}"),
                 outcome="wedged",

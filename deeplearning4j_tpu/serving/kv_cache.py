@@ -30,8 +30,12 @@ error is pure rounding — no clipping against a stale page maximum —
 and the parity gate is the PR 13 agreement gate, not exactness.
 
 The allocator is HOST state (free list + page tables + counters) under
-one lock; the device arrays are owned by the caller (`GenerationEngine`
-threads them through its jitted step functionally).  Exhaustion raises
+one lock.  The device arrays are held ONCE: every program that writes
+the pool — the engine's decode and verify steps, `write_prefill` here —
+takes the four arrays as DONATED arguments, updates them in place and
+returns them, and ``k_pages``/``v_pages``/``k_scales``/``v_scales`` are
+rebound from that result at once (`PagedKVCache` "Ownership" below).
+Exhaustion raises
 `KVPoolExhausted` — mapped by admission to HTTP 429, the explicit
 "retry later" backpressure signal, never a silent stall — and the fault
 site ``kv.alloc`` makes that path provokable (`raise` = injected
@@ -41,10 +45,12 @@ exhaustion).  Occupancy lands on the telemetry spine as
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -84,6 +90,35 @@ def quantize_page_rows(a):
     return q, scale.astype(jnp.float32)
 
 
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+def _write_pages(k_pages, v_pages, k_scales, v_scales, idx, k, v):
+    """`PagedKVCache.write_prefill`'s program: scatter a prompt's K/V
+    rows, (n_layers, n * page_size, H, Dh) f32, into pool pages ``idx``
+    (n,) of every layer.  The pool is donated, so the scatter is in
+    place; shapes are static per prefill bucket, so there is one small
+    program per bucket.  An int8 pool quantizes, then scatters."""
+    n_layers, num_pages, ps = k_pages.shape[:3]
+    # page (layer, p) is page layer * P + p of the pool seen as one run
+    # of L * P pages, and the rows are already in that order: both
+    # reshapes are free, and every update is one whole contiguous page
+    flat = (jnp.arange(n_layers)[:, None] * num_pages + idx).reshape(-1)
+
+    def put(pool, rows):
+        tail = pool.shape[2:]
+        return (pool.reshape((n_layers * num_pages,) + tail)
+                .at[flat].set(rows.astype(pool.dtype).reshape((-1,) + tail))
+                .reshape(pool.shape))
+
+    k = jnp.asarray(k, jnp.float32)
+    v = jnp.asarray(v, jnp.float32)
+    if k_scales is None:
+        return put(k_pages, k), put(v_pages, v), None, None
+    kq, ks = quantize_page_rows(k)
+    vq, vs = quantize_page_rows(v)
+    return (put(k_pages, kq), put(v_pages, vq),
+            put(k_scales, ks), put(v_scales, vs))
+
+
 class PagedKVCache:
     """Pool arrays + the block allocator for one transformer stack.
 
@@ -95,9 +130,16 @@ class PagedKVCache:
 
     Device state: ``k_pages``/``v_pages`` are (n_layers, num_pages,
     page_size, n_heads, head_dim); int8 mode adds ``k_scales``/
-    ``v_scales`` (n_layers, num_pages, page_size, n_heads).  The engine
-    reads these, threads them through its jitted step, and writes the
-    updated arrays back — the allocator never touches them.
+    ``v_scales`` (n_layers, num_pages, page_size, n_heads).
+
+    Ownership: the pool exists once.  A program that writes it takes
+    `pool()` as donated arguments and its caller hands the result to
+    `rebind` before doing anything else; the arrays passed in are dead
+    from the dispatch on.  So NOBODY may hold a reference to a pool
+    array across a dispatch — read ``kv.k_pages`` afresh each time, on
+    the thread that dispatches (the engine thread), and copy what has to
+    outlive the next step.  A dispatch that raises may have consumed
+    the pool with nothing to rebind: `revive` then makes it anew.
     """
 
     def __init__(self, n_layers: int, n_heads: int, head_dim: int,
@@ -115,17 +157,8 @@ class PagedKVCache:
         self.page_size = bucket_length(page_size, PAGE_QUANTUM)
         self.num_pages = int(num_pages)
         self.kv_dtype = kv_dtype
-        shape = (self.n_layers, self.num_pages, self.page_size,
-                 self.n_heads, self.head_dim)
-        store = jnp.int8 if kv_dtype == "int8" else jnp.float32
-        self.k_pages = jnp.zeros(shape, store)
-        self.v_pages = jnp.zeros(shape, store)
-        self.k_scales = self.v_scales = None
-        if kv_dtype == "int8":
-            sshape = shape[:-1]
-            # scale 1.0 everywhere: untouched rows dequantize to exact 0
-            self.k_scales = jnp.ones(sshape, jnp.float32)
-            self.v_scales = jnp.ones(sshape, jnp.float32)
+        self._pool_rebuilds = 0
+        self.rebind(*self._fresh_pool())
         self._lock = threading.Lock()
         self._free: list[int] = list(range(self.num_pages - 1, 0, -1))
         self._tables: dict[object, list[int]] = {}
@@ -133,6 +166,53 @@ class PagedKVCache:
         self._alloc_failures = 0
         self._gauge_total()
         self._gauge_used(0)
+
+    # -- the device pool ---------------------------------------------------
+    def _fresh_pool(self) -> tuple:
+        shape = (self.n_layers, self.num_pages, self.page_size,
+                 self.n_heads, self.head_dim)
+        if self.kv_dtype != "int8":
+            return (jnp.zeros(shape, jnp.float32),
+                    jnp.zeros(shape, jnp.float32), None, None)
+        # scale 1.0 everywhere: untouched rows dequantize to exact 0
+        return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
+                jnp.ones(shape[:-1], jnp.float32),
+                jnp.ones(shape[:-1], jnp.float32))
+
+    def pool(self) -> tuple:
+        """``(k_pages, v_pages, k_scales, v_scales)`` — the donated
+        arguments of every program that writes the pool, in the order
+        those programs return them (scales are None for an f32 pool)."""
+        return self.k_pages, self.v_pages, self.k_scales, self.v_scales
+
+    def rebind(self, k_pages, v_pages, k_scales, v_scales) -> None:
+        """Take a donating program's result as the pool."""
+        self.k_pages, self.v_pages = k_pages, v_pages
+        self.k_scales, self.v_scales = k_scales, v_scales
+
+    def revive(self, wait: bool = False) -> bool:
+        """After a dispatch that failed: if it consumed the donated pool
+        (an array is deleted) — or, with ``wait``, left it the result of
+        a program that failed on the device — make the pool anew, zeros
+        as at construction, and count it (``stats()["pool_rebuilds"]``).
+        The caller fails every stream and releases every page, so no
+        row of the old pool is owed to anyone.  ``wait`` blocks until
+        the device is done with the pool: not for a wedged device."""
+        arrays = [a for a in self.pool() if a is not None]
+        try:
+            dead = any(a.is_deleted() for a in arrays)
+            if wait and not dead:
+                jax.block_until_ready(arrays)
+        except Exception as e:
+            log.warning("kv pool unusable after a failed dispatch: %s", e)
+            dead = True
+        if dead:
+            del arrays
+            self.rebind(None, None, None, None)   # free before allocating
+            self.rebind(*self._fresh_pool())
+            with self._lock:
+                self._pool_rebuilds += 1
+        return dead
 
     # -- geometry ----------------------------------------------------------
     def pages_for(self, length: int) -> int:
@@ -281,6 +361,7 @@ class PagedKVCache:
                 "requests": len(self._tables),
                 "spec_reserved_pages": sum(self._spec_extra.values()),
                 "alloc_failures": self._alloc_failures,
+                "pool_rebuilds": self._pool_rebuilds,
                 "bytes_per_token": self.bytes_per_token(),
             }
 
@@ -310,7 +391,8 @@ class PagedKVCache:
         with T a multiple of ``page_size`` (the prefill bucket quantum
         guarantees it); the table must already cover T positions.
         Returns the page table as an int32 array (for the decode step's
-        page-table row)."""
+        page-table row).  One donated program per prefill bucket
+        (`_write_pages`): the pool is written in place and rebound."""
         pages = self.table(rid)
         t = int(k.shape[1])
         n = t // self.page_size
@@ -319,28 +401,12 @@ class PagedKVCache:
                 f"prefill length {t} does not fit {len(pages)} page(s) "
                 f"of {self.page_size}"
             )
-        idx = jnp.asarray(pages[:n], jnp.int32)
-        ps = self.page_size
-        if self.kv_dtype == "int8":
-            kq, ks = quantize_page_rows(k)
-            vq, vs = quantize_page_rows(v)
-            self.k_pages = self.k_pages.at[:, idx].set(
-                kq.reshape(self.n_layers, n, ps, self.n_heads,
-                           self.head_dim))
-            self.v_pages = self.v_pages.at[:, idx].set(
-                vq.reshape(self.n_layers, n, ps, self.n_heads,
-                           self.head_dim))
-            self.k_scales = self.k_scales.at[:, idx].set(
-                ks.reshape(self.n_layers, n, ps, self.n_heads))
-            self.v_scales = self.v_scales.at[:, idx].set(
-                vs.reshape(self.n_layers, n, ps, self.n_heads))
-        else:
-            self.k_pages = self.k_pages.at[:, idx].set(
-                jnp.asarray(k, jnp.float32).reshape(
-                    self.n_layers, n, ps, self.n_heads, self.head_dim))
-            self.v_pages = self.v_pages.at[:, idx].set(
-                jnp.asarray(v, jnp.float32).reshape(
-                    self.n_layers, n, ps, self.n_heads, self.head_dim))
+        # rebound by assignment from the call's own result: the form
+        # tpulint's use-after-donate rule (RH105) follows
+        (self.k_pages, self.v_pages, self.k_scales,
+         self.v_scales) = _write_pages(
+            self.k_pages, self.v_pages, self.k_scales, self.v_scales,
+            np.asarray(pages[:n], np.int32), k, v)
         return np.asarray(pages, np.int32)
 
     # -- telemetry (never on the allocation's critical path) ---------------

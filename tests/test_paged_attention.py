@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.ops.paged_attention import (
     IMPLS,
     paged_attention,
+    paged_attention_chunk,
     select_impl,
 )
 from deeplearning4j_tpu.serving.kv_cache import quantize_page_rows
@@ -163,6 +164,76 @@ class TestInt8Parity:
         ks = jnp.ones((P, PS, H), jnp.float32)
         with pytest.raises(ValueError, match="BOTH"):
             paged_attention(q, kp, vp, tbl, lens, k_scale=ks)
+
+
+class TestLayerIndexedPool:
+    """The serving step hands the kernel the WHOLE (L, P, ps, H, Dh)
+    stack plus a static ``layer``: every route must read exactly what
+    the 4-D call reads on ``pool[layer]`` — same gather, same blocks,
+    one more index."""
+
+    L = 3
+
+    def _stack(self, kv_dtype, seed):
+        """L distinct layers of `_case`'s pools (other layers hold other
+        values, so reading the wrong layer cannot pass)."""
+        rng = np.random.default_rng(100 + seed)
+        shape = (self.L, P, PS, H, DH)
+        k5 = rng.standard_normal(shape).astype(np.float32)
+        v5 = rng.standard_normal(shape).astype(np.float32)
+        if kv_dtype == "f32":
+            return jnp.asarray(k5), jnp.asarray(v5), None, None
+        kq, ks = quantize_page_rows(k5)
+        vq, vs = quantize_page_rows(v5)
+        return kq, vq, ks, vs
+
+    @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+    @pytest.mark.parametrize("impl", ["xla", "pallas"])
+    def test_step_equals_the_4d_call_on_that_layer(self, impl, kv_dtype):
+        q, _, _, tbl, lens = _case(seed=6)
+        k5, v5, ks5, vs5 = self._stack(kv_dtype, seed=6)
+        for li in (0, self.L - 1):
+            sc4 = ({} if ks5 is None
+                   else dict(k_scale=ks5[li], v_scale=vs5[li]))
+            sc5 = ({} if ks5 is None
+                   else dict(k_scale=ks5, v_scale=vs5))
+            ref = np.asarray(paged_attention(
+                q, k5[li], v5[li], tbl, lens, impl=impl, interpret=True,
+                **sc4))
+            got = np.asarray(paged_attention(
+                q, k5, v5, tbl, lens, layer=li, impl=impl, interpret=True,
+                **sc5))
+            np.testing.assert_array_equal(got, ref, err_msg=f"layer {li}")
+
+    @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+    @pytest.mark.parametrize("impl", ["xla", "pallas"])
+    def test_chunk_equals_the_4d_call_on_that_layer(self, impl, kv_dtype):
+        c = 3
+        _, _, _, tbl, lens = _case(seed=7)
+        rng = np.random.default_rng(7)
+        q = jnp.asarray(
+            rng.standard_normal((S, c, H, DH)).astype(np.float32))
+        attend = jnp.minimum(lens[:, None] + jnp.arange(c)[None, :] + 1,
+                             MAXP * PS).astype(jnp.int32)
+        k5, v5, ks5, vs5 = self._stack(kv_dtype, seed=7)
+        li = 1
+        sc4 = {} if ks5 is None else dict(k_scale=ks5[li], v_scale=vs5[li])
+        sc5 = {} if ks5 is None else dict(k_scale=ks5, v_scale=vs5)
+        ref = np.asarray(paged_attention_chunk(
+            q, k5[li], v5[li], tbl, attend, impl=impl, interpret=True,
+            **sc4))
+        got = np.asarray(paged_attention_chunk(
+            q, k5, v5, tbl, attend, layer=li, impl=impl, interpret=True,
+            **sc5))
+        np.testing.assert_array_equal(got, ref)
+
+    @pytest.mark.parametrize("layer,rank", [(None, 5), (1, 4)])
+    def test_rank_and_layer_must_agree(self, layer, rank):
+        q, kp, vp, tbl, lens = _case()
+        if rank == 5:
+            kp, vp = kp[None], vp[None]
+        with pytest.raises(ValueError, match="layer="):
+            paged_attention(q, kp, vp, tbl, lens, layer=layer, impl="xla")
 
 
 class TestSelection:

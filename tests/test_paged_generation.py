@@ -410,6 +410,162 @@ class TestLadder:
             srv.stop()
 
 
+# -- the pool is held once: donated, rebound, revived -------------------------
+
+def _deleted(arrays):
+    return [a.is_deleted() for a in arrays if a is not None]
+
+
+class TestDonatedPool:
+    """Every program that writes the pool takes it donated and the pool
+    is rebound from the result; a dispatch that fails after consuming it
+    leaves a NEW pool behind (`kv.revive`), never a dead one."""
+
+    @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+    def test_write_prefill_consumes_and_rebinds(self, kv_dtype):
+        kv = PagedKVCache(n_layers=2, n_heads=2, head_dim=8, num_pages=8,
+                          page_size=8, kv_dtype=kv_dtype)
+        rng = np.random.default_rng(3)
+        k = rng.standard_normal((2, 16, 2, 8)).astype(np.float32)
+        kv.alloc("a", 2)
+        before = kv.pool()
+        kv.write_prefill("a", k, k)
+        assert all(_deleted(before))       # the CPU backend donates
+        assert not any(_deleted(kv.pool()))
+        assert kv.stats()["pool_rebuilds"] == 0
+
+    def test_write_prefill_is_one_program_per_bucket(self):
+        """The second hand-off of a bucket compiles nothing, whether its
+        K/V come from the device (inline prefill) or the host (the
+        disaggregation seam); a new bucket is ONE new program."""
+        from deeplearning4j_tpu.runtime import compile_stats
+
+        # a pool shape no other test uses: `_write_pages` is one jit
+        # for the process
+        kv = PagedKVCache(n_layers=3, n_heads=3, head_dim=8, num_pages=11,
+                          page_size=8)
+        rng = np.random.default_rng(4)
+
+        def hand_off(rid, n_pages, on_device):
+            k = rng.standard_normal(
+                (3, 8 * n_pages, 3, 8)).astype(np.float32)
+            kv.alloc(rid, n_pages)
+            snap = compile_stats.snapshot()
+            kv.write_prefill(rid, *((jnp.asarray(k),) * 2 if on_device
+                                    else (k, k)))
+            delta = compile_stats.snapshot() - snap
+            tbl = kv.table(rid)
+            got = np.concatenate(
+                [np.asarray(kv.k_pages[:, p]) for p in tbl], axis=1)
+            np.testing.assert_array_equal(got, k)
+            kv.release(rid)
+            return delta.backend_compiles
+
+        assert hand_off("a", 2, on_device=True) == 1
+        assert hand_off("b", 2, on_device=True) == 0
+        assert hand_off("c", 2, on_device=False) == 0
+        assert hand_off("d", 3, on_device=False) == 1
+        assert hand_off("e", 2, on_device=True) == 0
+
+    def test_decode_step_consumes_and_rebinds(self, model):
+        eng = _engine(model).start()
+        try:
+            p = _prompt(5, seed=80)
+            eng.generate(p, 2, timeout=120.0)
+            before = eng.kv.pool()
+            np.testing.assert_array_equal(
+                np.asarray(eng.generate(p, 6, timeout=120.0)),
+                _dense(model, p, 6))
+            assert eng.drain(timeout=30.0)
+            assert all(_deleted(before))
+            assert not any(_deleted(eng.kv.pool()))
+            assert eng.kv.stats()["pool_rebuilds"] == 0
+        finally:
+            eng.stop()
+
+    @pytest.mark.parametrize("program", ["step", "verify"])
+    @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+    def test_compiled_programs_alias_the_whole_pool(self, model, kv_dtype,
+                                                    program):
+        eng = _engine(model, kv_dtype=kv_dtype, spec_k=2)
+        s, mp = CFG["slots"], CFG["max_pages_per_seq"]
+
+        def sds(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+        def vec(dtype, *tail):
+            return jax.ShapeDtypeStruct((s,) + tail, dtype)
+
+        toks = vec(jnp.int32) if program == "step" else vec(jnp.int32, 3)
+        fn = eng._make_step() if program == "step" else eng._make_verify()
+        compiled = fn.lower(
+            jax.tree.map(sds, model.params),
+            *[None if a is None else sds(a) for a in eng.kv.pool()],
+            vec(jnp.int32, mp), vec(jnp.int32), toks, vec(jnp.uint32),
+            vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
+        ).compile()
+        pool_bytes = sum(a.nbytes for a in eng.kv.pool() if a is not None)
+        assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+
+    @pytest.mark.faults
+    @pytest.mark.parametrize("how", ["fault_before_dispatch",
+                                     "raises_after_dispatch",
+                                     "wedged_after_dispatch"])
+    def test_failed_step_leaves_a_usable_pool(self, model, how):
+        eng = _engine(model).start()
+        release = threading.Event()
+        try:
+            eng.generate(_prompt(4), 2, timeout=120.0)     # warm
+            real = eng._step_fn
+            entered = threading.Event()
+
+            def consume_then_fail(*a):
+                eng._step_fn = real                        # one shot
+                out = real(*a)                             # pool consumed
+                if how == "raises_after_dispatch":
+                    raise RuntimeError("device lost")
+                entered.set()
+                release.wait(60.0)                         # "wedged"
+                return out
+
+            if how == "fault_before_dispatch":
+                faults.arm("serving.decode:raise:nth=1")
+            else:
+                eng._step_fn = consume_then_fail
+            req = eng.submit(_prompt(4), 6)
+            if how == "wedged_after_dispatch":
+                assert entered.wait(60.0)
+                stale = eng._thread
+                eng._on_wedged({"stage": "abort", "iteration": 0})
+            with pytest.raises(ServingError):
+                req.result(60.0)
+            faults.disarm()
+            assert eng.kv.used_pages == 0
+            assert not any(_deleted(eng.kv.pool()))
+            rebuilt = 0 if how == "fault_before_dispatch" else 1
+            assert eng.kv.stats()["pool_rebuilds"] == rebuilt
+            p = _prompt(5, seed=31)
+            np.testing.assert_array_equal(
+                np.asarray(eng.generate(p, 5, timeout=120.0)),
+                _dense(model, p, 5))
+            if how == "wedged_after_dispatch":
+                # the wedged dispatch returns at last: its pool must not
+                # replace the one the respawned loop is serving from
+                serving = eng.kv.pool()
+                release.set()
+                stale.join(30.0)
+                assert not stale.is_alive()
+                assert all(a is b for a, b in zip(eng.kv.pool(), serving))
+                np.testing.assert_array_equal(
+                    np.asarray(eng.generate(p, 5, timeout=120.0)),
+                    _dense(model, p, 5))
+            assert eng.kv.stats()["pool_rebuilds"] == rebuilt
+            assert eng.kv.leak_check() is None
+        finally:
+            release.set()
+            eng.stop()
+
+
 # -- bounded program set -----------------------------------------------------
 
 class TestCompileStability:

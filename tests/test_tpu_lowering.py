@@ -83,6 +83,41 @@ def test_paged_attention_chunk_c5():
                   sds((S, MP), jnp.int32), sds((S, c), jnp.int32))
 
 
+LAYERS = 3
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+def test_paged_attention_layer_indexed(kv_dtype):
+    """What the serving step calls: the whole (L, P, ps, H, Dh) stack is
+    the kernel's operand and the static layer is a block index."""
+    store = jnp.int8 if kv_dtype == "int8" else jnp.float32
+    specs = [sds((S, H, D), jnp.float32), sds((LAYERS, P, PS, H, D), store),
+             sds((LAYERS, P, PS, H, D), store), sds((S, MP), jnp.int32),
+             sds((S,), jnp.int32)]
+    if kv_dtype == "int8":
+        specs += [sds((LAYERS, P, PS, H), jnp.float32)] * 2
+
+    def f(q, k, v, tbl, lens, ks=None, vs=None):
+        return pa.paged_attention(q, k, v, tbl, lens, k_scale=ks, v_scale=vs,
+                                  layer=LAYERS - 1, impl="pallas",
+                                  interpret=False)
+
+    lower_for_tpu(f, *specs)
+
+
+def test_paged_attention_chunk_c5_layer_indexed():
+    c = 5
+
+    def f(q, k, v, tbl, attend):
+        return pa.paged_attention_chunk(q, k, v, tbl, attend, layer=1,
+                                        impl="pallas", interpret=False)
+
+    lower_for_tpu(f, sds((S, c, H, D), jnp.float32),
+                  sds((LAYERS, P, PS, H, D), jnp.float32),
+                  sds((LAYERS, P, PS, H, D), jnp.float32),
+                  sds((S, MP), jnp.int32), sds((S, c), jnp.int32))
+
+
 @pytest.mark.parametrize("m", [1, 8, 256])
 def test_dequant_matmul(m):
     lower_for_tpu(
