@@ -773,6 +773,11 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "kernel, xla = gather-then-attend reference; _int8 "
                 "suffix = fused dequant variant).  Counted at TRACE "
                 "time, never from inside the traced body")
+    reg.counter("dl4jtpu_flash_attention_total",
+                "Flash-attention sites lowered into compiled programs, "
+                "by the tiling they got (block_q, block_k: rows of the "
+                "Q and of the K/V block) and causal.  Counted at TRACE "
+                "time, never from inside the traced body")
     # generation-plane observability (serving/generation.py lifecycle
     # instrumentation + serving/flight.py flight recorder)
     reg.counter("dl4jtpu_generation_streams_admitted_total",

@@ -373,6 +373,7 @@ class TestTier1Gate:
             "dl4jtpu_ttft_seconds",
             "dl4jtpu_decode_batch_occupancy",
             "dl4jtpu_paged_attention_total",
+            "dl4jtpu_flash_attention_total",
         } <= fams
         # ISSUE-17 generation-plane observability families
         assert {

@@ -52,6 +52,26 @@ def test_flash_backward_bf16():
     lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
 
 
+@pytest.mark.parametrize("t, dtype", [
+    (2048, jnp.float32),        # the train cells' call: blocks 1024 x 1024
+    (2560, jnp.float32),        # 1024 does not divide: 512 x 512
+    (2176, jnp.bfloat16),       # 17 x 128: the bottom of the ladder
+])
+def test_flash_step_is_three_kernels_in_the_callers_dtype(t, dtype):
+    """Forward, dQ and dK+dV: three Mosaic calls per attention layer (the
+    benchmark's roofline reader counts steps by them), whatever the
+    tiling; bf16 goes in as operands, the caller's dtype comes out."""
+    q = sds((2, t, H, D), dtype)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    exported = lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert exported.mlir_module().count("@tpu_custom_call") == 3
+    assert [a.dtype for a in exported.out_avals] == [dtype] * 3
+
+
 @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
 def test_paged_attention(kv_dtype):
     store = jnp.int8 if kv_dtype == "int8" else jnp.float32
