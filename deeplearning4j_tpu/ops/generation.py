@@ -10,16 +10,28 @@ cache — O(T) per step, static shapes throughout, the whole decode loop a
 single compiled XLA program.  Greedy, temperature, and top-k sampling.
 
 This dense-cache `generate()` is the SINGLE-REQUEST REFERENCE PATH: its
-per-position numerics (`_block_step`'s f32 attention, `_sample`'s
+per-position numerics (`cache_row_attention`'s f32 attention, `_sample`'s
 greedy/temperature/top-k rules, the `fold_in(rng, i)` key schedule) are
 the contract the paged serving engine (`serving/generation.py` over
 `ops/paged_attention.py`) must reproduce token-for-token — greedy
 exactly, sampled exactly under a shared seed, int8-KV within the PR 13
-agreement gate.  Change decode semantics HERE first; the paged parity
-tests (`tests/test_paged_generation.py`) hold the engine to this file.
+agreement gate.
+
+What a stack computes is written HERE, once: `_plan` (the stack's
+layers), `embed_tokens` (gather, activation, position), `block` (one
+transformer block over an `attend(q, k, v)` callback) and `_head_logits`.
+`generate()`, the engine's prefill / step / verify programs and the model
+drafter (`serving/speculative.py`) all call them; a caller only chooses
+which state the attention runs against — the whole prompt
+(`prompt_forward`), a dense cache row (`cache_row_attention`), or the
+paged pool (the engine's closure).  Changing decode semantics here changes
+every one of them; the paged parity tests
+(`tests/test_paged_generation.py`) hold the engine to `generate()`.
 """
 
 from __future__ import annotations
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -36,11 +48,16 @@ from deeplearning4j_tpu.nn.conf.layers import (
     LayerConfig,
 )
 from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
+from deeplearning4j_tpu.ops.attention import mha
+
+#: what `_plan` returns: the stack's layers (each names its own entry of
+#: the params tree, `layer.name`) and the widths every caller needs
+_Stack = collections.namedtuple(
+    "_Stack", "embed pos blocks head d n_heads head_dim")
 
 
 def _plan(model):
-    """Validate the stack shape and return (embed, pos, blocks, head) with
-    their layer names."""
+    """Validate the stack shape and return its `_Stack` record."""
     layers = list(model.conf.layers)
     if not layers or not isinstance(layers[0], Embedding):
         raise ValueError("generate() needs an Embedding first layer")
@@ -72,7 +89,15 @@ def _plan(model):
                 "generate() requires causal blocks (bidirectional attention "
                 "cannot decode autoregressively)"
             )
-    return embed, pos, blocks, head
+    # the attention widths are the blocks'; a stack without one has none
+    n_heads = blocks[0].n_heads if blocks else 0
+    head_dim = blocks[0].d_model // n_heads if blocks else 0
+    return _Stack(embed, pos, tuple(blocks), head, embed.n_out, n_heads,
+                  head_dim)
+
+
+def _act_dtype(model):
+    return jnp.bfloat16 if model._bf16 else jnp.float32
 
 
 def _pe_row(pos_layer, lp, t, d):
@@ -100,55 +125,88 @@ def _ln(lp, x):
     return y * lp["gamma"].astype(x.dtype) + lp["beta"].astype(x.dtype)
 
 
-def _block_prefill(cfg, lp, x, mask):
-    """Dense block forward on the prompt that ALSO returns the K/V it
-    computed (cache seed).  x: (B, T, D)."""
-    b, t, d = x.shape
-    h_, dh = cfg.n_heads, cfg.d_model // cfg.n_heads
+def embed_tokens(stack, params, toks, positions, dt):
+    """Token ids -> the first block's input: the embedding gather through
+    the LAYER's semantics (its activation included) plus position.
+    ``positions=None`` is a whole prompt from position 0 (the layer's own
+    vectorized encoding — a per-position Python loop would unroll O(T)
+    ops into the trace); otherwise one traced position for every row of
+    ``toks``, or a scalar shared by them all (the decode tick)."""
+    E = params[stack.embed.name]["W"].astype(dt)
+    x = stack.embed._act()(E[toks])
+    pos = stack.pos
+    lp = params.get(pos.name, {}) if pos is not None else {}
+    if positions is None:
+        if pos is not None:
+            x, _ = pos.apply(lp, {}, x)
+        return x
+    row = lambda t: _pe_row(pos, lp, t, stack.d)
+    pe = row(positions) if jnp.ndim(positions) == 0 else jax.vmap(row)(
+        positions)
+    return x + pe.astype(dt)
+
+
+def block(cfg, lp, x, attend):
+    """One transformer block on x: (..., D).  ``attend(q, k, v)`` takes the
+    (..., H, Dh) projections of these rows and returns their attention
+    output, same shape; which K/V it runs against — and where it keeps
+    the rows it was handed — is the caller's closure."""
+    dt = x.dtype
+    rows, h_ = x.shape[:-1], cfg.n_heads
+    heads = rows + (h_, cfg.d_model // h_)
     ap = lp["attn"]
     hh = _ln(lp["ln1"], x)
-    q = (hh @ ap["Wq"].astype(x.dtype)).reshape(b, t, h_, dh)
-    k = (hh @ ap["Wk"].astype(x.dtype)).reshape(b, t, h_, dh)
-    v = (hh @ ap["Wv"].astype(x.dtype)).reshape(b, t, h_, dh)
-    from deeplearning4j_tpu.ops.attention import mha
-
-    out = mha(q, k, v, causal=True, mask=mask)
-    x = x + out.reshape(b, t, h_ * dh) @ ap["Wo"].astype(x.dtype)
+    q = (hh @ ap["Wq"].astype(dt)).reshape(heads)
+    k = (hh @ ap["Wk"].astype(dt)).reshape(heads)
+    v = (hh @ ap["Wv"].astype(dt)).reshape(heads)
+    out = attend(q, k, v).reshape(rows + (cfg.d_model,)).astype(dt)
+    x = x + out @ ap["Wo"].astype(dt)
     hh = _ln(lp["ln2"], x)
-    hh = cfg.ffn_activation(hh @ lp["W1"].astype(x.dtype) + lp["b1"].astype(x.dtype))
-    x = x + (hh @ lp["W2"].astype(x.dtype) + lp["b2"].astype(x.dtype))
-    return x, k, v
+    hh = cfg.ffn_activation(hh @ lp["W1"].astype(dt) + lp["b1"].astype(dt))
+    return x + (hh @ lp["W2"].astype(dt) + lp["b2"].astype(dt))
 
 
-def _block_step(cfg, lp, x_t, k_cache, v_cache, pos):
-    """One-token block step against the cache.  x_t: (B, D);
-    caches: (B, L, H, Dh); pos: scalar current position."""
-    b, d = x_t.shape
-    h_, dh = cfg.n_heads, cfg.d_model // cfg.n_heads
-    L = k_cache.shape[1]
-    ap = lp["attn"]
-    hh = _ln(lp["ln1"], x_t)
-    q = (hh @ ap["Wq"].astype(x_t.dtype)).reshape(b, h_, dh)
-    k_t = (hh @ ap["Wk"].astype(x_t.dtype)).reshape(b, h_, dh)
-    v_t = (hh @ ap["Wv"].astype(x_t.dtype)).reshape(b, h_, dh)
-    k_cache = lax.dynamic_update_index_in_dim(k_cache, k_t, pos, axis=1)
-    v_cache = lax.dynamic_update_index_in_dim(v_cache, v_t, pos, axis=1)
-    scores = jnp.einsum("bhd,blhd->bhl", q.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) / np.sqrt(dh)
-    live = jnp.arange(L)[None, None, :] <= pos
-    scores = jnp.where(live, scores, -jnp.inf)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhl,blhd->bhd", p, v_cache.astype(jnp.float32))
-    out = out.reshape(b, h_ * dh).astype(x_t.dtype)
-    x_t = x_t + out @ ap["Wo"].astype(x_t.dtype)
-    hh = _ln(lp["ln2"], x_t)
-    hh = cfg.ffn_activation(hh @ lp["W1"].astype(x_t.dtype) + lp["b1"].astype(x_t.dtype))
-    x_t = x_t + (hh @ lp["W2"].astype(x_t.dtype) + lp["b2"].astype(x_t.dtype))
-    return x_t, k_cache, v_cache
+def prompt_forward(stack, params, toks, dt):
+    """Dense causal forward over whole prompts, toks: (B, T).  Returns the
+    last block's output (B, T, D) and every block's (k, v), each
+    (B, T, H, Dh) — the cache seed."""
+    x = embed_tokens(stack, params, toks, None, dt)
+    kvs = []
+
+    def attend(q, k, v):
+        kvs.append((k, v))
+        return mha(q, k, v, causal=True)
+
+    for cfg in stack.blocks:
+        x = block(cfg, params[cfg.name], x, attend)
+    return x, kvs
 
 
-def _head_logits(head, lp, h):
+def cache_row_attention(k_cache, v_cache, pos, grown):
+    """`attend` for ONE query row per sequence against a dense cache:
+    q, k_t, v_t: (B, H, Dh); caches: (B, L, H, Dh); pos: scalar current
+    position.  The caches with this row written are appended to
+    ``grown``.  f32 scores, ``-inf`` past ``pos``, f32 softmax, f32
+    output: the per-position numerics `ops/paged_attention.py` holds
+    itself to."""
+    def attend(q, k_t, v_t):
+        dh, L = q.shape[-1], k_cache.shape[1]
+        k_c = lax.dynamic_update_index_in_dim(k_cache, k_t, pos, axis=1)
+        v_c = lax.dynamic_update_index_in_dim(v_cache, v_t, pos, axis=1)
+        grown.append((k_c, v_c))
+        scores = jnp.einsum("bhd,blhd->bhl", q.astype(jnp.float32),
+                            k_c.astype(jnp.float32)) / np.sqrt(dh)
+        live = jnp.arange(L)[None, None, :] <= pos
+        scores = jnp.where(live, scores, -jnp.inf)
+        p = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bhl,blhd->bhd", p, v_c.astype(jnp.float32))
+
+    return attend
+
+
+def _head_logits(stack, params, h):
     """h: (..., D) -> (..., vocab) logits."""
+    head, lp = stack.head, params[stack.head.name]
     if isinstance(head, ChunkedSoftmaxOutputLayer):
         return head.logits(lp, h)
     y = h @ lp["W"].astype(h.dtype)
@@ -180,7 +238,8 @@ def generate(model, prompt_ids, max_new_tokens: int, *,
     """
     if model.params is None:
         model.init()
-    embed, pos, blocks, head = _plan(model)
+    stack = _plan(model)
+    pos = stack.pos
     prompt = jnp.asarray(prompt_ids).astype(jnp.int32)
     if prompt.ndim == 1:
         prompt = prompt[None, :]
@@ -201,44 +260,30 @@ def generate(model, prompt_ids, max_new_tokens: int, *,
         cache = model._gen_fns = {}
     if key not in cache:
         cache[key] = _generate_jit(
-            model, embed, pos, tuple(blocks), head,
-            int(max_new_tokens), float(temperature), int(top_k),
+            model, stack, int(max_new_tokens), float(temperature),
+            int(top_k),
         )
     return cache[key](model.params, prompt, jax.random.key(seed))
 
 
-def _generate_jit(model, embed, pos, blocks, head, max_new, temperature, top_k):
-    names = [l.name for l in model.conf.layers]
-    embed_name, head_name = names[0], names[-1]
-    block_names = [l.name for l in model.conf.layers
-                   if isinstance(l, TransformerEncoderBlock)]
-    pos_name = pos.name if pos is not None else None
-    d = embed.n_out
-
+def _generate_jit(model, stack, max_new, temperature, top_k):
     @jax.jit
     def run(params, prompt, rng):
         b, t_p = prompt.shape
         L = t_p + max_new
-        dt = jnp.bfloat16 if model._bf16 else jnp.float32
-        E = params[embed_name]["W"].astype(dt)
+        dt = _act_dtype(model)
 
         # ---- prefill: dense forward over the prompt, caches out ----
-        # embed through the LAYER's semantics (its activation included)
-        x = embed._act()(E[prompt])                     # (B, T_p, D)
-        if pos is not None:
-            # reuse the layer's own vectorized encoding — a per-position
-            # Python loop would unroll O(T_p) ops into the trace
-            x, _ = pos.apply(params.get(pos_name, {}), {}, x)
+        x, kvs = prompt_forward(stack, params, prompt, dt)
         caches = []
-        for cfg, nm in zip(blocks, block_names):
-            x, k, v = _block_prefill(cfg, params[nm], x, None)
+        for k, v in kvs:
             k_c = jnp.zeros((b, L) + k.shape[2:], k.dtype)
             v_c = jnp.zeros((b, L) + v.shape[2:], v.dtype)
             caches.append((
                 lax.dynamic_update_slice(k_c, k, (0, 0, 0, 0)),
                 lax.dynamic_update_slice(v_c, v, (0, 0, 0, 0)),
             ))
-        logits = _head_logits(head, params[head_name], x[:, -1])
+        logits = _head_logits(stack, params, x[:, -1])
         first = _sample(logits, temperature=temperature, top_k=top_k,
                         rng=jax.random.fold_in(rng, 0))
 
@@ -246,17 +291,15 @@ def _generate_jit(model, embed, pos, blocks, head, max_new, temperature, top_k):
         def tick(carry, i):
             tok, caches = carry
             t = t_p + i                                  # position of tok
-            x_t = embed._act()(E[tok]) + _pe_row(
-                pos, params.get(pos_name, {}), t, d
-            ).astype(dt)
-            new_caches = []
-            for cfg, nm, (k_c, v_c) in zip(blocks, block_names, caches):
-                x_t, k_c, v_c = _block_step(cfg, params[nm], x_t, k_c, v_c, t)
-                new_caches.append((k_c, v_c))
-            logits = _head_logits(head, params[head_name], x_t)
+            x_t = embed_tokens(stack, params, tok, t, dt)
+            grown = []
+            for cfg, (k_c, v_c) in zip(stack.blocks, caches):
+                x_t = block(cfg, params[cfg.name], x_t,
+                            cache_row_attention(k_c, v_c, t, grown))
+            logits = _head_logits(stack, params, x_t)
             nxt = _sample(logits, temperature=temperature, top_k=top_k,
                           rng=jax.random.fold_in(rng, i + 1))
-            return (nxt, tuple(new_caches)), tok
+            return (nxt, tuple(grown)), tok
 
         (last, _), toks = lax.scan(
             tick, (first, tuple(caches)), jnp.arange(max_new - 1)

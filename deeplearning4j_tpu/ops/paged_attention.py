@@ -9,7 +9,7 @@ one dispatch, mirroring ``ops/dequant_matmul.py``:
 
 - ``xla`` — gather-then-attend reference: the page table gathers the
   slot's pages into a dense (L, H, Dh) view and the attention math is
-  EXACTLY ``ops/generation.py``'s ``_block_step`` (f32 einsum scores,
+  EXACTLY ``ops/generation.py``'s ``cache_row_attention`` (f32 einsum scores,
   ``-inf`` masking past ``seq_len``, f32 softmax, f32 einsum output) —
   masked positions contribute exact zeros, so paged greedy decode is
   token-identical to the dense reference.
@@ -109,8 +109,8 @@ def _gather_kv(k_pages, v_pages, page_tbl, k_scale, v_scale, layer):
 
 def _xla_paged_attention(q, k_pages, v_pages, page_tbl, seq_lens,
                          k_scale=None, v_scale=None, layer=None):
-    """Gather-then-attend: `_block_step`'s exact numerics against the
-    page-table-indexed view.  q: (S, H, Dh); pools: (P, ps, H, Dh), or
+    """Gather-then-attend: `cache_row_attention`'s exact numerics against
+    the page-table-indexed view.  q: (S, H, Dh); pools: (P, ps, H, Dh), or
     (L, P, ps, H, Dh) with ``layer``; int8 pools carry (..., P, ps, H)
     per-row scale blocks."""
     dh = q.shape[-1]
@@ -334,9 +334,18 @@ def paged_attention_chunk(q, k_pages, v_pages, page_tbl, attend_lens, *,
       `paged_attention` kernel dispatch (int8 variants included) — no
       new kernel, the grid just sees S*C slots.
 
+    A chunk of ONE is the plain call on either route (and counts as
+    it): the serving step is this function at ``C == 1``.
+
     Returns (S, C, H, Dh) f32.
     """
     s, c, h, dh = q.shape
+    if c == 1:
+        return paged_attention(
+            q.reshape(s, h, dh), k_pages, v_pages, page_tbl,
+            attend_lens.reshape(s), k_scale=k_scale, v_scale=v_scale,
+            layer=layer, impl=impl, interpret=interpret,
+        ).reshape(q.shape)
     quant = _check_call(k_pages, k_scale, v_scale, layer)
     chosen = impl or select_impl()
     if chosen == "xla":

@@ -88,16 +88,14 @@ import numpy as np
 
 from deeplearning4j_tpu.observe import trace as otrace
 from deeplearning4j_tpu.ops.generation import (
-    _block_prefill,
+    _act_dtype,
     _head_logits,
-    _ln,
-    _pe_row,
     _plan,
+    block,
+    embed_tokens,
+    prompt_forward,
 )
-from deeplearning4j_tpu.ops.paged_attention import (
-    paged_attention,
-    paged_attention_chunk,
-)
+from deeplearning4j_tpu.ops.paged_attention import paged_attention_chunk
 from deeplearning4j_tpu.runtime import faults
 from deeplearning4j_tpu.runtime.flags import bucket_length
 from deeplearning4j_tpu.runtime.watchdog import StepWatchdog
@@ -364,19 +362,10 @@ class GenerationEngine:
         )
         self.breaker = server.breaker if server is not None else None
 
-        embed, pos, blocks, head = _plan(self.model)
-        self._stack = (embed, pos, tuple(blocks), head)
-        names = [l.name for l in self.model.conf.layers]
-        self._embed_name, self._head_name = names[0], names[-1]
-        self._pos_name = pos.name if pos is not None else None
-        self._block_names = [b.name for b in blocks]
-        self._d = embed.n_out
-        self._n_heads = blocks[0].n_heads
-        self._head_dim = blocks[0].d_model // blocks[0].n_heads
-
+        self._stack = stack = _plan(self.model)
         self.kv = PagedKVCache(
-            n_layers=len(blocks), n_heads=self._n_heads,
-            head_dim=self._head_dim, num_pages=cfg.num_pages,
+            n_layers=len(stack.blocks), n_heads=stack.n_heads,
+            head_dim=stack.head_dim, num_pages=cfg.num_pages,
             page_size=cfg.page_size, kv_dtype=cfg.kv_dtype,
         )
         self._quantum = cfg.prefill_quantum or self.kv.page_size
@@ -417,12 +406,14 @@ class GenerationEngine:
         self._rows_attended = 0
         self._counts_flushed = (0, 0, 0)
         self._tokens_out = 0
-        self._step_fn = None
+        # the compiled decode programs by chunk width c: 1 is the plain
+        # step, spec_k + 1 the speculative verify (built at first use)
+        self._step_fns: dict[int, Callable] = {}
         self._prefill_fns: dict[int, Callable] = {}
         # speculative decode: resolve the engine-wide draft length and
         # drafter once (env knobs DL4J_TPU_SPEC_K/DL4J_TPU_SPEC_DRAFTER,
         # overridden by explicit config fields); spec_k == 0 keeps the
-        # whole path disabled and the verify program never built
+        # whole path disabled and the c > 1 program never built
         k = (cfg.spec_k if cfg.spec_k is not None
              else speculative.spec_k_from_env(0))
         self.spec_k = max(0, int(k))
@@ -432,9 +423,8 @@ class GenerationEngine:
                 cfg.spec_drafter or speculative.drafter_from_env(),
                 draft_model=cfg.spec_draft_model,
             )
-        self._verify_fn = None
         self._vocab = int(
-            self.model.params[self._embed_name]["W"].shape[0])
+            self.model.params[stack.embed.name]["W"].shape[0])
         self._spec_counts = {"drafted": 0, "accepted": 0, "rejected": 0,
                              "bonus": 0, "emitted": 0,
                              "verify_dispatches": 0,
@@ -534,7 +524,7 @@ class GenerationEngine:
                 f"stream needs {span} KV positions; the page table holds "
                 f"{cap} (max_pages_per_seq x page_size)"
             )
-        _, pos, _, _ = self._stack
+        pos = self._stack.pos
         if pos is not None and pos.learned and span > pos.max_length:
             raise ValueError(
                 f"stream needs {span} positions; learned "
@@ -676,10 +666,7 @@ class GenerationEngine:
 
     # -- compiled programs -------------------------------------------------
     def _make_prefill(self, t_b: int):
-        embed, pos, blocks, head = self._stack
-        pos_name, head_name = self._pos_name, self._head_name
-        block_names, embed_name = self._block_names, self._embed_name
-        dt = jnp.bfloat16 if self.model._bf16 else jnp.float32
+        stack = self._stack
 
         @jax.jit
         def prefill(params, prompt_pad, prompt_len, seed, temp, top_k):
@@ -687,23 +674,16 @@ class GenerationEngine:
             # causal attention they influence nothing before them, and
             # their garbage K/V rows sit beyond seq_len (masked at
             # decode, overwritten as the stream grows into them)
-            E = params[embed_name]["W"].astype(dt)
-            x = embed._act()(E[prompt_pad])
-            if pos is not None:
-                x, _ = pos.apply(params.get(pos_name, {}), {}, x)
-            ks, vs = [], []
-            for cfg_b, nm in zip(blocks, block_names):
-                x, k, v = _block_prefill(cfg_b, params[nm], x, None)
-                ks.append(k[0])
-                vs.append(v[0])
-            h_last = x[0, prompt_len - 1]
-            logits = _head_logits(head, params[head_name], h_last)
+            x, kvs = prompt_forward(stack, params, prompt_pad,
+                                    _act_dtype(self.model))
+            logits = _head_logits(stack, params, x[0, prompt_len - 1])
             first = _sample_token(
                 logits, temp, top_k,
                 jax.random.fold_in(jax.random.key(seed), 0),
             )
-            return (jnp.stack(ks).astype(jnp.float32),
-                    jnp.stack(vs).astype(jnp.float32), first)
+            return (jnp.stack([k[0] for k, _ in kvs]).astype(jnp.float32),
+                    jnp.stack([v[0] for _, v in kvs]).astype(jnp.float32),
+                    first)
 
         return prefill
 
@@ -733,88 +713,17 @@ class GenerationEngine:
         )
         return k, v, int(first), req.t_submit
 
-    def _make_step(self):
-        embed, pos, blocks, head = self._stack
-        pos_name, head_name = self._pos_name, self._head_name
-        block_names, embed_name = self._block_names, self._embed_name
-        d, ps = self._d, self.kv.page_size
-        h_, dh = self._n_heads, self._head_dim
-        quant = self.kv.kv_dtype == "int8"
-        impl = self.config.attention_impl
-        interp = self.config.attention_interpret
-        n_slots = self.config.slots
-
-        # the pool is DONATED and every layer of it read and written in
-        # place (`layer=li`, `.at[li, ...]`): the step never holds a
-        # second pool or a per-layer piece of one
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-        def step(params, k_pages, v_pages, k_scales, v_scales,
-                 page_tbl, seq_lens, last_tok, seeds, gen_counts,
-                 temps, top_ks):
-            dt = jnp.bfloat16 if self.model._bf16 else jnp.float32
-            active = seq_lens > 0
-            pos_idx = seq_lens                       # write position
-            E = params[embed_name]["W"].astype(dt)
-            x_t = embed._act()(E[last_tok])          # (S, D)
-            pe = jax.vmap(
-                lambda t: _pe_row(pos, params.get(pos_name, {}), t, d)
-            )(pos_idx)
-            x_t = x_t + pe.astype(dt)
-            page_of = page_tbl[jnp.arange(n_slots), pos_idx // ps]
-            row_of = pos_idx % ps
-            attend = seq_lens + 1                    # includes this token
-            for li, (cfg_b, nm) in enumerate(zip(blocks, block_names)):
-                lp = params[nm]
-                ap = lp["attn"]
-                hh = _ln(lp["ln1"], x_t)
-                q = (hh @ ap["Wq"].astype(dt)).reshape(n_slots, h_, dh)
-                k_t = (hh @ ap["Wk"].astype(dt)).reshape(n_slots, h_, dh)
-                v_t = (hh @ ap["Wv"].astype(dt)).reshape(n_slots, h_, dh)
-                if quant:
-                    kq, ksc = quantize_page_rows(k_t)
-                    vq, vsc = quantize_page_rows(v_t)
-                    k_pages = k_pages.at[li, page_of, row_of].set(kq)
-                    v_pages = v_pages.at[li, page_of, row_of].set(vq)
-                    k_scales = k_scales.at[li, page_of, row_of].set(ksc)
-                    v_scales = v_scales.at[li, page_of, row_of].set(vsc)
-                else:
-                    k_pages = k_pages.at[li, page_of, row_of].set(
-                        k_t.astype(k_pages.dtype))
-                    v_pages = v_pages.at[li, page_of, row_of].set(
-                        v_t.astype(v_pages.dtype))
-                # the scales are None for an f32 pool
-                attn = paged_attention(
-                    q.astype(jnp.float32), k_pages, v_pages, page_tbl,
-                    attend, k_scale=k_scales, v_scale=v_scales, layer=li,
-                    impl=impl, interpret=interp,
-                )
-                out = attn.reshape(n_slots, h_ * dh).astype(dt)
-                x_t = x_t + out @ ap["Wo"].astype(dt)
-                hh = _ln(lp["ln2"], x_t)
-                hh = cfg_b.ffn_activation(
-                    hh @ lp["W1"].astype(dt) + lp["b1"].astype(dt))
-                x_t = x_t + (hh @ lp["W2"].astype(dt)
-                             + lp["b2"].astype(dt))
-            logits = _head_logits(head, params[head_name], x_t)
-            keys = _slot_keys(seeds, gen_counts)
-            nxt = jax.vmap(_sample_token)(
-                logits.astype(jnp.float32), temps, top_ks, keys,
-            )
-            nxt = jnp.where(active, nxt, 0)
-            return k_pages, v_pages, k_scales, v_scales, nxt
-
-        return step
-
-    def _make_verify(self):
-        """The speculative verify-once program: ONE dispatch scores a
-        C = spec_k + 1 token chunk per slot (the stream's last token
-        plus its k draft proposals) through the SAME paged pool the
-        plain step uses — shaped like a short prefill, compiled once,
-        so speculation never grows the program set.
+    def _make_step(self, c: int = 1):
+        """The decode program: ONE dispatch advances every slot by a
+        ``c``-token chunk through the paged pool.  ``c == 1`` is the
+        plain step (``toks``: (S,), the slots' last tokens); ``c ==
+        spec_k + 1`` the speculative verify-once pass (``toks``: (S, c),
+        the last token plus k draft proposals) — shaped like a short
+        prefill, compiled once, so speculation adds one program.
 
         Chunk row ``j`` of slot ``s`` writes K/V at sequence position
         ``seq_len + j`` and attends positions ``< seq_len + j + 1``
-        (all C rows are written before the chunk attends; masking in
+        (all c rows are written before the chunk attends; masking in
         `paged_attention_chunk` expresses the in-chunk causality), so
         its logits are bit-equal to what ``j`` sequential plain steps
         over the same tokens would produce.  Row ``j``'s token is
@@ -823,101 +732,87 @@ class GenerationEngine:
         what makes the harvested accept-prefix + corrected/bonus token
         BYTE-identical to plain decode at any temperature, not merely
         distribution-identical."""
-        embed, pos, blocks, head = self._stack
-        pos_name, head_name = self._pos_name, self._head_name
-        block_names, embed_name = self._block_names, self._embed_name
-        d, ps = self._d, self.kv.page_size
-        h_, dh = self._n_heads, self._head_dim
+        stack, ps = self._stack, self.kv.page_size
         quant = self.kv.kv_dtype == "int8"
         impl = self.config.attention_impl
         interp = self.config.attention_interpret
-        n_slots = self.config.slots
-        mp = self.config.max_pages_per_seq
-        c = self.spec_k + 1
-        cap = mp * ps
+        n_slots, mp = self.config.slots, self.config.max_pages_per_seq
+        cap, n = mp * ps, n_slots * c
+        # a per-slot value, once for each of the slot's c chunk rows
+        rows = lambda a: jnp.repeat(a, c, axis=0)
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-        def verify(params, k_pages, v_pages, k_scales, v_scales,
-                   page_tbl, seq_lens, chunk_toks, seeds, gen_counts,
-                   temps, top_ks):
-            dt = jnp.bfloat16 if self.model._bf16 else jnp.float32
-            n = n_slots * c
+        # the pool is DONATED and every layer of it read and written in
+        # place (`layer=li`, `.at[li, ...]`): the step never holds a
+        # second pool or a per-layer piece of one
+        def step(params, k_pages, v_pages, k_scales, v_scales,
+                 page_tbl, seq_lens, toks, seeds, gen_counts,
+                 temps, top_ks):
             active = seq_lens > 0
-            act_r = jnp.repeat(active, c)
-            # flattened (S*C, ...) throughout so every matmul keeps the
-            # plain step's 2-D shape (only M grows, S -> S*C)
+            # flattened (S*c, ...) throughout so every matmul keeps the
+            # plain step's 2-D shape (only M grows, S -> S*c)
             pos2 = seq_lens[:, None] + jnp.arange(c)[None, :]
-            pos_idx = pos2.reshape(n)
-            E = params[embed_name]["W"].astype(dt)
-            x_t = embed._act()(E[chunk_toks.reshape(n)])
-            pe = jax.vmap(
-                lambda t: _pe_row(pos, params.get(pos_name, {}), t, d)
-            )(pos_idx)
-            x_t = x_t + pe.astype(dt)
+            pos_idx = pos2.reshape(n)                # write positions
+            x = embed_tokens(stack, params, toks.reshape(n), pos_idx,
+                             _act_dtype(self.model))
             # write guard: a row past the table capacity lands on the
             # scratch page — NEVER index-clamp into a real page, that
             # would clobber a live row; rows within capacity but past
             # the allocated table hit entries that are already
             # SCRATCH_PAGE.  Accepted rows always fit (emit <= the
             # admission-funded budget), so only rejected-tail garbage
-            # ever spills.
-            tbl_rep = jnp.repeat(page_tbl, c, axis=0)
+            # ever spills — and at c == 1 nothing does.
             write_ok = pos_idx < cap
             page_of = jnp.where(
                 write_ok,
-                tbl_rep[jnp.arange(n),
-                        jnp.minimum(pos_idx // ps, mp - 1)],
+                rows(page_tbl)[jnp.arange(n),
+                               jnp.minimum(pos_idx // ps, mp - 1)],
                 SCRATCH_PAGE,
             )
             row_of = jnp.where(write_ok, pos_idx % ps, 0)
-            attend = jnp.where(active[:, None],
-                               jnp.minimum(pos2 + 1, cap), 0)
-            for li, (cfg_b, nm) in enumerate(zip(blocks, block_names)):
-                lp = params[nm]
-                ap = lp["attn"]
-                hh = _ln(lp["ln1"], x_t)
-                q = (hh @ ap["Wq"].astype(dt)).reshape(n, h_, dh)
-                k_t = (hh @ ap["Wk"].astype(dt)).reshape(n, h_, dh)
-                v_t = (hh @ ap["Wv"].astype(dt)).reshape(n, h_, dh)
-                qc = q.astype(jnp.float32).reshape(n_slots, c, h_, dh)
+            # each row attends its prefix, itself included; idle slots 0
+            attend_lens = jnp.where(active[:, None],
+                                    jnp.minimum(pos2 + 1, cap), 0)
+            pool = [k_pages, v_pages, k_scales, v_scales]
+
+            def attend(li, q, k_t, v_t):
+                # layer li's rows into the pool, then the chunk against it
+                kp, vp, ksc, vsc = pool
                 if quant:
-                    kq, ksc = quantize_page_rows(k_t)
-                    vq, vsc = quantize_page_rows(v_t)
-                    k_pages = k_pages.at[li, page_of, row_of].set(kq)
-                    v_pages = v_pages.at[li, page_of, row_of].set(vq)
-                    k_scales = k_scales.at[li, page_of, row_of].set(ksc)
-                    v_scales = v_scales.at[li, page_of, row_of].set(vsc)
+                    kq, k_sc = quantize_page_rows(k_t)
+                    vq, v_sc = quantize_page_rows(v_t)
+                    kp = kp.at[li, page_of, row_of].set(kq)
+                    vp = vp.at[li, page_of, row_of].set(vq)
+                    ksc = ksc.at[li, page_of, row_of].set(k_sc)
+                    vsc = vsc.at[li, page_of, row_of].set(v_sc)
                 else:
-                    k_pages = k_pages.at[li, page_of, row_of].set(
-                        k_t.astype(k_pages.dtype))
-                    v_pages = v_pages.at[li, page_of, row_of].set(
-                        v_t.astype(v_pages.dtype))
-                attn = paged_attention_chunk(
-                    qc, k_pages, v_pages, page_tbl, attend,
-                    k_scale=k_scales, v_scale=v_scales, layer=li,
-                    impl=impl, interpret=interp,
+                    # the scales are None for an f32 pool
+                    kp = kp.at[li, page_of, row_of].set(k_t.astype(kp.dtype))
+                    vp = vp.at[li, page_of, row_of].set(v_t.astype(vp.dtype))
+                pool[:] = kp, vp, ksc, vsc
+                return paged_attention_chunk(
+                    q.astype(jnp.float32).reshape((n_slots, c) + q.shape[1:]),
+                    kp, vp, page_tbl, attend_lens, k_scale=ksc, v_scale=vsc,
+                    layer=li, impl=impl, interpret=interp,
                 )
-                out = attn.reshape(n, h_ * dh).astype(dt)
-                x_t = x_t + out @ ap["Wo"].astype(dt)
-                hh = _ln(lp["ln2"], x_t)
-                hh = cfg_b.ffn_activation(
-                    hh @ lp["W1"].astype(dt) + lp["b1"].astype(dt))
-                x_t = x_t + (hh @ lp["W2"].astype(dt)
-                             + lp["b2"].astype(dt))
-            logits = _head_logits(head, params[head_name], x_t)
+
+            for li, cfg_b in enumerate(stack.blocks):
+                x = block(cfg_b, params[cfg_b.name], x,
+                          functools.partial(attend, li))
+            logits = _head_logits(stack, params, x)
             keys = _slot_keys(
-                jnp.repeat(seeds, c),
+                rows(seeds),
                 (gen_counts[:, None] + jnp.arange(c)[None, :]).reshape(n),
             )
             nxt = jax.vmap(_sample_token)(
-                logits.astype(jnp.float32), jnp.repeat(temps, c),
-                jnp.repeat(top_ks, c), keys,
+                logits.astype(jnp.float32), rows(temps), rows(top_ks),
+                keys,
             )
-            nxt = jnp.where(act_r, nxt, 0)
-            return (k_pages, v_pages, k_scales, v_scales,
-                    nxt.reshape(n_slots, c))
+            nxt = jnp.where(rows(active), nxt, 0)
+            return (*pool, nxt.reshape(toks.shape))
 
-        return verify
+        # the names the profile is read by: `jit_step`, `jit_verify`
+        step.__name__ = step.__qualname__ = "step" if c == 1 else "verify"
+        return jax.jit(step, donate_argnums=(1, 2, 3, 4))
 
     # -- the decode loop ---------------------------------------------------
     def _loop(self, my_gen: int) -> None:
@@ -1168,15 +1063,14 @@ class GenerationEngine:
             args = (self._page_tbl.copy(), seq_lens, toks_in,
                     self._seeds.copy(), gen0, self._temps.copy(),
                     self._top_ks.copy())
+            # built at first use; `jax.jit` construction is lazy, so
+            # cheap under the lock (see `_prefill_fn`)
+            fn = self._step_fns.get(c)
+            if fn is None:
+                fn = self._step_fns[c] = self._make_step(c)
         if drafts is None:
-            if self._step_fn is None:
-                self._step_fn = self._make_step()
-            fn, harvest = self._step_fn, self._harvest_plain
+            harvest = self._harvest_plain
         else:
-            if self._verify_fn is None:
-                self._verify_fn = self._make_verify()
-            fn = self._verify_fn
-
             def harvest(*a):
                 self._harvest_verify(*a, toks_in, dl, gen0)
 

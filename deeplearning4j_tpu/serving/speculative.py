@@ -48,9 +48,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.ops.generation import (
-    _block_prefill,
+    _act_dtype,
     _head_logits,
     _plan,
+    prompt_forward,
 )
 from deeplearning4j_tpu.runtime.flags import bucket_length
 
@@ -136,32 +137,19 @@ class ModelDrafter(DraftSource):
         self.model = model
         self._quantum = int(quantum)
         self._fns: dict = {}
-        embed, pos, blocks, head = _plan(model)
-        self._stack = (embed, pos, tuple(blocks), head)
-        names = [l.name for l in model.conf.layers]
-        self._embed_name, self._head_name = names[0], names[-1]
-        self._pos_name = pos.name if pos is not None else None
-        self._block_names = [b.name for b in blocks]
+        self._stack = _plan(model)
 
     def _fn(self, t_b: int):
         fn = self._fns.get(t_b)
         if fn is not None:
             return fn
-        embed, pos, blocks, head = self._stack
-        pos_name, head_name = self._pos_name, self._head_name
-        block_names, embed_name = self._block_names, self._embed_name
-        dt = jnp.bfloat16 if self.model._bf16 else jnp.float32
+        stack = self._stack
 
         @jax.jit
         def last_greedy(params, toks_pad, true_len):
-            E = params[embed_name]["W"].astype(dt)
-            x = embed._act()(E[toks_pad])
-            if pos is not None:
-                x, _ = pos.apply(params.get(pos_name, {}), {}, x)
-            for cfg_b, nm in zip(blocks, block_names):
-                x, _, _ = _block_prefill(cfg_b, params[nm], x, None)
-            h_last = x[0, true_len - 1]
-            logits = _head_logits(head, params[head_name], h_last)
+            x, _ = prompt_forward(stack, params, toks_pad,
+                                  _act_dtype(self.model))
+            logits = _head_logits(stack, params, x[0, true_len - 1])
             return jnp.argmax(logits).astype(jnp.int32)
 
         self._fns[t_b] = last_greedy
@@ -171,7 +159,7 @@ class ModelDrafter(DraftSource):
         toks = np.asarray(history, np.int32).reshape(-1)
         if k <= 0 or toks.shape[0] < 1:
             return _EMPTY
-        _, pos, _, _ = self._stack
+        pos = self._stack.pos
         if (pos is not None and pos.learned
                 and toks.shape[0] + k > pos.max_length):
             return _EMPTY                 # would overflow the draft PE

@@ -2,8 +2,8 @@
 
 The paged pools + page table are the serving plane's KV layout; this
 file holds the three implementations to each other and to the dense
-`_block_step` numerics: the XLA gather reference IS the contract, the
-Pallas online-softmax kernel (interpret mode on CPU) must match it to
+`cache_row_attention` numerics: the XLA gather reference IS the contract,
+the Pallas online-softmax kernel (interpret mode on CPU) must match it to
 float tolerance, and the fused int8 path must match dequantize-then-
 attend exactly (the dequant is algebraically hoisted, not
 approximated).  Masking is load-bearing: garbage rows past ``seq_len``
@@ -46,7 +46,8 @@ def _case(seed=0, seq_lens=(7, 1, 13, 4)):
 
 def _dense_reference(q, k_pages, v_pages, tbl, seq_lens):
     """Per-slot dense softmax attention over the gathered live rows —
-    `ops.generation._block_step`'s numerics, computed independently."""
+    `ops.generation.cache_row_attention`'s numerics, computed
+    independently."""
     q, kp, vp = map(np.asarray, (q, k_pages, v_pages))
     tbl, seq_lens = np.asarray(tbl), np.asarray(seq_lens)
     out = np.zeros_like(q)

@@ -412,6 +412,24 @@ class TestLadder:
 
 # -- the pool is held once: donated, rebound, revived -------------------------
 
+def _aval(a):
+    return None if a is None else jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+
+def _step_avals(eng, model, c):
+    """The abstract arguments `eng._make_step(c)` is lowered with."""
+    s, mp = eng.config.slots, eng.config.max_pages_per_seq
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((s,) + tail, dtype)
+
+    toks = vec(jnp.int32) if c == 1 else vec(jnp.int32, c)
+    return (jax.tree.map(_aval, model.params),
+            *[_aval(a) for a in eng.kv.pool()],
+            vec(jnp.int32, mp), vec(jnp.int32), toks, vec(jnp.uint32),
+            vec(jnp.int32), vec(jnp.float32), vec(jnp.int32))
+
+
 def _deleted(arrays):
     return [a.is_deleted() for a in arrays if a is not None]
 
@@ -488,22 +506,10 @@ class TestDonatedPool:
     def test_compiled_programs_alias_the_whole_pool(self, model, kv_dtype,
                                                     program):
         eng = _engine(model, kv_dtype=kv_dtype, spec_k=2)
-        s, mp = CFG["slots"], CFG["max_pages_per_seq"]
-
-        def sds(a):
-            return jax.ShapeDtypeStruct(a.shape, a.dtype)
-
-        def vec(dtype, *tail):
-            return jax.ShapeDtypeStruct((s,) + tail, dtype)
-
-        toks = vec(jnp.int32) if program == "step" else vec(jnp.int32, 3)
-        fn = eng._make_step() if program == "step" else eng._make_verify()
-        compiled = fn.lower(
-            jax.tree.map(sds, model.params),
-            *[None if a is None else sds(a) for a in eng.kv.pool()],
-            vec(jnp.int32, mp), vec(jnp.int32), toks, vec(jnp.uint32),
-            vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
-        ).compile()
+        c = 1 if program == "step" else 3
+        fn = eng._make_step() if c == 1 else eng._make_step(c)
+        assert fn.__name__ == program     # what the profile is read by
+        compiled = fn.lower(*_step_avals(eng, model, c)).compile()
         pool_bytes = sum(a.nbytes for a in eng.kv.pool() if a is not None)
         assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
@@ -516,11 +522,11 @@ class TestDonatedPool:
         release = threading.Event()
         try:
             eng.generate(_prompt(4), 2, timeout=120.0)     # warm
-            real = eng._step_fn
+            real = eng._step_fns[1]
             entered = threading.Event()
 
             def consume_then_fail(*a):
-                eng._step_fn = real                        # one shot
+                eng._step_fns[1] = real                    # one shot
                 out = real(*a)                             # pool consumed
                 if how == "raises_after_dispatch":
                     raise RuntimeError("device lost")
@@ -531,7 +537,7 @@ class TestDonatedPool:
             if how == "fault_before_dispatch":
                 faults.arm("serving.decode:raise:nth=1")
             else:
-                eng._step_fn = consume_then_fail
+                eng._step_fns[1] = consume_then_fail
             req = eng.submit(_prompt(4), 6)
             if how == "wedged_after_dispatch":
                 assert entered.wait(60.0)
@@ -567,6 +573,54 @@ class TestDonatedPool:
 
 
 # -- bounded program set -----------------------------------------------------
+
+class TestOneBlock:
+    """Every inference program runs `ops.generation.block`, once per
+    layer: no program carries a block body of its own (PR 30)."""
+
+    @pytest.mark.parametrize("program", ["generate", "prefill", "step",
+                                         "verify", "drafter"])
+    def test_every_program_traces_the_one_block(self, model, monkeypatch,
+                                                program):
+        from deeplearning4j_tpu.ops import generation as dense
+        from deeplearning4j_tpu.serving import generation as serving
+        from deeplearning4j_tpu.serving.speculative import ModelDrafter
+
+        calls, real = [], dense.block
+
+        def counted(cfg, lp, x, attend):
+            calls.append(cfg.name)
+            return real(cfg, lp, x, attend)
+
+        monkeypatch.setattr(dense, "block", counted)
+        monkeypatch.setattr(serving, "block", counted)
+        spec_k = 2
+        eng = _engine(model, spec_k=spec_k)
+        params = jax.tree.map(_aval, model.params)
+        prompt = jax.ShapeDtypeStruct((1, 8), jnp.int32)
+        scalar = lambda dt: jax.ShapeDtypeStruct((), dt)
+        if program == "generate":
+            # a fresh jit of the whole dense program: prefill + ONE scan
+            # tick, each of which walks the stack once
+            fn = dense._generate_jit(model, dense._plan(model), 4, 0.0, 0)
+            fn.lower(params, prompt, jax.random.key(0))
+            want = 2 * LAYERS
+        elif program == "prefill":
+            eng._make_prefill(8).lower(
+                params, prompt, scalar(jnp.int32), scalar(jnp.uint32),
+                scalar(jnp.float32), scalar(jnp.int32))
+            want = LAYERS
+        elif program == "drafter":
+            ModelDrafter(model)._fn(8).lower(params, prompt,
+                                             scalar(jnp.int32))
+            want = LAYERS
+        else:
+            c = 1 if program == "step" else spec_k + 1
+            eng._make_step(c).lower(*_step_avals(eng, model, c))
+            want = LAYERS
+        assert len(calls) == want and set(calls) == {
+            b.name for b in eng._stack.blocks}
+
 
 class TestCompileStability:
     def test_zero_fresh_compiles_after_warm_up(self, model):
