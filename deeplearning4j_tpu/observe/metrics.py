@@ -754,6 +754,12 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "once (seq_len + spec_k + 1, capped at the page "
                 "table's span).  Times layers x 2 x heads x head size x "
                 "bytes per element it is the KV bytes demanded")
+    reg.counter("dl4jtpu_serving_params_casts_total",
+                "Serving copies of the parameter tree the generation "
+                "engines made (the matrices cast to the activation "
+                "type once, not in every step): 1 at an engine's "
+                "start, + 1 per installed hot-swap, flat across decode "
+                "steps.  Bridged by the decode counts' pull collector")
     reg.gauge("dl4jtpu_kv_pages_used",
               "KV pool pages currently owned by live streams "
               "(page 0, the scratch page, never counts)")

@@ -19,19 +19,22 @@ agreement gate.
 
 What a stack computes is written HERE, once: `_plan` (the stack's
 layers), `embed_tokens` (gather, activation, position), `block` (one
-transformer block over an `attend(q, k, v)` callback) and `_head_logits`.
-`generate()`, the engine's prefill / step / verify programs and the model
-drafter (`serving/speculative.py`) all call them; a caller only chooses
-which state the attention runs against — the whole prompt
-(`prompt_forward`), a dense cache row (`cache_row_attention`), or the
-paged pool (the engine's closure).  Changing decode semantics here changes
-every one of them; the paged parity tests
-(`tests/test_paged_generation.py`) hold the engine to `generate()`.
+transformer block over an `attend(q, k, v)` callback) and `_head_logits`;
+`serving_params` names the leaves they cast at use, for a caller that
+keeps a tree across calls.  `generate()`, the engine's prefill / step /
+verify programs and the model drafter (`serving/speculative.py`) all call
+them; a caller only chooses which state the attention runs against — the
+whole prompt (`prompt_forward`), a dense cache row
+(`cache_row_attention`), or the paged pool (the engine's closure).
+Changing decode semantics here changes every one of them; the paged
+parity tests (`tests/test_paged_generation.py`) hold the engine to
+`generate()`.
 """
 
 from __future__ import annotations
 
 import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +52,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
 )
 from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
 from deeplearning4j_tpu.ops.attention import mha
+from deeplearning4j_tpu.quant.qtensor import QuantizedTensor
 
 #: what `_plan` returns: the stack's layers (each names its own entry of
 #: the params tree, `layer.name`) and the widths every caller needs
@@ -98,6 +102,41 @@ def _plan(model):
 
 def _act_dtype(model):
     return jnp.bfloat16 if model._bf16 else jnp.float32
+
+
+#: the leaves of a block's entry that `block` casts to the activation type
+#: at use (the embedding's are "W", the head's "W" and "b")
+_BLOCK_CAST = frozenset(("Wq", "Wk", "Wv", "Wo", "gamma", "beta",
+                         "W1", "b1", "W2", "b2"))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast_leaves(leaves, dt):
+    return [a.astype(dt) for a in leaves]
+
+
+def serving_params(stack, params, dt):
+    """The tree a long-lived caller dispatches its programs with: the
+    structure of ``params``, in which exactly the leaves `embed_tokens`,
+    `block` and `_head_logits` cast at use are in ``dt`` already, so the
+    casts there trace to nothing and a program reads a weight at the
+    width it multiplies.  Every other leaf is the object ``params``
+    holds: the position table (read in f32), integer leaves, any
+    `QuantizedTensor`, and every leaf that is ``dt`` already — all of
+    them where ``dt`` is f32.  One jitted cast, made once per tree."""
+    cast = {stack.embed.name: ("W",), stack.head.name: ("W", "b"),
+            **{b.name: _BLOCK_CAST for b in stack.blocks}}
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        params, is_leaf=lambda a: isinstance(a, QuantizedTensor))
+    leaves = [a for _, a in flat]
+    wide = [i for i, (path, a) in enumerate(flat)
+            if path[-1].key in cast.get(path[0].key, ())
+            and not isinstance(a, QuantizedTensor)
+            and jnp.issubdtype(a.dtype, jnp.floating) and a.dtype != dt]
+    if wide:
+        for i, a in zip(wide, _cast_leaves([leaves[i] for i in wide], dt)):
+            leaves[i] = a
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def _pe_row(pos_layer, lp, t, d):

@@ -29,9 +29,9 @@ loop into the serving plane the way the Gemma-on-TPU serving stack does:
   a silent stall), each decode step runs under a `StepWatchdog` whose
   abort fails every in-flight stream AND releases all their pages, the
   shared breaker trips on step failures, and a hot-swap lands between
-  decode steps (the step snapshots params under the server's weights
-  lock) so in-flight streams finish — on the new weights — with zero
-  drops.
+  decode steps (every dispatch reads the installed tree under the
+  server's weights lock and serves its copy of it, `_serving_params`)
+  so in-flight streams finish — on the new weights — with zero drops.
 
 Numerics contract: greedy paged decode is token-identical to
 `ops.generation.generate` for f32 (same per-position math, same
@@ -94,6 +94,7 @@ from deeplearning4j_tpu.ops.generation import (
     block,
     embed_tokens,
     prompt_forward,
+    serving_params,
 )
 from deeplearning4j_tpu.ops.paged_attention import paged_attention_chunk
 from deeplearning4j_tpu.runtime import faults
@@ -150,6 +151,20 @@ def _gen_breakdown_families() -> dict:
 DECODE_COUNT_FAMILIES = ("dl4jtpu_decode_steps_total",
                          "dl4jtpu_decode_slot_steps_total",
                          "dl4jtpu_decode_rows_attended_total")
+
+#: serving copies of the parameter tree made (`_serving_params`): one per
+#: installed tree, flushed with the decode counts
+PARAMS_CASTS_FAMILY = "dl4jtpu_serving_params_casts_total"
+
+#: a prompt forward of fewer tokens than this spends longer READING f32
+#: block matrices than multiplying by them (2 FLOPs a token against 4
+#: bytes a weight: 481 tokens on a v5e, 330-460 on its siblings), so its
+#: bucket is given the copy's narrow ones; a longer bucket gains next to
+#: nothing from them (PERF.md section 6, PR 31: 2 % at 1024-1792 tokens)
+#: and keeps the wide ones — the TPU compiler writes five times the code
+#: for a prompt forward over narrow matrices (19 -> 102 MB a bucket), and
+#: every process start pays to load it
+WEIGHT_BOUND_PREFILL_TOKENS = 480
 
 _ENGINES: "weakref.WeakSet[GenerationEngine]" = weakref.WeakSet()
 _ENGINES_LOCK = threading.Lock()
@@ -404,7 +419,12 @@ class GenerationEngine:
         self._steps = 0
         self._slot_steps = 0
         self._rows_attended = 0
-        self._counts_flushed = (0, 0, 0)
+        # `model.params` as the copies below were made from it, the tree
+        # the programs are dispatched with, and the long prefill buckets'
+        # (`_serving_params`); and how many were made
+        self._served = (None, None, None)
+        self._params_casts = 0
+        self._counts_flushed = (0, 0, 0, 0)
         self._tokens_out = 0
         # the compiled decode programs by chunk width c: 1 is the plain
         # step, spec_k + 1 the speculative verify (built at first use)
@@ -458,6 +478,7 @@ class GenerationEngine:
         if self._thread is not None and self._thread.is_alive():
             return self
         self._stop.clear()
+        self._serving_params()      # made here, not by the first request
         with self._mu:
             self._loop_gen += 1
             gen = self._loop_gen
@@ -665,6 +686,32 @@ class GenerationEngine:
         return req
 
     # -- compiled programs -------------------------------------------------
+    def _serving_params(self, t_b: int = 0):
+        """What a program is dispatched with: `model.params` with the
+        matrices already in the activation type (`serving_params`), made
+        once per installed tree and not once per step.  ``t_b`` is the
+        prefill bucket the tree is for (0: the decode programs); a
+        bucket past `WEIGHT_BOUND_PREFILL_TOKENS` gets the same tree
+        with the blocks' entries as `model.params` holds them — the
+        same leaves either way, so no third set of weights.  This read
+        is the hot-swap boundary: `push_weights` installs under the same
+        lock, so the dispatch after a swap finds another tree there and
+        serves its copy — a swap lands BETWEEN decode steps and
+        in-flight streams continue, on the new weights, with zero
+        drops."""
+        with self._weights_lock:
+            live = self.model.params
+            if self._served[0] is not live:
+                self._served = (None, None, None)   # the stale copy goes first
+                copy = serving_params(self._stack, live,
+                                      _act_dtype(self.model))
+                self._served = (live, copy, {
+                    **copy, **{b.name: live[b.name]
+                               for b in self._stack.blocks}})
+                self._params_casts += 1
+            long = t_b > WEIGHT_BOUND_PREFILL_TOKENS
+            return self._served[2 if long else 1]
+
     def _make_prefill(self, t_b: int):
         stack = self._stack
 
@@ -705,11 +752,10 @@ class GenerationEngine:
         t_b = bucket_length(t_p, self._quantum)
         pad = np.zeros((1, t_b), np.int32)
         pad[0, :t_p] = req.prompt
-        with self._weights_lock:
-            params = self.model.params
         k, v, first = self._prefill_fn(t_b)(
-            params, pad, np.int32(t_p), np.uint32(req.seed),
-            np.float32(req.temperature), np.int32(req.top_k),
+            self._serving_params(t_b), pad, np.int32(t_p),
+            np.uint32(req.seed), np.float32(req.temperature),
+            np.int32(req.top_k),
         )
         return k, v, int(first), req.t_submit
 
@@ -986,7 +1032,7 @@ class GenerationEngine:
         the engine thread with NO span around them (a gap of the device
         between two steps straddles all four; a parent would take every
         such gap for itself): ``generation.decode_prepare`` (fault
-        consult, drafts, argument copies, params snapshot — the hot-swap
+        consult, drafts, argument copies, `_serving_params` — the hot-swap
         boundary — watchdog arm) -> ``generation.decode_dispatch`` (the
         jit call and the donated pool's rebinding from its result;
         annotated with the live slots and the KV rows the step attends)
@@ -1074,11 +1120,7 @@ class GenerationEngine:
             def harvest(*a):
                 self._harvest_verify(*a, toks_in, dl, gen0)
 
-        with self._weights_lock:
-            # the hot-swap boundary: push_weights installs under this
-            # lock, so a swap lands BETWEEN decode steps and in-flight
-            # streams continue (on the new weights) with zero drops
-            params = self.model.params
+        params = self._serving_params()
         # what the step serves, from the arrays it is dispatched with:
         # a slot is live where seq_len > 0, and attends its seq_len rows
         # plus the c it writes (a verify chunk's union, capped at the
@@ -1579,6 +1621,7 @@ class GenerationEngine:
             "decode_steps": self._steps,
             "decode_slot_steps": self._slot_steps,
             "decode_rows_attended": self._rows_attended,
+            "serving_params_casts": self._params_casts,
             "tokens_generated": self._tokens_out,
             "tokens_per_s": round(self.tokens_per_s(), 4),
             "streams": {"settled": settled, "outcomes": outcomes},
@@ -1670,10 +1713,12 @@ class GenerationEngine:
 
             reg = registry()
             with self._stats_lock:
-                now = (self._steps, self._slot_steps, self._rows_attended)
+                now = (self._steps, self._slot_steps, self._rows_attended,
+                       self._params_casts)
                 delta = [a - b for a, b in zip(now, self._counts_flushed)]
                 self._counts_flushed = now
-            for family, d in zip(DECODE_COUNT_FAMILIES, delta):
+            for family, d in zip(
+                    DECODE_COUNT_FAMILIES + (PARAMS_CASTS_FAMILY,), delta):
                 if d > 0:
                     reg.counter(family).inc(d)
         except Exception as e:

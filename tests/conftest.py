@@ -103,3 +103,31 @@ class HostProfile:
 @pytest.fixture
 def host_profile(tmp_path):
     return lambda: HostProfile(str(tmp_path / "profile"))
+
+
+def learned_position_lm(vocab=31, d=16, heads=2, layers=2, *,
+                        max_length=32, bf16=None, seed=5):
+    """The serving benchmark's stack at toy widths, initialised: Embedding,
+    a LEARNED position table, causal blocks and the chunked-vocab head.
+    ``bf16=True`` forces the activation type a TPU picks by default."""
+    from deeplearning4j_tpu.models.sequential import SequentialModel
+    from deeplearning4j_tpu.nn.conf import (
+        ChunkedSoftmaxOutputLayer, Embedding, InputType,
+        NeuralNetConfiguration,
+    )
+    from deeplearning4j_tpu.nn.conf.attention import (
+        PositionalEncoding, TransformerEncoderBlock,
+    )
+
+    b = NeuralNetConfiguration.builder().seed(seed)
+    if bf16 is not None:
+        b = b.bf16_compute(bf16)
+    b = (b.list()
+         .layer(Embedding(n_in=vocab, n_out=d))
+         .layer(PositionalEncoding(learned=True, max_length=max_length)))
+    for _ in range(layers):
+        b.layer(TransformerEncoderBlock(d_model=d, n_heads=heads,
+                                        causal=True))
+    b.layer(ChunkedSoftmaxOutputLayer(n_out=vocab, chunk=16))
+    return SequentialModel(
+        b.set_input_type(InputType.recurrent(1)).build()).init()

@@ -372,6 +372,36 @@ class TestLatencySurfaces:
         assert top["ttft_s"] is not None
         assert "spans" in top and top["spans"]
 
+    def test_serving_copy_count_is_exported_and_flat_while_decoding(
+            self, model, rec):
+        """`dl4jtpu_serving_params_casts_total`: one copy of the tree at
+        `start()`, none per decode step; the pull collector bridges the
+        engine's own count, `engine.stats()` reads the same."""
+        from deeplearning4j_tpu.observe.metrics import registry
+
+        fam = "dl4jtpu_serving_params_casts_total"
+        reg = registry()
+        assert f"# TYPE {fam} counter" in reg.to_prometheus_text()
+        reg.collect()
+        before = reg.counter(fam).value()
+        eng = _engine(model)
+        assert eng.stats()["serving_params_casts"] == 0
+        eng.start()
+        try:
+            assert eng.stats()["serving_params_casts"] == 1
+            reg.collect()
+            assert reg.counter(fam).value() == before + 1
+            steps = eng.stats()["decode_steps"]
+            for i in range(2):
+                eng.generate(_prompt(4, seed=60 + i), 6, timeout=120.0)
+            assert eng.stats()["decode_steps"] >= steps + 10
+            assert eng.stats()["serving_params_casts"] == 1
+        finally:
+            eng.stop()
+        reg.collect()
+        assert reg.counter(fam).value() == before + 1
+        assert f"{fam} " in reg.to_prometheus_text()
+
     def test_status_healthz_and_ui_surfaces(self, model, rec):
         import gc
 

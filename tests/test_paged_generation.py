@@ -12,6 +12,7 @@ disaggregation seam (engine-to-engine and routed through a
 `ServingFleet` with replica roles)."""
 
 import json
+import re
 import threading
 import time
 import urllib.error
@@ -31,6 +32,7 @@ from deeplearning4j_tpu.serving.admission import (
     ServingRejected,
 )
 from deeplearning4j_tpu.serving.generation import (
+    WEIGHT_BOUND_PREFILL_TOKENS,
     GenerationConfig,
     GenerationEngine,
 )
@@ -416,15 +418,16 @@ def _aval(a):
     return None if a is None else jax.ShapeDtypeStruct(a.shape, a.dtype)
 
 
-def _step_avals(eng, model, c):
-    """The abstract arguments `eng._make_step(c)` is lowered with."""
+def _step_avals(eng, model, c, params=None):
+    """The abstract arguments `eng._make_step(c)` is lowered with
+    (``params``: another tree than the model's own)."""
     s, mp = eng.config.slots, eng.config.max_pages_per_seq
 
     def vec(dtype, *tail):
         return jax.ShapeDtypeStruct((s,) + tail, dtype)
 
     toks = vec(jnp.int32) if c == 1 else vec(jnp.int32, c)
-    return (jax.tree.map(_aval, model.params),
+    return (jax.tree.map(_aval, model.params if params is None else params),
             *[_aval(a) for a in eng.kv.pool()],
             vec(jnp.int32, mp), vec(jnp.int32), toks, vec(jnp.uint32),
             vec(jnp.int32), vec(jnp.float32), vec(jnp.int32))
@@ -620,6 +623,215 @@ class TestOneBlock:
             want = LAYERS
         assert len(calls) == want and set(calls) == {
             b.name for b in eng._stack.blocks}
+
+
+# -- the programs are dispatched with a serving copy of the tree --------------
+
+#: a weight a program takes as an ARGUMENT and narrows itself
+_ARG_NARROWED = re.compile(
+    r"stablehlo\.convert %arg\d+ : \(tensor<[0-9x]*xf32>\) -> "
+    r"tensor<[0-9x]*xbf16>")
+
+
+@pytest.fixture(scope="module")
+def bf16_lm():
+    """The activation type a TPU picks, forced here: the programs then
+    cast every matrix at use, as they do on the chip."""
+    from conftest import learned_position_lm
+
+    return learned_position_lm(vocab=VOCAB, d=D, heads=HEADS, layers=LAYERS,
+                               bf16=True)
+
+
+def _run_program(eng, program, tree):
+    """One dispatch of `program` with `tree`, on fixed inputs and a
+    fresh seeded pool (the step donates it); every output, on the host."""
+    rng = np.random.default_rng(7)
+    if program == "prefill":
+        pad = np.zeros((1, 8), np.int32)
+        pad[0, :6] = rng.integers(0, VOCAB, 6)
+        out = eng._make_prefill(8)(
+            tree, pad, np.int32(6), np.uint32(3), np.float32(1.0),
+            np.int32(0))
+        return [np.asarray(a) for a in out]
+    c = 1 if program == "step" else 3
+    s, mp = eng.config.slots, eng.config.max_pages_per_seq
+    pool = [None if a is None
+            else jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+            for a in eng.kv.pool()]
+    tbl = np.full((s, mp), SCRATCH_PAGE, np.int32)
+    tbl[:3] = 1 + np.arange(3 * mp).reshape(3, mp)
+    toks = rng.integers(0, VOCAB, (s,) if c == 1 else (s, c))
+    out = eng._make_step(c)(
+        tree, *pool, tbl, np.array([5, 9, 17, 0], np.int32),
+        toks.astype(np.int32), np.arange(s, dtype=np.uint32),
+        np.array([1, 4, 2, 0], np.int32),
+        np.array([0.0, 1.0, 0.7, 0.0], np.float32),
+        np.array([0, 0, 5, 0], np.int32))
+    return [np.asarray(a) for a in out if a is not None]
+
+
+class TestServingCopy:
+    """The engine dispatches a copy of the tree whose matrices are in the
+    activation type already (`ops.generation.serving_params`): the same
+    arithmetic on the same values, the cast made once and not per step."""
+
+    @pytest.mark.parametrize("program", ["step", "prefill", "verify"])
+    def test_copy_and_tree_give_the_same_bits(self, bf16_lm, program):
+        eng = _engine(bf16_lm, spec_k=2)
+        copy = eng._serving_params()
+        assert copy[eng._stack.embed.name]["W"].dtype == jnp.bfloat16
+        want = _run_program(eng, program, bf16_lm.params)
+        got = _run_program(eng, program, copy)
+        assert len(got) == len(want) == 3     # K, V and the tokens
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("program", ["step", "prefill", "verify"])
+    def test_program_given_the_copy_narrows_no_argument(self, bf16_lm,
+                                                        program):
+        eng = _engine(bf16_lm, spec_k=2)
+
+        def lowered(tree):
+            if program == "prefill":
+                scalar = lambda dt: jax.ShapeDtypeStruct((), dt)
+                return eng._make_prefill(8).lower(
+                    jax.tree.map(_aval, tree),
+                    jax.ShapeDtypeStruct((1, 8), jnp.int32),
+                    scalar(jnp.int32), scalar(jnp.uint32),
+                    scalar(jnp.float32), scalar(jnp.int32)).as_text()
+            c = 1 if program == "step" else 3
+            return eng._make_step(c).lower(
+                *_step_avals(eng, bf16_lm, c, params=tree)).as_text()
+
+        # the f32 tree: every matrix of every block, the table, the head
+        assert len(_ARG_NARROWED.findall(lowered(bf16_lm.params))) \
+            >= 12 * LAYERS + 3
+        assert not _ARG_NARROWED.findall(lowered(eng._serving_params()))
+
+    def test_long_prefill_buckets_keep_the_wide_block_matrices(self,
+                                                               bf16_lm):
+        """A prompt forward bound by its matmuls is given the blocks'
+        entries as the model holds them, beside the copy's table and
+        head: the same leaves, no third set of weights, no further
+        copy."""
+        eng = _engine(bf16_lm)
+        stack, live, copy = eng._stack, bf16_lm.params, eng._serving_params()
+        assert eng._serving_params(WEIGHT_BOUND_PREFILL_TOKENS) is copy
+        long = eng._serving_params(WEIGHT_BOUND_PREFILL_TOKENS + 1)
+        assert (jax.tree_util.tree_structure(long)
+                == jax.tree_util.tree_structure(copy))
+        for b in stack.blocks:
+            assert long[b.name] is live[b.name]
+            assert copy[b.name]["W1"].dtype == jnp.bfloat16
+        for layer in (stack.embed, stack.pos, stack.head):
+            assert long[layer.name] is copy[layer.name]
+        assert eng.stats()["serving_params_casts"] == 1
+
+    def test_f32_engine_serves_the_tree_itself(self, model):
+        eng = _engine(model)
+        served = eng._serving_params()
+        for a, b in zip(jax.tree.leaves(model.params),
+                        jax.tree.leaves(served)):
+            assert a is b
+        assert eng._serving_params() is served
+        assert eng.stats()["serving_params_casts"] == 1
+
+    def test_concurrent_readers_and_swaps_make_one_copy_a_tree(self, model):
+        """Readers on more threads than cores against a thread that
+        installs trees: a tree is copied once however many readers find
+        it new at once, and a reader never gets a copy of a tree that was
+        not installed."""
+        import sys
+
+        srv = InferenceServer(TransformerEncoder(
+            vocab_size=VOCAB, d_model=D, n_heads=HEADS, n_layers=LAYERS,
+            causal=True, seed=5).init_model())
+        eng = GenerationEngine(server=srv, config=GenerationConfig(**CFG))
+        swaps, stop, seen, errors = 8, threading.Event(), set(), []
+        trees = [srv.model.params] + [
+            jax.tree.map(lambda a, i=i: a + i if jnp.issubdtype(
+                a.dtype, jnp.floating) else a, srv.model.params)
+            for i in range(1, swaps + 1)]
+        own = {id(jax.tree.leaves(t)[0]) for t in trees}
+
+        def read():
+            try:
+                while not stop.is_set():
+                    seen.add(id(jax.tree.leaves(eng._serving_params())[0]))
+            except Exception as exc:
+                errors.append(exc)
+
+        readers = [threading.Thread(target=read) for _ in range(16)]
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in readers:
+                t.start()
+            for tree in trees[1:]:
+                assert srv.push_weights(tree, source="test")
+                eng._serving_params()       # every tree is found once
+            stop.set()
+            for t in readers:
+                t.join(30.0)
+                assert not t.is_alive()
+        finally:
+            stop.set()
+            sys.setswitchinterval(old)
+            srv.stop()
+        assert not errors
+        assert seen <= own
+        assert eng.stats()["serving_params_casts"] == 1 + swaps
+
+    def test_swap_in_flight_serves_the_new_tree_from_the_next_step(self):
+        """The tree is swapped from inside the engine thread's own token
+        callback, so the position it lands at is known: every token after
+        it is the new weights' — and the copy was remade once."""
+        fresh = lambda: TransformerEncoder(
+            vocab_size=VOCAB, d_model=D, n_heads=HEADS, n_layers=LAYERS,
+            causal=True, seed=5).init_model()
+        live, swapped = fresh(), fresh()
+        stack = GenerationEngine(model=live)._stack
+        # another head and another last-block FFN: the next token changes
+        # and the K/V rows already in the pool stay the new weights' own
+        new = {k: dict(v) for k, v in live.params.items()}
+        new[stack.head.name]["W"] = -new[stack.head.name]["W"]
+        last = stack.blocks[-1].name
+        new[last]["W2"] = -new[last]["W2"]
+        swapped.params = new
+        srv = InferenceServer(live)
+        eng = GenerationEngine(server=srv, config=GenerationConfig(**CFG))
+        at, pushed = 4, []
+
+        def on_token(tok, idx):
+            if idx == at - 1:           # `at` tokens are out: swap now
+                pushed.append(srv.push_weights(new, source="test"))
+
+        try:
+            eng.start()
+            assert eng.stats()["serving_params_casts"] == 1
+            p = _prompt(5, seed=90)
+            old = _dense(live, p, 12)
+            out = np.asarray(eng.submit(p, 12, on_token=on_token)
+                             .result(120.0))
+            assert pushed == [True]
+            head = out[:5 + at]
+            np.testing.assert_array_equal(head, old[:5 + at])
+            np.testing.assert_array_equal(
+                out, _dense(swapped, head, 12 - at))
+            assert not np.array_equal(out, old)
+            assert eng.stats()["serving_params_casts"] == 2
+            # a push the verification refuses: copy and count stay
+            served = eng._served
+            bad = {k: dict(v) for k, v in new.items()}
+            bad[last]["W2"] = bad[last]["W2"].at[0, 0].set(jnp.nan)
+            assert not srv.push_weights(bad, source="test")
+            eng.generate(p, 3, timeout=120.0)
+            assert eng._served is served
+            assert eng.stats()["serving_params_casts"] == 2
+        finally:
+            eng.stop()
+            srv.stop()
 
 
 class TestCompileStability:
