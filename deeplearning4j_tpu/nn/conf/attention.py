@@ -16,7 +16,10 @@ data/tensor parallelism around it).  On a single chip or a mesh without a
 scale-portable.
 
 Also here: TransformerEncoderBlock, a pre-LN encoder block (MHA + FFN with
-residuals) so a DSL-built transformer is a first-class citizen of the zoo.
+residuals) so a DSL-built transformer is a first-class citizen of the zoo;
+and LatentSparseDecoder, the `glm_moe_dsa` family's decoder (latent
+attention, a learned sparse selection shared between layers, sigmoid-routed
+experts) as ONE layer, because the selection crosses its blocks.
 """
 
 from __future__ import annotations
@@ -384,3 +387,199 @@ class TransformerEncoderBlock(LayerConfig):
         )
         h = quantf.matmul(h, params["W2"]) + params["b2"].astype(x.dtype)
         return x + h, state
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentBlock:
+    """One layer of a `LatentSparseDecoder`, as `ops/latent.latent_block`
+    and the serving engine read it: a view with the decoder's widths, this
+    layer's kinds and where its parameters sit (``path`` into the model's
+    tree).  Not a DSL layer of its own — the selection of a ``"full"``
+    layer serves the ``"shared"`` ones after it, so the layers only exist
+    together."""
+
+    name: str
+    path: tuple
+    indexer: str                  # "full" | "shared"
+    ffn: str                      # "dense" | "sparse"
+    d_model: int
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    rms_eps: float
+    index_n_heads: int
+    index_head_dim: int
+    index_topk: int
+    top_k: int
+    routed_scale: float
+    held_first: int
+    causal: bool = True
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+
+    @property
+    def cache_rows(self) -> dict:
+        """What one token caches in this layer: pool name -> row width."""
+        rows = {"latent": self.kv_lora_rank + self.qk_rope_head_dim}
+        if self.indexer == "full":
+            rows["index_key"] = self.index_head_dim
+        return rows
+
+
+@serde.register
+@dataclasses.dataclass(frozen=True)
+class LatentSparseDecoder(LayerConfig):
+    """The decoder of the `glm_moe_dsa` family (GLM-5.x; DeepSeek-V3.2's
+    attention): per layer ``x += MLA(norm1 x)``, ``x += FFN(norm2 x)`` with
+    RMSNorm, no biases, rotary positions on ``qk_rope_head_dim`` of each
+    query head and on the one shared rotary key; after the last layer
+    ``norm_f``.  Equations and names: `ops/latent.py`, `ops/moe.py`.
+
+    ``indexer_types[i]`` is ``"full"`` (the layer holds an indexer and
+    selects each query's ``index_topk`` rows) or ``"shared"`` (it attends
+    the selection of the nearest full layer before it); ``mlp_types[i]`` is
+    ``"dense"`` (gated SiLU FFN of width ``d_ff``) or ``"sparse"``
+    (``n_routed`` sigmoid-routed experts of width ``moe_d_ff``, ``top_k`` a
+    token, plus one shared expert).  ``n_held`` > 0 makes this the share
+    of ONE chip of an expert-parallel deployment: the router keeps all
+    ``n_routed`` outputs and the layer holds, and computes, experts
+    ``[held_first, held_first + n_held)`` only.
+
+    `apply` is the whole-sequence form in plain differentiable
+    `jax.numpy` (the selection is a constant of the backward pass);
+    the serving engine runs the same blocks against its paged latent
+    pool (`ops/generation.block`)."""
+
+    d_model: int = 0
+    n_heads: int = 1
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-5
+    index_n_heads: int = 1
+    index_head_dim: int = 0
+    index_topk: int = 0
+    indexer_types: tuple[str, ...] = ()
+    mlp_types: tuple[str, ...] = ()
+    d_ff: int = 0
+    moe_d_ff: int = 0
+    n_routed: int = 0
+    top_k: int = 1
+    routed_scale: float = 1.0
+    held_first: int = 0
+    n_held: int = 0                      # 0: every routed expert
+
+    EXPECTS = "rnn"
+    REGULARIZED = ()
+
+    def __post_init__(self):
+        super().__post_init__()
+        for f in ("indexer_types", "mlp_types"):
+            object.__setattr__(self, f, tuple(getattr(self, f)))
+        if len(self.indexer_types) != len(self.mlp_types):
+            raise ValueError("indexer_types and mlp_types name the same "
+                             "layers: their lengths differ")
+        if self.indexer_types and self.indexer_types[0] != "full":
+            raise ValueError("the first layer must hold an indexer "
+                             "(\"full\"): a shared layer has no selection "
+                             "before it to reuse")
+        bad = (set(self.indexer_types) - {"full", "shared"}
+               | set(self.mlp_types) - {"dense", "sparse"})
+        if bad:
+            raise ValueError(f"unknown layer kinds {sorted(bad)}")
+        if self.qk_rope_head_dim % 2 or (
+                self.index_head_dim < self.qk_rope_head_dim):
+            raise ValueError("qk_rope_head_dim must be even and at most "
+                             "index_head_dim")
+
+    def _held(self) -> int:
+        return self.n_held or self.n_routed
+
+    def blocks(self) -> tuple:
+        shared = {f.name: getattr(self, f.name)
+                  for f in dataclasses.fields(LatentBlock)
+                  if f.name in {g.name for g in dataclasses.fields(self)}
+                  and f.name != "name"}
+        return tuple(
+            LatentBlock(name=f"{self.name}.layer{i:02d}",
+                        path=(self.name, f"layer{i:02d}"),
+                        indexer=ix, ffn=mlp, **shared)
+            for i, (ix, mlp) in enumerate(zip(self.indexer_types,
+                                              self.mlp_types)))
+
+    def output_type(self, itype: InputType) -> InputType:
+        if itype.size != self.d_model:
+            raise ValueError(
+                f"LatentSparseDecoder d_model={self.d_model} but input "
+                f"feature size is {itype.size}")
+        return InputType.recurrent(self.d_model, itype.shape[0])
+
+    def init(self, key, itype):
+        wi = self._winit(WeightInit.LECUN_NORMAL)
+        d, h_ = self.d_model, self.n_heads
+        dq, lk = self.q_lora_rank, self.kv_lora_rank
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        hi, di = self.index_n_heads, self.index_head_dim
+        ones = lambda n: jnp.ones((n,), jnp.float32)
+
+        def mat(k, *shape):
+            return wi.init(k, shape, fan_in=shape[-2], fan_out=shape[-1])
+
+        def ffn(k, width, lead=()):
+            kg, ku, kd = jax.random.split(k, 3)
+            return {"Wg": mat(kg, *lead, d, width),
+                    "Wu": mat(ku, *lead, d, width),
+                    "Wd": mat(kd, *lead, width, d)}
+
+        params = {"norm_f": ones(d)}
+        for cfg, k in zip(self.blocks(),
+                          jax.random.split(key, len(self.mlp_types))):
+            ka, ki, kf = jax.random.split(k, 3)
+            k1, k2, k3, k4, k5 = jax.random.split(ka, 5)
+            lp = {"norm1": ones(d), "norm2": ones(d), "attn": {
+                "Wqa": mat(k1, d, dq), "q_norm": ones(dq),
+                "Wqb": mat(k2, dq, h_ * (dn + dr)),
+                "Wkva": mat(k3, d, lk + dr), "kv_norm": ones(lk),
+                "Wkvb": mat(k4, lk, h_ * (dn + dv)),
+                "Wo": mat(k5, h_ * dv, d)}}
+            if cfg.indexer == "full":
+                k1, k2, k3 = jax.random.split(ki, 3)
+                lp["indexer"] = {
+                    "Wq": mat(k1, dq, hi * di), "Wk": mat(k2, d, di),
+                    "k_gamma": ones(di),
+                    "k_beta": jnp.zeros((di,), jnp.float32),
+                    "Ww": mat(k3, d, hi)}
+            if cfg.ffn == "dense":
+                lp["ffn"] = ffn(kf, self.d_ff)
+            else:
+                kr, ke, ks = jax.random.split(kf, 3)
+                lp["ffn"] = {
+                    "router": mat(kr, d, self.n_routed),
+                    "router_bias": jnp.zeros((self.n_routed,), jnp.float32),
+                    "experts": ffn(ke, self.moe_d_ff, (self._held(),)),
+                    "shared": ffn(ks, self.moe_d_ff)}
+            params[cfg.path[-1]] = lp
+        return params, {}
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        from deeplearning4j_tpu.ops.latent import (
+            LatentRows, latent_block, rms_norm, sequence_attend,
+        )
+
+        def one(x):
+            rows = LatentRows(sequence_attend(), jnp.arange(x.shape[0]))
+            for cfg in self.blocks():
+                x = latent_block(cfg, params[cfg.path[-1]], x, rows)
+            return rms_norm(x, params["norm_f"], self.rms_eps)
+
+        # one sequence at a time: the grouped product has no batch rule
+        return jax.lax.map(one, x), state

@@ -760,6 +760,26 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "type once, not in every step): 1 at an engine's "
                 "start, + 1 per installed hot-swap, flat across decode "
                 "steps.  Bridged by the decode counts' pull collector")
+    reg.counter("dl4jtpu_dsa_rows_scored_total",
+                "Context rows the sparse-attention indexers scored, per "
+                "query row per indexer layer (its whole prefix, itself "
+                "included), prefill and decode; counted on the host "
+                "from the lengths, bridged by the decode counts' pull "
+                "collector")
+    reg.counter("dl4jtpu_dsa_rows_selected_total",
+                "Rows the indexers' exact top-k kept of those scored: "
+                "min(prefix, index_topk) per query row per indexer "
+                "layer.  Over dl4jtpu_dsa_rows_scored_total it is the "
+                "share of the context the attention then reads")
+    reg.counter("dl4jtpu_moe_assignments_total",
+                "Expert assignments (rows x experts per token) of the "
+                "rows served, by whether the chosen expert is held on "
+                "this chip (held=true|false); counted on the device "
+                "into an array the programs carry, read at scrape")
+    reg.counter("dl4jtpu_moe_expert_assignments_total",
+                "The held assignments by layer and expert (expert = "
+                "its index among all routed experts): the load each "
+                "held expert saw")
     reg.gauge("dl4jtpu_kv_pages_used",
               "KV pool pages currently owned by live streams "
               "(page 0, the scratch page, never counts)")

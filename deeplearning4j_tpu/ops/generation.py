@@ -42,6 +42,8 @@ import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.nn.conf.attention import (
+    LatentBlock,
+    LatentSparseDecoder,
     PositionalEncoding,
     TransformerEncoderBlock,
 )
@@ -52,12 +54,31 @@ from deeplearning4j_tpu.nn.conf.layers import (
 )
 from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
 from deeplearning4j_tpu.ops.attention import mha
+from deeplearning4j_tpu.ops.latent import latent_block, rms_norm
 from deeplearning4j_tpu.quant.qtensor import QuantizedTensor
 
-#: what `_plan` returns: the stack's layers (each names its own entry of
-#: the params tree, `layer.name`) and the widths every caller needs
-_Stack = collections.namedtuple(
-    "_Stack", "embed pos blocks head d n_heads head_dim")
+#: what `_plan` returns: the stack's layers (each block knows where its
+#: entry of the params tree is, `block_params`, and what one token caches
+#: in it, `cache_rows`), the decoder whose final norm precedes the head
+#: (None: the stack has none), and the model width
+_Stack = collections.namedtuple("_Stack", "embed pos blocks head d final")
+
+
+def cache_rows(cfg) -> dict:
+    """What one token caches in a block: pool name -> the row's shape.  A
+    `TransformerEncoderBlock` caches a key and a value of ``(heads,
+    head_dim)`` each; a `LatentBlock` states its own widths."""
+    if isinstance(cfg, LatentBlock):
+        return {name: (width,) for name, width in cfg.cache_rows.items()}
+    row = (cfg.n_heads, cfg.d_model // cfg.n_heads)
+    return {"k": row, "v": row}
+
+
+def block_params(params, cfg):
+    """The block's entry of the model's params tree."""
+    if isinstance(cfg, LatentBlock):
+        return params[cfg.path[0]][cfg.path[1]]
+    return params[cfg.name]
 
 
 def _plan(model):
@@ -71,14 +92,26 @@ def _plan(model):
     if i < len(layers) and isinstance(layers[i], PositionalEncoding):
         pos = layers[i]
         i += 1
-    blocks = []
-    while i < len(layers) and isinstance(layers[i], TransformerEncoderBlock):
-        blocks.append(layers[i])
+    blocks, final = [], None
+    if i < len(layers) and isinstance(layers[i], LatentSparseDecoder):
+        # rotary positions inside the blocks; a position table would add
+        # a second, absolute, encoding the family does not have
+        if pos is not None:
+            raise ValueError("a LatentSparseDecoder takes no "
+                             "PositionalEncoding before it")
+        final = layers[i]
+        blocks = list(final.blocks())
         i += 1
+    else:
+        while (i < len(layers)
+               and isinstance(layers[i], TransformerEncoderBlock)):
+            blocks.append(layers[i])
+            i += 1
     if i != len(layers) - 1:
         raise ValueError(
             "generate() supports [Embedding, PositionalEncoding?, "
-            "TransformerEncoderBlock*, head] stacks; layer "
+            "TransformerEncoderBlock*, head] and [Embedding, "
+            "LatentSparseDecoder, head] stacks; layer "
             f"{type(layers[i]).__name__} at position {i} is not supported"
         )
     head = layers[-1]
@@ -93,11 +126,7 @@ def _plan(model):
                 "generate() requires causal blocks (bidirectional attention "
                 "cannot decode autoregressively)"
             )
-    # the attention widths are the blocks'; a stack without one has none
-    n_heads = blocks[0].n_heads if blocks else 0
-    head_dim = blocks[0].d_model // n_heads if blocks else 0
-    return _Stack(embed, pos, tuple(blocks), head, embed.n_out, n_heads,
-                  head_dim)
+    return _Stack(embed, pos, tuple(blocks), head, embed.n_out, final)
 
 
 def _act_dtype(model):
@@ -123,9 +152,13 @@ def serving_params(stack, params, dt):
     width it multiplies.  Every other leaf is the object ``params``
     holds: the position table (read in f32), integer leaves, any
     `QuantizedTensor`, and every leaf that is ``dt`` already — all of
-    them where ``dt`` is f32.  One jitted cast, made once per tree."""
+    them where ``dt`` is f32.  A `LatentSparseDecoder`'s leaves pass as they
+    are: its trees are made in the type they are served in (an f32 twin of
+    a tree that fills half the chip cannot exist beside it).  One jitted
+    cast, made once per tree."""
     cast = {stack.embed.name: ("W",), stack.head.name: ("W", "b"),
-            **{b.name: _BLOCK_CAST for b in stack.blocks}}
+            **{b.name: _BLOCK_CAST for b in stack.blocks
+               if isinstance(b, TransformerEncoderBlock)}}
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         params, is_leaf=lambda a: isinstance(a, QuantizedTensor))
     leaves = [a for _, a in flat]
@@ -179,6 +212,8 @@ def embed_tokens(stack, params, toks, positions, dt):
         if pos is not None:
             x, _ = pos.apply(lp, {}, x)
         return x
+    if pos is None:
+        return x
     row = lambda t: _pe_row(pos, lp, t, stack.d)
     pe = row(positions) if jnp.ndim(positions) == 0 else jax.vmap(row)(
         positions)
@@ -186,10 +221,17 @@ def embed_tokens(stack, params, toks, positions, dt):
 
 
 def block(cfg, lp, x, attend):
-    """One transformer block on x: (..., D).  ``attend(q, k, v)`` takes the
-    (..., H, Dh) projections of these rows and returns their attention
-    output, same shape; which K/V it runs against — and where it keeps
-    the rows it was handed — is the caller's closure."""
+    """One decoder block on x, by the block's config; ``attend`` is the
+    caller's side of it.  A `TransformerEncoderBlock`: x is (..., D) and
+    ``attend(q, k, v)`` takes the (..., H, Dh) projections of these rows
+    and returns their attention output, same shape; which K/V it runs
+    against — and where it keeps the rows it was handed — is the caller's
+    closure.  A `LatentBlock`: x is (n, D) rows and ``attend`` an
+    `ops/latent.LatentRows` — the rows' positions, the closure
+    ``attend(cfg, q, latent, index, wkvb)`` and what the expert layers
+    count.  Either way the block's output rows come back."""
+    if isinstance(cfg, LatentBlock):
+        return latent_block(cfg, lp, x, attend)
     dt = x.dtype
     rows, h_ = x.shape[:-1], cfg.n_heads
     heads = rows + (h_, cfg.d_model // h_)
@@ -209,6 +251,9 @@ def prompt_forward(stack, params, toks, dt):
     """Dense causal forward over whole prompts, toks: (B, T).  Returns the
     last block's output (B, T, D) and every block's (k, v), each
     (B, T, H, Dh) — the cache seed."""
+    if stack.final is not None:
+        raise ValueError("prompt_forward seeds a dense K/V cache; a "
+                         "LatentSparseDecoder stack has none")
     x = embed_tokens(stack, params, toks, None, dt)
     kvs = []
 
@@ -244,8 +289,11 @@ def cache_row_attention(k_cache, v_cache, pos, grown):
 
 
 def _head_logits(stack, params, h):
-    """h: (..., D) -> (..., vocab) logits."""
+    """h: (..., D), the last block's output -> (..., vocab) logits."""
     head, lp = stack.head, params[stack.head.name]
+    if stack.final is not None:
+        h = rms_norm(h, params[stack.final.name]["norm_f"],
+                     stack.final.rms_eps)
     if isinstance(head, ChunkedSoftmaxOutputLayer):
         return head.logits(lp, h)
     y = h @ lp["W"].astype(h.dtype)
@@ -278,6 +326,11 @@ def generate(model, prompt_ids, max_new_tokens: int, *,
     if model.params is None:
         model.init()
     stack = _plan(model)
+    if stack.final is not None:
+        raise ValueError(
+            "generate() decodes against a dense K/V cache; a "
+            "LatentSparseDecoder stack is served by GenerationEngine "
+            "(serving/generation.py) over its paged latent pool")
     pos = stack.pos
     prompt = jnp.asarray(prompt_ids).astype(jnp.int32)
     if prompt.ndim == 1:

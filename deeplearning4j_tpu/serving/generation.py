@@ -73,6 +73,7 @@ span's ``dur``.  Every step also counts what it served —
 
 from __future__ import annotations
 
+import collections
 import functools
 import logging
 import threading
@@ -92,9 +93,21 @@ from deeplearning4j_tpu.ops.generation import (
     _head_logits,
     _plan,
     block,
+    block_params,
+    cache_rows,
     embed_tokens,
     prompt_forward,
     serving_params,
+)
+from deeplearning4j_tpu.ops.latent import (
+    MOE_ROW_TILE,
+    LatentRows,
+    attend_expanded,
+    attend_gathered,
+    blocked,
+    expand_latents,
+    index_scores,
+    topk_mask,
 )
 from deeplearning4j_tpu.ops.paged_attention import paged_attention_chunk
 from deeplearning4j_tpu.runtime import faults
@@ -151,6 +164,30 @@ def _gen_breakdown_families() -> dict:
 DECODE_COUNT_FAMILIES = ("dl4jtpu_decode_steps_total",
                          "dl4jtpu_decode_slot_steps_total",
                          "dl4jtpu_decode_rows_attended_total")
+
+#: what the learned sparse selection did, process totals, counted on the
+#: host from the lengths (prefill and decode): context rows the indexers
+#: scored and rows they selected, per query row per indexer layer
+DSA_COUNT_FAMILIES = ("dl4jtpu_dsa_rows_scored_total",
+                      "dl4jtpu_dsa_rows_selected_total")
+
+#: expert assignments of the rows served, counted ON THE DEVICE into a
+#: small int32 array the programs carry (`_moe_counts`) and read only on
+#: demand: all of them by whether the expert is held here (``held``), and
+#: the held ones by layer and expert
+MOE_ASSIGNMENTS_FAMILY = "dl4jtpu_moe_assignments_total"
+MOE_EXPERT_FAMILY = "dl4jtpu_moe_expert_assignments_total"
+
+#: a row pool stores its rows at a multiple of this many values (the TPU's
+#: lanes): the device lays a [rows, 576] array out COLUMN-major to spare the
+#: lane padding, and every program that gathers or scatters rows of it then
+#: transposes the whole pool first (offline v5e compile: + 0.66 GB of
+#: temporaries and two pool copies a step at 576, none at 640)
+ROW_LANES = 128
+
+#: query rows per block of a latent prefill chunk's attention: bounds the
+#: f32 scores a program holds to (heads, this, context) at a time
+PREFILL_QUERY_BLOCK = 256
 
 #: serving copies of the parameter tree made (`_serving_params`): one per
 #: installed tree, flushed with the decode counts
@@ -337,6 +374,25 @@ def _sample_token(logits, temp, top_k, key):
     return jnp.where(temp <= 0.0, greedy, samp)
 
 
+def _stored_row(row: tuple) -> tuple:
+    """The shape a pool stores a cached row at: a flat row is padded to
+    whole `ROW_LANES`; keys and values of (heads, head_dim) stay."""
+    return row if len(row) > 1 else (-(-row[0] // ROW_LANES) * ROW_LANES,)
+
+
+def _to_row_width(rows, pool):
+    """rows (n, w) in the pool's type, zero-padded to its stored width."""
+    pad = pool.shape[-1] - rows.shape[-1]
+    return jnp.pad(rows.astype(pool.dtype), ((0, 0), (0, pad)))
+
+
+#: where a decode step's S * c rows sit: the slots' page tables (S, mp),
+#: each row's page and row in it (the write guard applied), the lengths
+#: the rows attend (S, c), their positions, and which rows are live
+_StepRows = collections.namedtuple(
+    "_StepRows", "page_tbl page_of row_of attend_lens positions active")
+
+
 def _slot_keys(seeds, gen_counts):
     """Per-slot sampling keys on the dense reference's schedule: the
     g-th generated token of a stream seeded ``s`` uses
@@ -378,11 +434,34 @@ class GenerationEngine:
         self.breaker = server.breaker if server is not None else None
 
         self._stack = stack = _plan(self.model)
+        # what the stack's blocks cache decides the pool: per named row,
+        # the layers that hold one (and which of them each block's is)
+        held: dict = {}
+        self._pool_index = {}
+        for b in stack.blocks:
+            self._pool_index[b.name] = at = {}
+            for name, row in cache_rows(b).items():
+                at[name] = held.setdefault(name, [0, row])[0]
+                held[name][0] += 1
+        # a stack with no block keeps the empty K/V pool it always had
         self.kv = PagedKVCache(
-            n_layers=len(stack.blocks), n_heads=stack.n_heads,
-            head_dim=stack.head_dim, num_pages=cfg.num_pages,
-            page_size=cfg.page_size, kv_dtype=cfg.kv_dtype,
-        )
+            num_pages=cfg.num_pages, page_size=cfg.page_size,
+            kv_dtype=cfg.kv_dtype,
+            rows={name: (n, _stored_row(row))
+                  for name, (n, row) in held.items()} or None)
+        # expert layers: their place in the device-side assignment counts
+        self._moe_index = {
+            b.name: i for i, b in enumerate(
+                b for b in stack.blocks if getattr(b, "ffn", "") == "sparse")}
+        self._moe_counts = self._fresh_moe_counts()
+        self._moe_flushed = None
+        self._dsa_layers = sum(getattr(b, "indexer", "") == "full"
+                               for b in stack.blocks)
+        self._dsa_topk = max((b.index_topk for b in stack.blocks
+                              if getattr(b, "indexer", "") == "full"),
+                             default=0)
+        self._dsa_scored = 0
+        self._dsa_selected = 0
         self._quantum = cfg.prefill_quantum or self.kv.page_size
         if self._quantum % self.kv.page_size:
             raise ValueError(
@@ -424,7 +503,7 @@ class GenerationEngine:
         # (`_serving_params`); and how many were made
         self._served = (None, None, None)
         self._params_casts = 0
-        self._counts_flushed = (0, 0, 0, 0)
+        self._counts_flushed = (0, 0, 0, 0, 0, 0)
         self._tokens_out = 0
         # the compiled decode programs by chunk width c: 1 is the plain
         # step, spec_k + 1 the speculative verify (built at first use)
@@ -634,6 +713,10 @@ class GenerationEngine:
         replica.  The stream's trace context (adopted from `trace_ctx`
         or allocated here) and timing marks ride the handoff, so the
         decode replica extends the SAME causal chain."""
+        if not self.kv.kv_layout:
+            raise ValueError(
+                "this stack's prefill writes its row pools in place; "
+                "handing its rows to another replica is not implemented")
         req = GenerationRequest(
             prompt, int(max_new_tokens), temperature=temperature,
             top_k=top_k, seed=seed, stop_tokens=stop_tokens,
@@ -707,7 +790,8 @@ class GenerationEngine:
                                       _act_dtype(self.model))
                 self._served = (live, copy, {
                     **copy, **{b.name: live[b.name]
-                               for b in self._stack.blocks}})
+                               for b in self._stack.blocks
+                               if b.name in live}})
                 self._params_casts += 1
             long = t_b > WEIGHT_BOUND_PREFILL_TOKENS
             return self._served[2 if long else 1]
@@ -734,30 +818,287 @@ class GenerationEngine:
 
         return prefill
 
-    def _prefill_fn(self, t_b: int):
+    def _prefill_fn(self, key: int):
         # `jax.jit` construction is lazy (compilation happens at the
         # first CALL, outside this lock), so memoizing under `_mu` is
-        # cheap even with the decode loop live
+        # cheap even with the decode loop live.  A K/V pool's programs
+        # are keyed by the prompt's bucket, row pools' by the chunk's index
+        make = (self._make_prefill if self.kv.kv_layout
+                else self._make_prefill_chunk)
         with self._mu:
-            fn = self._prefill_fns.get(t_b)
+            fn = self._prefill_fns.get(key)
             if fn is None:
-                fn = self._prefill_fns[t_b] = self._make_prefill(t_b)
+                fn = self._prefill_fns[key] = make(key)
         return fn
 
     def _run_prefill(self, req: GenerationRequest):
-        """Dispatch the bucketed prefill program for one request;
-        returns (k, v, first_token, ttft_anchor) with k/v shaped
-        (n_layers, t_bucket, H, Dh) f32."""
+        """Dispatch one request's prefill; returns (k, v, first_token,
+        ttft_anchor).  A K/V pool: the bucketed whole-prompt program,
+        k/v shaped (n_layers, t_bucket, H, Dh) f32 for `write_prefill`.
+        Row pools: the prompt's chunk programs in a row, each writing the
+        stream's pages in place (the pool is donated through every one)
+        and only the last chunk's token read back; k and v are None."""
         t_p = req.prompt.shape[0]
         t_b = bucket_length(t_p, self._quantum)
-        pad = np.zeros((1, t_b), np.int32)
-        pad[0, :t_p] = req.prompt
-        k, v, first = self._prefill_fn(t_b)(
-            self._serving_params(t_b), pad, np.int32(t_p),
-            np.uint32(req.seed), np.float32(req.temperature),
-            np.int32(req.top_k),
-        )
-        return k, v, int(first), req.t_submit
+        params = self._serving_params(t_b)
+        sampling = (np.int32(t_p), np.uint32(req.seed),
+                    np.float32(req.temperature), np.int32(req.top_k))
+        if self.kv.kv_layout:
+            pad = np.zeros((1, t_b), np.int32)
+            pad[0, :t_p] = req.prompt
+            k, v, first = self._prefill_fn(t_b)(params, pad, *sampling)
+            return k, v, int(first), req.t_submit
+        c_rows = self._quantum
+        pad = np.zeros(t_b, np.int32)
+        pad[:t_p] = req.prompt
+        row = np.full(self.config.max_pages_per_seq, SCRATCH_PAGE, np.int32)
+        tbl = self.kv.table(req.rid)
+        row[: len(tbl)] = tbl
+        for ci in range(t_b // c_rows):
+            out = self._prefill_fn(ci)(
+                params, *self._program_state(), row,
+                pad[ci * c_rows:(ci + 1) * c_rows], *sampling)
+            self._rebind_state(out[:-1])
+        self._count_selection(np.arange(1, t_p + 1))
+        return None, None, int(out[-1]), req.t_submit
+
+    def _count_selection(self, contexts) -> None:
+        """Query rows at the contexts ``contexts`` (each row's own
+        included) went through the stack: per indexer layer a row's whole
+        context was scored and the top ``index_topk`` of it (all of it
+        while shorter) selected."""
+        self._dsa_scored += self._dsa_layers * int(contexts.sum())
+        self._dsa_selected += self._dsa_layers * int(
+            np.minimum(contexts, self._dsa_topk).sum())
+
+    # -- what every program carries ---------------------------------------
+    def _program_state(self) -> tuple:
+        """The device arrays every program that writes them takes DONATED
+        and returns first: the pool, and the expert assignment counts
+        where the stack has expert layers."""
+        extra = () if self._moe_counts is None else (self._moe_counts,)
+        return self.kv.pool() + extra
+
+    def _rebind_state(self, out) -> None:
+        n = len(self.kv.pool())
+        self.kv.rebind(*out[:n])
+        if self._moe_counts is not None:
+            self._moe_counts = out[n]
+
+    def _fresh_moe_counts(self):
+        if not self._moe_index:
+            return None
+        held = self._stack.final._held()
+        return jnp.zeros((len(self._moe_index), held + 1), jnp.int32)
+
+    def _revive_state(self, wait: bool = False) -> bool:
+        """After a failed dispatch: the pool anew if it was consumed, and
+        the assignment counts with it (they restart at zero).  True when
+        the pool was made anew: no cached row of any stream survives."""
+        dead = self.kv.revive(wait=wait)
+        c = self._moe_counts
+        if c is not None and c.is_deleted():
+            self._moe_counts = self._fresh_moe_counts()
+            self._moe_flushed = None
+        return dead
+
+    def _run_blocks(self, params, x, attend_of):
+        """The stack's blocks over rows x, block ``li`` with the
+        caller's side ``attend_of(li)`` (`ops/generation.block`)."""
+        for li, cfg in enumerate(self._stack.blocks):
+            x = block(cfg, block_params(params, cfg), x, attend_of(li))
+        return x
+
+    def _counts_sink(self, state):
+        """The expert assignment counts a program carries — the last of
+        its donated arguments ``state`` where the stack has expert layers
+        — as a one-element list (empty without) and the ``counts_to`` that
+        adds a layer's counts into it."""
+        counts = [state[-1]] if self._moe_counts is not None else []
+
+        def counts_to(cfg, new):
+            counts[0] = counts[0].at[self._moe_index[cfg.name]].add(new)
+
+        return counts, counts_to
+
+    def _make_prefill_chunk(self, ci: int):
+        """Prefill chunk ``ci`` of a stack over row pools: the prompt's
+        rows ``[ci * C, (ci + 1) * C)`` (C = the prefill quantum) against
+        the stream's context so far, which the earlier chunks left in the
+        pool.  One program per chunk INDEX — its context length is static
+        — so a prompt of n chunks runs programs 0..n-1 and the mix's
+        longest prompt warms them all.  Per layer: the chunk's rows are
+        written into the stream's pages, the context ``[0, (ci + 1) * C)``
+        is read back through the page table, a full layer scores it with
+        the indexer and keeps each query's exact top ``index_topk`` as a
+        mask (carried to the shared layers after it), and the chunk
+        attends under that mask in blocks of `PREFILL_QUERY_BLOCK`
+        queries: no (heads, T, T) array exists.  Rows past the prompt's
+        end are pad: causal attention keeps them from every real row, and
+        the decode step overwrites their cached rows as the stream grows.
+        Every chunk returns the token sampled after row ``prompt_len - 1``;
+        the caller reads the last chunk's."""
+        stack, ps = self._stack, self.kv.page_size
+        c_rows = self._quantum
+        start, ctx = ci * c_rows, (ci + 1) * c_rows
+        new_pg = slice(start // ps, ctx // ps)
+        n_state = len(self._program_state())
+        bq = PREFILL_QUERY_BLOCK
+
+        def prefill_chunk(params, *rest):
+            state = rest[:n_state]
+            page_row, toks, prompt_len, seed, temp, top_k = rest[n_state:]
+            # the programs address a layer's pages in the pool flattened
+            # over (layer, page), so none slices a layer out of it
+            pools = dict(zip(self.kv.rows, state))
+            positions = start + jnp.arange(c_rows)
+            x = embed_tokens(stack, params, toks, positions,
+                             _act_dtype(self.model))
+            carried = []
+
+            def through_pages(name, at, rows):
+                """Write the chunk's rows of one layer into the stream's
+                pages, and read the context back."""
+                pool = pools[name]
+                tail = pool.shape[2:]
+                pages = at * self.kv.num_pages + page_row
+                flat = pool.reshape((-1,) + tail)
+                flat = flat.at[pages[new_pg]].set(
+                    _to_row_width(rows, pool).reshape((-1,) + tail))
+                pools[name] = flat.reshape(pool.shape)
+                return flat[pages[: ctx // ps]].reshape((ctx,) + tail[1:])
+
+            def attend(cfg, q, latent, index, wkvb):
+                at = self._pool_index[cfg.name]
+                context = through_pages("latent", at["latent"], latent)
+                if index is not None:
+                    q_i, k_i, w = index
+                    keys = through_pages("index_key", at["index_key"],
+                                         k_i)[:, :k_i.shape[-1]]
+                    seen = jnp.arange(ctx)
+                    with jax.named_scope("dsa_index"):
+                        carried[:] = [blocked(
+                            lambda qb, wb, pb: topk_mask(
+                                index_scores(qb, wb, keys),
+                                seen[None, :] <= pb[:, None],
+                                cfg.index_topk),
+                            bq, q_i, w, positions)]
+                expanded = expand_latents(cfg, context, wkvb)
+                return blocked(
+                    lambda qb, mb: attend_expanded(cfg, qb, *expanded, mb),
+                    bq, q, carried[0])
+
+            counts, counts_to = self._counts_sink(state)
+            rows = LatentRows(attend, positions, positions < prompt_len,
+                              counts_to, MOE_ROW_TILE)
+            x = self._run_blocks(params, x, lambda li: rows)
+            last = jnp.clip(prompt_len - 1 - start, 0, c_rows - 1)
+            first = _sample_token(
+                _head_logits(stack, params, x[last]), temp, top_k,
+                jax.random.fold_in(jax.random.key(seed), 0))
+            return (*pools.values(), *counts, first)
+
+        return jax.jit(prefill_chunk,
+                       donate_argnums=tuple(range(1, 1 + n_state)))
+
+    # -- the decode step's `attend`, by what the pool holds ------------------
+    def _kv_step_attend(self, c: int, pool: list, at: "_StepRows",
+                        counts_to):
+        """Keys and values: layer ``li``'s rows into the pool (the int8
+        twin and its scales included), then the chunk against it through
+        the paged kernel.  The pool is read and written in place
+        (`layer=li`, `.at[li, ...]`): the step never holds a second pool
+        or a per-layer piece of one."""
+        quant = self.kv.kv_dtype == "int8"
+        impl = self.config.attention_impl
+        interp = self.config.attention_interpret
+        n_slots = self.config.slots
+        page_of, row_of = at.page_of, at.row_of
+
+        def attend(li, q, k_t, v_t):
+            kp, vp, ksc, vsc = pool
+            if quant:
+                kq, k_sc = quantize_page_rows(k_t)
+                vq, v_sc = quantize_page_rows(v_t)
+                kp = kp.at[li, page_of, row_of].set(kq)
+                vp = vp.at[li, page_of, row_of].set(vq)
+                ksc = ksc.at[li, page_of, row_of].set(k_sc)
+                vsc = vsc.at[li, page_of, row_of].set(v_sc)
+            else:
+                # the scales are None for an f32 pool
+                kp = kp.at[li, page_of, row_of].set(k_t.astype(kp.dtype))
+                vp = vp.at[li, page_of, row_of].set(v_t.astype(vp.dtype))
+            pool[:] = kp, vp, ksc, vsc
+            return paged_attention_chunk(
+                q.astype(jnp.float32).reshape((n_slots, c) + q.shape[1:]),
+                kp, vp, at.page_tbl, at.attend_lens, k_scale=ksc,
+                v_scale=vsc,
+                layer=li, impl=impl, interpret=interp,
+            )
+
+        return lambda li: functools.partial(attend, li)
+
+    def _row_step_attend(self, c: int, pool: list, at: "_StepRows",
+                         counts_to):
+        """Latent rows and indexer keys: per layer the c rows of every
+        slot are written into the latent pool, a full layer scores the
+        slot's WHOLE context with the indexer (its cached keys, through
+        the page table), takes each row's exact top ``index_topk`` and
+        hands the selection to the shared layers after it, and every
+        layer gathers just the selected latent rows through the page
+        table and attends them with ``W_kvb`` folded into the query and
+        the output.  A layer's rows are addressed in the pool flattened
+        over (layer, page, row), so none slices a layer out of it."""
+        ps = self.kv.page_size
+        n_slots, mp = self.config.slots, self.config.max_pages_per_seq
+        cap, n = mp * ps, n_slots * c
+        per_layer = self.kv.num_pages * ps               # rows of one layer
+        at_pool = {name: i for i, name in enumerate(self.kv.rows)}
+        page_tbl = at.page_tbl
+        tbl = jnp.repeat(page_tbl, c, axis=0)                     # (n, mp)
+        row_at = at.page_of * ps + at.row_of
+        # an idle slot attends its one scratch row (finite garbage nobody
+        # reads): a softmax over no row at all is not a number
+        seen = (jnp.arange(cap)[None, :]
+                < jnp.maximum(at.attend_lens.reshape(n), 1)[:, None])
+        carried = []
+
+        def write(name, layer, new):
+            stored = pool[at_pool[name]]
+            flat = stored.reshape((-1,) + stored.shape[3:])
+            flat = flat.at[layer * per_layer + row_at].set(
+                _to_row_width(new, stored))
+            pool[at_pool[name]] = flat.reshape(stored.shape)
+            return flat, layer * per_layer
+
+        def attend(cfg, q, latent, index, wkvb):
+            held = self._pool_index[cfg.name]
+            cached, base = write("latent", held["latent"], latent)
+            if index is not None:
+                q_i, k_i, w = index
+                keys, k_base = write("index_key", held["index_key"], k_i)
+                with jax.named_scope("dsa_index"):
+                    # the slot's context keys, page by page
+                    ctx = keys.reshape((-1, ps) + keys.shape[1:])[
+                        k_base // ps + page_tbl].reshape(
+                            n_slots, cap, -1)[..., :k_i.shape[-1]]
+                    scores = jax.vmap(index_scores)(
+                        q_i.reshape((n_slots, c) + q_i.shape[1:]),
+                        w.reshape(n_slots, c, -1), ctx).reshape(n, cap)
+                with jax.named_scope("dsa_topk"):
+                    best, where = jax.lax.top_k(
+                        jnp.where(seen, scores, -jnp.inf),
+                        min(cfg.index_topk, cap))
+                    chosen = tbl[jnp.arange(n)[:, None],
+                                 where // ps] * ps + where % ps
+                carried[:] = [(chosen, best > -jnp.inf)]
+            chosen, valid = carried[0]
+            return attend_gathered(cfg, q, cached[base + chosen], valid,
+                                   wkvb)
+
+        rows = LatentRows(attend, at.positions, at.active, counts_to,
+                          MOE_ROW_TILE)
+        return lambda li: rows
 
     def _make_step(self, c: int = 1):
         """The decode program: ONE dispatch advances every slot by a
@@ -767,32 +1108,37 @@ class GenerationEngine:
         the last token plus k draft proposals) — shaped like a short
         prefill, compiled once, so speculation adds one program.
 
-        Chunk row ``j`` of slot ``s`` writes K/V at sequence position
-        ``seq_len + j`` and attends positions ``< seq_len + j + 1``
-        (all c rows are written before the chunk attends; masking in
-        `paged_attention_chunk` expresses the in-chunk causality), so
+        Chunk row ``j`` of slot ``s`` writes its cached rows at sequence
+        position ``seq_len + j`` and attends positions ``< seq_len + j +
+        1`` (all c rows are written before the chunk attends; the
+        attended lengths express the in-chunk causality), so
         its logits are bit-equal to what ``j`` sequential plain steps
         over the same tokens would produce.  Row ``j``'s token is
         sampled with the baseline key ``fold_in(key(seed),
         gen_count + j)`` — the exact `_slot_keys` schedule — which is
         what makes the harvested accept-prefix + corrected/bonus token
         BYTE-identical to plain decode at any temperature, not merely
-        distribution-identical."""
+        distribution-identical.
+
+        Slots, positions, the write guard, the head and the sampling are
+        the same for every stack; what a layer caches and which cached
+        rows a row attends is the `attend` that goes with the pool's
+        layout (`_kv_step_attend`, `_row_step_attend`)."""
         stack, ps = self._stack, self.kv.page_size
-        quant = self.kv.kv_dtype == "int8"
-        impl = self.config.attention_impl
-        interp = self.config.attention_interpret
         n_slots, mp = self.config.slots, self.config.max_pages_per_seq
         cap, n = mp * ps, n_slots * c
+        n_pool = len(self.kv.pool())
+        n_state = len(self._program_state())
+        step_attend = (self._kv_step_attend if self.kv.kv_layout
+                       else self._row_step_attend)
         # a per-slot value, once for each of the slot's c chunk rows
         rows = lambda a: jnp.repeat(a, c, axis=0)
 
-        # the pool is DONATED and every layer of it read and written in
-        # place (`layer=li`, `.at[li, ...]`): the step never holds a
-        # second pool or a per-layer piece of one
-        def step(params, k_pages, v_pages, k_scales, v_scales,
-                 page_tbl, seq_lens, toks, seeds, gen_counts,
-                 temps, top_ks):
+        # the pool (and the expert counts) are DONATED and come back first
+        def step(params, *rest):
+            state = rest[:n_state]
+            (page_tbl, seq_lens, toks, seeds, gen_counts, temps,
+             top_ks) = rest[n_state:]
             active = seq_lens > 0
             # flattened (S*c, ...) throughout so every matmul keeps the
             # plain step's 2-D shape (only M grows, S -> S*c)
@@ -818,32 +1164,11 @@ class GenerationEngine:
             # each row attends its prefix, itself included; idle slots 0
             attend_lens = jnp.where(active[:, None],
                                     jnp.minimum(pos2 + 1, cap), 0)
-            pool = [k_pages, v_pages, k_scales, v_scales]
-
-            def attend(li, q, k_t, v_t):
-                # layer li's rows into the pool, then the chunk against it
-                kp, vp, ksc, vsc = pool
-                if quant:
-                    kq, k_sc = quantize_page_rows(k_t)
-                    vq, v_sc = quantize_page_rows(v_t)
-                    kp = kp.at[li, page_of, row_of].set(kq)
-                    vp = vp.at[li, page_of, row_of].set(vq)
-                    ksc = ksc.at[li, page_of, row_of].set(k_sc)
-                    vsc = vsc.at[li, page_of, row_of].set(v_sc)
-                else:
-                    # the scales are None for an f32 pool
-                    kp = kp.at[li, page_of, row_of].set(k_t.astype(kp.dtype))
-                    vp = vp.at[li, page_of, row_of].set(v_t.astype(vp.dtype))
-                pool[:] = kp, vp, ksc, vsc
-                return paged_attention_chunk(
-                    q.astype(jnp.float32).reshape((n_slots, c) + q.shape[1:]),
-                    kp, vp, page_tbl, attend_lens, k_scale=ksc, v_scale=vsc,
-                    layer=li, impl=impl, interpret=interp,
-                )
-
-            for li, cfg_b in enumerate(stack.blocks):
-                x = block(cfg_b, params[cfg_b.name], x,
-                          functools.partial(attend, li))
+            pool = list(state[:n_pool])
+            counts, counts_to = self._counts_sink(state)
+            x = self._run_blocks(params, x, step_attend(
+                c, pool, _StepRows(page_tbl, page_of, row_of, attend_lens,
+                                   pos_idx, rows(active)), counts_to))
             logits = _head_logits(stack, params, x)
             keys = _slot_keys(
                 rows(seeds),
@@ -854,11 +1179,11 @@ class GenerationEngine:
                 keys,
             )
             nxt = jnp.where(rows(active), nxt, 0)
-            return (*pool, nxt.reshape(toks.shape))
+            return (*pool, *counts, nxt.reshape(toks.shape))
 
         # the names the profile is read by: `jit_step`, `jit_verify`
         step.__name__ = step.__qualname__ = "step" if c == 1 else "verify"
-        return jax.jit(step, donate_argnums=(1, 2, 3, 4))
+        return jax.jit(step, donate_argnums=tuple(range(1, 1 + n_state)))
 
     # -- the decode loop ---------------------------------------------------
     def _loop(self, my_gen: int) -> None:
@@ -981,7 +1306,10 @@ class GenerationEngine:
                 first = req.prefilled["first_token"]
                 hand_t0 = req.prefilled.get("t_done_pc")
             with self._span("generation.kv_handoff") as sp:
-                tbl = self.kv.write_prefill(req.rid, k, v)
+                # chunk programs wrote the stream's pages themselves
+                tbl = (np.asarray(self.kv.table(req.rid), np.int32)
+                       if k is None else
+                       self.kv.write_prefill(req.rid, k, v))
             t_w1 = sp.t0 + sp.dur
             # cross-replica handoff spans from the PREFILL replica's
             # completion mark (perf_counter is comparable in-process);
@@ -998,6 +1326,13 @@ class GenerationEngine:
             self.kv.release(req.rid)
             self._finish(req, "error",
                          ServingError(f"prefill failed: {exc}"))
+            # a program that raised may have consumed the donated pool,
+            # and with it every live stream's rows
+            with self._mu:
+                if (self._loop_gen == my_gen
+                        and self._revive_state(wait=True)):
+                    self._fail_active_locked(ServingError(
+                        f"prefill failed and took the pool: {exc}"))
             return
         req._record(first)
         self._observe_ttft(req)
@@ -1047,16 +1382,16 @@ class GenerationEngine:
         try:
             with self._span("generation.decode_dispatch",
                             slots=n_live, rows=rows) as disp:
-                out = fn(params, *self.kv.pool(), *args)
+                out = fn(params, *self._program_state(), *args)
                 # the pool was donated: what `kv` held is dead from here
                 # on, so the result becomes the pool before anything can
                 # fail.  A loop that `_on_wedged` replaced meanwhile has
                 # had its pool revived and drops this one
                 with self._mu:
                     if self._loop_gen == my_gen:
-                        self.kv.rebind(*out[:4])
+                        self._rebind_state(out[:-1])
             with self._span("generation.decode_readback") as rb:
-                toks = np.asarray(out[4])
+                toks = np.asarray(out[-1])
         except Exception as exc:
             self.watchdog.disarm(None)
             self._step_failed(my_gen, exc)
@@ -1132,6 +1467,9 @@ class GenerationEngine:
         self._steps += 1
         self._slot_steps += n_live
         self._rows_attended += rows
+        if self._dsa_layers:
+            self._count_selection(
+                np.minimum(live[:, None] + 1 + np.arange(c), cap))
         # the watchdog arms with the chunk width so the EWMA deadline
         # stays per-token-normalized
         self.watchdog.arm(self._steps, n_steps=c)
@@ -1410,7 +1748,7 @@ class GenerationEngine:
                 return
             # a dispatch that raised may have consumed the donated pool;
             # no stream learns of the failure before the pool is usable
-            self.kv.revive(wait=True)
+            self._revive_state(wait=True)
             self._fail_active_locked(
                 ServingError(f"decode step failed: {exc}"))
         self._gauge_occupancy()
@@ -1448,7 +1786,7 @@ class GenerationEngine:
             gen = self._loop_gen
             # the wedged dispatch holds the donated pool: the respawned
             # loop gets a new one (no waiting on a device that is stuck)
-            self.kv.revive()
+            self._revive_state()
             self._fail_active_locked(
                 ServingError(f"decode step wedged: {event.get('stage')}"),
                 outcome="wedged",
@@ -1622,6 +1960,9 @@ class GenerationEngine:
             "decode_slot_steps": self._slot_steps,
             "decode_rows_attended": self._rows_attended,
             "serving_params_casts": self._params_casts,
+            "dsa": {"rows_scored": self._dsa_scored,
+                    "rows_selected": self._dsa_selected},
+            "moe": self._moe_stats(),
             "tokens_generated": self._tokens_out,
             "tokens_per_s": round(self.tokens_per_s(), 4),
             "streams": {"settled": settled, "outcomes": outcomes},
@@ -1651,6 +1992,30 @@ class GenerationEngine:
             },
         }
         return out
+
+    def _moe_counts_host(self):
+        """The device-side assignment counts, read now: (expert layers,
+        held experts + 1) int64, the last column the assignments that
+        fell on experts held elsewhere; None for a stack without expert
+        layers.  The engine thread donates the array with every dispatch,
+        so a reader that catches it mid-step tries again."""
+        for _ in range(50):
+            a = self._moe_counts
+            if a is None:
+                return None
+            try:
+                return np.asarray(a).astype(np.int64)
+            except RuntimeError:            # donated under the reader
+                time.sleep(0.002)
+        return None
+
+    def _moe_stats(self) -> Optional[dict]:
+        counts = self._moe_counts_host()
+        if counts is None:
+            return None
+        return {"assignments_held": int(counts[:, :-1].sum()),
+                "assignments_elsewhere": int(counts[:, -1].sum()),
+                "expert_assignments": counts[:, :-1].tolist()}
 
     def health_summary(self) -> dict:
         """Compact generation block for `InferenceServer.health()` —
@@ -1712,15 +2077,36 @@ class GenerationEngine:
             from deeplearning4j_tpu.observe.metrics import registry
 
             reg = registry()
+            moe = self._moe_counts_host()
             with self._stats_lock:
                 now = (self._steps, self._slot_steps, self._rows_attended,
-                       self._params_casts)
+                       self._params_casts, self._dsa_scored,
+                       self._dsa_selected)
                 delta = [a - b for a, b in zip(now, self._counts_flushed)]
                 self._counts_flushed = now
+                if moe is not None:
+                    was = self._moe_flushed
+                    moe_delta = moe if was is None else moe - was
+                    self._moe_flushed = moe
             for family, d in zip(
-                    DECODE_COUNT_FAMILIES + (PARAMS_CASTS_FAMILY,), delta):
+                    DECODE_COUNT_FAMILIES + (PARAMS_CASTS_FAMILY,)
+                    + DSA_COUNT_FAMILIES, delta):
                 if d > 0:
                     reg.counter(family).inc(d)
+            if moe is not None:
+                blocks = [b for b in self._stack.blocks
+                          if b.name in self._moe_index]
+                total = reg.counter(MOE_ASSIGNMENTS_FAMILY)
+                per = reg.counter(MOE_EXPERT_FAMILY)
+                for b, row in zip(blocks, moe_delta):
+                    for e, d in enumerate(row[:-1]):
+                        if d > 0:
+                            per.inc(int(d), layer=b.name,
+                                    expert=str(b.held_first + e))
+                    if row[:-1].sum() > 0:
+                        total.inc(int(row[:-1].sum()), held="true")
+                    if row[-1] > 0:
+                        total.inc(int(row[-1]), held="false")
         except Exception as e:
             log.debug("decode count flush failed: %s", e)
 

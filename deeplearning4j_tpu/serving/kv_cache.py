@@ -16,6 +16,17 @@ Layout (per layer, K and V each)::
     pages:  (num_pages, page_size, n_heads, head_dim)   f32 | int8
     scales: (num_pages, page_size, n_heads)             f32 (int8 only)
 
+A stack that caches something else per token states it as ROW POOLS
+(``rows=``): for each named row its width and how many layers hold one —
+a latent-attention decoder keeps a 576-wide ``latent`` row in every layer
+and a 128-wide ``index_key`` in the layers that have an indexer::
+
+    <name>_pages:  (layers holding it, num_pages, page_size, *row)  f32 | bf16
+
+under the same allocator, page tables, donation and `revive`; K/V above is
+the same thing with two rows of ``(n_heads, head_dim)`` per layer, plus
+the int8 scales and the paged kernel that only that layout has.
+
 Position ``p`` of a request lives at row ``p % page_size`` of pool page
 ``table[p // page_size]``.  Page 0 is RESERVED as the engine's scratch
 page (idle decode slots write their garbage rows there), so the
@@ -119,6 +130,9 @@ def _write_pages(k_pages, v_pages, k_scales, v_scales, idx, k, v):
             put(k_scales, ks), put(v_scales, vs))
 
 
+_ROW_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+
+
 class PagedKVCache:
     """Pool arrays + the block allocator for one transformer stack.
 
@@ -142,16 +156,42 @@ class PagedKVCache:
     the pool with nothing to rebind: `revive` then makes it anew.
     """
 
-    def __init__(self, n_layers: int, n_heads: int, head_dim: int,
-                 num_pages: int, page_size: int,
-                 kv_dtype: str = "f32"):
-        if kv_dtype not in ("f32", "int8"):
-            raise ValueError(f"kv_dtype must be f32|int8, got {kv_dtype!r}")
+    def __init__(self, n_layers: int = 0, n_heads: int = 0,
+                 head_dim: int = 0, *, num_pages: int, page_size: int,
+                 kv_dtype: str = "f32", rows: Optional[dict] = None):
+        """One pool per named row of ``rows`` = {name: (layers holding
+        it, row shape)} (``<name>_pages``).  ``n_layers``, ``n_heads``
+        and ``head_dim`` are the short form of a key and a value of
+        ``(n_heads, head_dim)`` in each of ``n_layers`` layers."""
+        if rows is None:
+            rows = {name: (n_layers, (n_heads, head_dim))
+                    for name in ("k", "v")}
+        self.rows = {name: (int(layers), tuple(int(w) for w in row))
+                     for name, (layers, row) in rows.items()}
+        #: keys and values of (heads, head_dim), one of each per layer:
+        #: the layout that has int8 pages with per-row scales, takes a
+        #: prompt's rows by `write_prefill` and is read by the paged kernel
+        self.kv_layout = (list(self.rows) == ["k", "v"]
+                          and self.rows["k"] == self.rows["v"]
+                          and len(self.rows["k"][1]) == 2)
+        if self.kv_layout and kv_dtype not in ("f32", "int8"):
+            raise ValueError(
+                "a K/V pool (rows of heads x head_dim) holds f32 or int8 "
+                f"pages, got kv_dtype {kv_dtype!r}; bf16 pages are for row "
+                "pools (rows=)")
+        if not self.kv_layout and kv_dtype not in _ROW_DTYPES:
+            raise ValueError(
+                "a row pool holds f32 or bf16 pages, got kv_dtype "
+                f"{kv_dtype!r}; int8 pages with per-row scales exist for "
+                "K/V pools only")
         if num_pages < 2:
             raise ValueError("pool needs >= 2 pages (page 0 is scratch)")
-        self.n_layers = int(n_layers)
-        self.n_heads = int(n_heads)
-        self.head_dim = int(head_dim)
+        self.n_layers, (self.n_heads, self.head_dim) = (
+            self.rows["k"] if self.kv_layout else (0, (0, 0)))
+        #: the pool's arrays, as attributes, in the order of `pool()`
+        self._names = (("k_pages", "v_pages", "k_scales", "v_scales")
+                       if self.kv_layout else
+                       tuple(f"{name}_pages" for name in self.rows))
         # recompile hygiene: a page size of 13 would give every distinct
         # prompt-length bucket its own page count AND its own tail shape
         self.page_size = bucket_length(page_size, PAGE_QUANTUM)
@@ -169,6 +209,11 @@ class PagedKVCache:
 
     # -- the device pool ---------------------------------------------------
     def _fresh_pool(self) -> tuple:
+        if not self.kv_layout:
+            return tuple(
+                jnp.zeros((layers, self.num_pages, self.page_size) + row,
+                          _ROW_DTYPES[self.kv_dtype])
+                for layers, row in self.rows.values())
         shape = (self.n_layers, self.num_pages, self.page_size,
                  self.n_heads, self.head_dim)
         if self.kv_dtype != "int8":
@@ -180,15 +225,16 @@ class PagedKVCache:
                 jnp.ones(shape[:-1], jnp.float32))
 
     def pool(self) -> tuple:
-        """``(k_pages, v_pages, k_scales, v_scales)`` — the donated
-        arguments of every program that writes the pool, in the order
-        those programs return them (scales are None for an f32 pool)."""
-        return self.k_pages, self.v_pages, self.k_scales, self.v_scales
+        """``(k_pages, v_pages, k_scales, v_scales)`` — or one array per
+        named row — the donated arguments of every program that writes
+        the pool, in the order those programs return them (scales are
+        None for an f32 pool)."""
+        return tuple(getattr(self, name) for name in self._names)
 
-    def rebind(self, k_pages, v_pages, k_scales, v_scales) -> None:
+    def rebind(self, *arrays) -> None:
         """Take a donating program's result as the pool."""
-        self.k_pages, self.v_pages = k_pages, v_pages
-        self.k_scales, self.v_scales = k_scales, v_scales
+        for name, a in zip(self._names, arrays, strict=True):
+            setattr(self, name, a)
 
     def revive(self, wait: bool = False) -> bool:
         """After a dispatch that failed: if it consumed the donated pool
@@ -208,7 +254,7 @@ class PagedKVCache:
             dead = True
         if dead:
             del arrays
-            self.rebind(None, None, None, None)   # free before allocating
+            self.rebind(*[None] * len(self._names))   # free before allocating
             self.rebind(*self._fresh_pool())
             with self._lock:
                 self._pool_rebuilds += 1
@@ -223,7 +269,12 @@ class PagedKVCache:
     def bytes_per_token(self) -> int:
         """HBM bytes one position costs across layers and K+V (the
         residency number `bench.py --generate` reports): int8 pays 1
-        byte/element plus the f32 per-(position, head) scale."""
+        byte/element plus the f32 per-(position, head) scale; row pools
+        pay every named row in the layers that hold it."""
+        if not self.kv_layout:
+            size = jnp.dtype(_ROW_DTYPES[self.kv_dtype]).itemsize
+            return sum(layers * int(np.prod(row)) * size
+                       for layers, row in self.rows.values())
         elems = self.n_layers * 2 * self.n_heads * self.head_dim
         if self.kv_dtype == "int8":
             return elems + self.n_layers * 2 * self.n_heads * 4
@@ -363,6 +414,9 @@ class PagedKVCache:
                 "alloc_failures": self._alloc_failures,
                 "pool_rebuilds": self._pool_rebuilds,
                 "bytes_per_token": self.bytes_per_token(),
+                # what a token caches: row name -> [layers, *row shape]
+                "rows": {name: [layers, *row]
+                         for name, (layers, row) in self.rows.items()},
             }
 
     def leak_check(self) -> Optional[str]:
@@ -392,7 +446,14 @@ class PagedKVCache:
         guarantees it); the table must already cover T positions.
         Returns the page table as an int32 array (for the decode step's
         page-table row).  One donated program per prefill bucket
-        (`_write_pages`): the pool is written in place and rebound."""
+        (`_write_pages`): the pool is written in place and rebound.
+        Row pools have no hand-off: the programs that compute their rows
+        write them in place."""
+        if not self.kv_layout:
+            raise ValueError(
+                "write_prefill hands over keys and values; this pool "
+                f"holds the rows {list(self.rows)}, which the prefill "
+                "programs write in place")
         pages = self.table(rid)
         t = int(k.shape[1])
         n = t // self.page_size
