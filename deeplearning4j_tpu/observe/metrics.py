@@ -754,6 +754,14 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "once (seq_len + spec_k + 1, capped at the page "
                 "table's span).  Times layers x 2 x heads x head size x "
                 "bytes per element it is the KV bytes demanded")
+    reg.counter("dl4jtpu_decode_pages_attended_total",
+                "Pool pages that hold the KV rows the decode dispatches "
+                "attended, summed over their live slots: ceil(rows / "
+                "page size) per slot, rows as "
+                "dl4jtpu_decode_rows_attended_total counts them.  The "
+                "paged kernel's loop runs over exactly these pages; over "
+                "decode steps x slots x pages per sequence it is the "
+                "share of the page tables that is live")
     reg.counter("dl4jtpu_serving_params_casts_total",
                 "Serving copies of the parameter tree the generation "
                 "engines made (the matrices cast to the activation "

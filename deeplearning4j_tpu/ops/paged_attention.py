@@ -13,20 +13,37 @@ one dispatch, mirroring ``ops/dequant_matmul.py``:
   ``-inf`` masking past ``seq_len``, f32 softmax, f32 einsum output) —
   masked positions contribute exact zeros, so paged greedy decode is
   token-identical to the dense reference.
-- ``pallas`` — the paged TPU kernel: grid (slots, pages), the page
-  table rides PrefetchScalarGridSpec so each grid step DMAs ONE pool
-  page into VMEM (HBM never sees a gathered dense copy), and the
-  softmax is accumulated online (running max / normalizer / weighted
-  sum in VMEM scratch) across a slot's pages.  The body is VPU-only
-  (one query row per head is a matrix-vector product — nothing for the
-  MXU to do).  CPU tier-1 runs the SAME kernel with ``interpret=True``;
+- ``pallas`` — the paged TPU kernel.  Its iteration space follows the
+  LIVE pages, not the page table's shape: grid ``(slots,)``, one
+  program per slot, whose body is a ``fori_loop`` over that slot's
+  ``ceil(seq_len / page_size)`` pages, several pages a turn
+  (`_pages_per_turn`: 64 rows where the page's bytes and the VMEM
+  budget allow — chosen from the shapes, no knob).  The pools stay in
+  HBM (``memory_space=ANY``; with ``layer`` the whole stack, indexed in
+  place); the page table and the lengths ride PrefetchScalarGridSpec
+  and the body copies each turn's pages into VMEM itself, the next
+  turn's in flight (double buffer) while this one is attended — HBM
+  never sees a gathered dense copy.  The softmax is accumulated online,
+  ONE update of the running max / normalizer / weighted sum per turn,
+  carried in registers.  What a slot costs is its pages: measured on a
+  v5e at 16 slots x 80 pages of 16 x 16 x 128 f32, an all-idle call is
+  9 us (16 near-empty programs), and a live page adds 0.33-0.39 us —
+  its 262 KB of K+V at 680-790 GB/s, so an f32 pool is read near the
+  HBM roofline.  A slot of length 0 runs no turn, fetches nothing and
+  writes zeros; a page past the last live one is never fetched; rows
+  past ``seq_len`` are taken out by selects, so nothing stale — a nan
+  included — reaches the output.  The body is VPU-only (one query row
+  per head is a matrix-vector product — nothing for the MXU to do).
+  CPU tier-1 runs the SAME kernel with ``interpret=True``;
   ``tests/test_tpu_lowering.py`` holds it to Mosaic's TPU lowering.
-- ``pallas_int8`` — the fused int8-KV variant: pages are int8 with
-  per-page scale blocks (``serving/kv_cache.py``'s layout); the kernel
-  dequantizes each page IN VMEM (HBM reads ~1 byte per KV element) and
-  accumulates in f32 — the decode step is HBM-bandwidth-bound, so on
-  TPU the byte ratio is the speedup (bench.py --generate's roofline
-  column).
+- ``pallas_int8`` — the same body over int8 pages with per-page scale
+  blocks (``serving/kv_cache.py``'s layout): a page is dequantized IN
+  VMEM (HBM reads ~1 byte per KV element) and accumulated in f32.  The
+  scale blocks alone do not come by the body's own copies: Mosaic
+  refuses any DMA out of an HBM array whose minor dimension is under
+  128 lanes, and the scale pool's is the head count, so the blocks of
+  the table's pages (1/32 of their pages' bytes) are gathered by XLA
+  and handed to each slot's program as a VMEM block.
 
 Selection (``impl=None``): the env override ``DL4JTPU_PAGED_KERNEL``
 (pallas / xla / auto) wins; auto picks ``pallas`` on TPU, ``xla`` on CPU
@@ -140,131 +157,199 @@ def _scale_col(row, eye):
                    axis=-1, keepdims=True)
 
 
-def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-               page_size: int, n_pages: int, quant: bool):
-    """Grid (slots, pages), pages innermost (sequential): online-softmax
-    accumulation of one slot's query row over its page-table-indexed
-    pages.  Scalar-prefetched ``tbl_ref``/``len_ref`` drive the page
-    DMAs via the BlockSpec index maps; this body only needs the mask.
+#: rows one loop turn attends where the shapes allow: the running max,
+#: normaliser and accumulator are rescaled once per turn, and the turn's
+#: rows are unrolled in the body, so this bounds the kernel's code too
+_TURN_ROWS = 64
+#: VMEM the page buffers may take (K and V, two turns each) of the
+#: 16 MiB a kernel gets by default on every TPU generation
+_PAGE_BUFFER_BYTES = 4 << 20
+
+
+def _pages_per_turn(page_size: int, page_bytes: int, n_pages: int) -> int:
+    """Pages one loop turn fetches and attends, from the shapes alone:
+    enough for `_TURN_ROWS` rows, no more than the double-buffered K and
+    V pages of `_PAGE_BUFFER_BYTES`, and never past the table's span."""
+    want = -(-_TURN_ROWS // page_size)
+    fit = _PAGE_BUFFER_BYTES // (4 * page_bytes)
+    return max(1, min(want, fit, n_pages))
+
+
+def _pa_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
+               page_size: int, group: int, quant: bool):
+    """Grid (slots,): one program per slot, whose work is a loop over
+    that slot's ``ceil(seq_len / page_size)`` LIVE pages, ``group`` pages
+    a turn.  The pools stay in HBM; the scalar-prefetched ``tbl_ref``
+    names the pages this body copies into VMEM itself, the next turn's
+    pages in flight (second buffer) while this turn's are attended.  A
+    page past the last live one is never fetched, and a slot of length
+    0 runs no turn and writes zeros.
 
     One query row per head makes the score a matrix-VECTOR product, so
-    the body stays on the VPU: every page row ``p`` is one (H, Dh) tile
-    (heads on sublanes, head_dim on lanes — the pool's own layout, no
-    transpose), its score column is ``sum(q * k_p, lanes)`` -> (H, 1),
+    the body stays on the VPU: every page row is one (H, Dh) tile (heads
+    on sublanes, head_dim on lanes — the pool's own layout, no
+    transpose), its score column is ``sum(q * k_row, lanes)`` -> (H, 1),
     and the weighted sum broadcasts that column back over the lanes.
-    The loop over the page's rows is unrolled (``page_size`` is static
-    and small), which keeps every value a whole (H, Dh) or (H, 1) tile —
-    the batched ``hd,phd->hp`` einsum this replaces has no Mosaic
-    lowering (no lhs free dimension, batch dimension mid-rhs)."""
+    The rows of a turn are unrolled (``group * page_size`` is static),
+    which keeps every value a whole (H, Dh) or (H, 1) tile — the batched
+    ``hd,phd->hp`` einsum this replaces has no Mosaic lowering (no lhs
+    free dimension, batch dimension mid-rhs) — and the turn's rows share
+    ONE online-softmax update.  Rows past ``seq_len`` (the tail of the
+    last live page, and the buffer of a page that was not fetched) are
+    taken out by SELECTS on the score and on the value row, never by a
+    product with a zero weight: whatever they hold, nan included, does
+    not reach the output."""
     if quant:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        ks_ref, vs_ref, *rest = rest
+    o_ref, k_buf, v_buf, sem = rest
+    streams = ((k_hbm, k_buf), (v_hbm, v_buf))
     s = pl.program_id(0)
-    j = pl.program_id(1)
     h, dh = q_ref.shape[1], q_ref.shape[2]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _MASK)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
+    turn_rows = group * page_size
     length = len_ref[s]
-    base = j * page_size
+    n_live = jax.lax.div(length + (page_size - 1), page_size)
+    n_turns = jax.lax.div(n_live + (group - 1), group)
 
-    # pages wholly past seq_len (the table's scratch-page tail, or every
-    # page of an idle slot) contribute exact zeros: skip their compute
-    @pl.when(base < length)
-    def _page():
-        q = q_ref[0].astype(jnp.float32) * (1.0 / np.sqrt(dh))   # (H, Dh)
-        if quant:
-            eye = (jax.lax.broadcasted_iota(jnp.int32, (h, h), 0)
-                   == jax.lax.broadcasted_iota(jnp.int32, (h, h), 1))
+    def turn_copies(t, buf, g):
+        """The async copies of page ``g`` of turn ``t`` into buffer
+        ``buf``: the layer and the table's page index address the pool
+        in place."""
+        at = layer_ref[0], tbl_ref[s, t * group + g]
+        return [pltpu.make_async_copy(hbm.at[at], vmem.at[buf, g],
+                                      sem.at[buf, i])
+                for i, (hbm, vmem) in enumerate(streams)]
+
+    def each_live_page(t, buf, do):
+        for g in range(group):
+            @pl.when(t * group + g < n_live)
+            def _(g=g):
+                for copy in turn_copies(t, buf, g):
+                    do(copy)
+
+    @pl.when(n_turns > 0)
+    def _first():
+        each_live_page(0, 0, lambda copy: copy.start())
+
+    q = q_ref[0].astype(jnp.float32) * (1.0 / np.sqrt(dh))       # (H, Dh)
+    if quant:
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (h, h), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (h, h), 1))
+
+    def turn(t, carry):
+        m_prev, ell, acc = carry
+        buf = jax.lax.rem(t, 2)
+
+        @pl.when(t + 1 < n_turns)
+        def _next():
+            each_live_page(t + 1, 1 - buf, lambda copy: copy.start())
+
+        each_live_page(t, buf, lambda copy: copy.wait())
+        base = t * turn_rows
+
+        def live(r, shape):
+            return jnp.full(shape, base + r, jnp.int32) < length
+
+        def scale_col(ref, r):
+            # the slot's gathered scale rows end with the table's span,
+            # the last turn's rows need not
+            at = jnp.minimum(base + r, ref.shape[1] - 1)
+            return _scale_col(ref[0, pl.ds(at, 1), :], eye)
+
         scores = []
-        for p in range(page_size):
-            sc = jnp.sum(q * k_ref[0, p].astype(jnp.float32),
+        for r in range(turn_rows):
+            g, p = divmod(r, page_size)
+            sc = jnp.sum(q * k_buf[buf, g, p].astype(jnp.float32),
                          axis=-1, keepdims=True)                 # (H, 1)
             if quant:
                 # the row scale commutes with the contraction over Dh
-                sc = sc * _scale_col(ks_ref[0, pl.ds(p, 1), :], eye)
-            pos = jnp.full((h, 1), base + p, jnp.int32)
-            scores.append(jnp.where(pos < length, sc, _MASK))
-        m_prev = m_ref[...]                                      # (H, 1)
+                sc = sc * scale_col(ks_ref, r)
+            scores.append(jnp.where(live(r, (h, 1)), sc, _MASK))
+        # every turn that runs holds a live row, so m_new is a real score
         m_new = functools.reduce(jnp.maximum, scores, m_prev)
         alpha = jnp.exp(m_prev - m_new)
-        ell = l_ref[...] * alpha
-        acc = acc_ref[...] * alpha                               # (H, Dh)
-        for p in range(page_size):
-            w = jnp.exp(scores[p] - m_new)        # (H, 1); masked -> 0.0
+        ell = ell * alpha
+        acc = acc * alpha                                        # (H, Dh)
+        for r in range(turn_rows):
+            g, p = divmod(r, page_size)
+            w = jnp.exp(scores[r] - m_new)        # (H, 1); masked -> 0.0
             ell = ell + w
             if quant:
-                w = w * _scale_col(vs_ref[0, pl.ds(p, 1), :], eye)
-            acc = acc + w * v_ref[0, p].astype(jnp.float32)
-        m_ref[...] = m_new
-        l_ref[...] = ell
-        acc_ref[...] = acc
+                # a dead row's scale is as stale as its values
+                w = jnp.where(live(r, (h, 1)), w * scale_col(vs_ref, r), 0.0)
+            row = jnp.where(live(r, (h, dh)),
+                            v_buf[buf, g, p].astype(jnp.float32), 0.0)
+            acc = acc + w * row
+        return m_new, ell, acc
 
-    @pl.when(j == n_pages - 1)
-    def _done():
-        ell = l_ref[...]
-        o_ref[0] = (acc_ref[...]
-                    / jnp.where(ell > 0.0, ell, 1.0)).astype(o_ref.dtype)
+    _, ell, acc = jax.lax.fori_loop(
+        0, n_turns, turn,
+        (jnp.full((h, 1), _MASK, jnp.float32),       # running max
+         jnp.zeros((h, 1), jnp.float32),             # running normalizer
+         jnp.zeros((h, dh), jnp.float32)))           # weighted-sum acc
+    o_ref[0] = (acc / jnp.where(ell > 0.0, ell, 1.0)).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames="interpret")
 def _pallas_paged_attention(q, k_pages, v_pages, page_tbl, seq_lens,
-                            k_scale=None, v_scale=None, layer=None, *,
-                            interpret: bool):
+                            k_scale, v_scale, layer, *, interpret: bool):
+    """The kernel's call, over (L, P, page_size, H, Dh) pools.  ``layer``
+    is DATA here, a (1,) int32 beside the table and the lengths: a
+    stack's calls then differ in a scalar and not in their code, so a
+    step traces and lowers this function — the 64-row body — once for
+    all its layers (a jit inside the step's: the lowered module holds
+    one function and a call per layer); a static layer would make every
+    layer's call a program of its own, 24 traces of the body a step."""
     s, h, dh = q.shape
     n_pages = page_tbl.shape[1]
     page_size = k_pages.shape[-3]
-    quant = k_scale is not None
-    # page blocks are selected by the scalar-prefetched table: grid step
-    # (s, j) DMAs pool page page_tbl[s, j] — the gather never exists in
-    # HBM.  Given the whole (L, P, ...) stack, the static `layer` is one
-    # more block index (a squeezed leading dim): the operand is the pool
-    # itself, the body sees the same (1, page_size, H, Dh) block
-    if layer is None:
-        lead, at = (), ()
-    else:
-        lead, at = (None,), (layer,)
-    page_spec = pl.BlockSpec(
-        lead + (1, page_size, h, dh),
-        lambda s_, j, tbl, lens: at + (tbl[s_, j], 0, 0, 0),
-    )
-    scale_spec = pl.BlockSpec(
-        lead + (1, page_size, h),
-        lambda s_, j, tbl, lens: at + (tbl[s_, j], 0, 0),
-    )
-    row_spec = pl.BlockSpec((1, h, dh), lambda s_, j, tbl, lens: (s_, 0, 0))
-    in_specs = [row_spec, page_spec, page_spec]
+    page_shape = (page_size, h, dh)
+    group = _pages_per_turn(
+        page_size, int(np.prod(page_shape)) * k_pages.dtype.itemsize,
+        n_pages)
+    # the pools are operands AS THEY ARE, in HBM: the prefetched `layer`
+    # is one more index of the body's page copies, so the gather never
+    # exists in HBM and no program holds a per-layer piece of the pool
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    row_spec = pl.BlockSpec((1, h, dh), lambda s_, *prefetched: (s_, 0, 0))
+    in_specs = [row_spec, in_hbm, in_hbm]
     args = [q, k_pages, v_pages]
-    if quant:
-        in_specs += [scale_spec, scale_spec]
-        args += [k_scale, v_scale]
+    if k_scale is not None:
+        # Mosaic copies nothing out of an HBM array whose minor dimension
+        # is under 128 lanes, and the scale pool's is the head count: the
+        # (page_size, H) scale blocks of the table's pages — 1/32 of their
+        # pages' bytes — are gathered here and reach the body as the
+        # slot's own (maxP * page_size, H) rows
+        span_spec = pl.BlockSpec((1, n_pages * page_size, h),
+                                 lambda s_, *prefetched: (s_, 0, 0))
+        in_specs += [span_spec, span_spec]
+        args += [_gather_pages(k_scale, page_tbl, layer[0]),
+                 _gather_pages(v_scale, page_tbl, layer[0])]
+    scratch = [
+        # K and V pages of two turns: the one attended, the one in flight
+        pltpu.VMEM((2, group) + page_shape, k_pages.dtype),
+        pltpu.VMEM((2, group) + page_shape, v_pages.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+    ]
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
         )
     return pl.pallas_call(
-        functools.partial(_pa_kernel, page_size=page_size,
-                          n_pages=n_pages, quant=quant),
+        functools.partial(_pa_kernel, page_size=page_size, group=group,
+                          quant=k_scale is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(s, n_pages),
+            num_scalar_prefetch=3,
+            grid=(s,),
             in_specs=in_specs,
             out_specs=row_spec,
-            scratch_shapes=[
-                pltpu.VMEM((h, 1), jnp.float32),       # running max
-                pltpu.VMEM((h, 1), jnp.float32),       # running normalizer
-                pltpu.VMEM((h, dh), jnp.float32),      # weighted-sum acc
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((s, h, dh), jnp.float32),
         interpret=interpret,
+        name="paged_attn",
         **kwargs,
-    )(page_tbl.astype(jnp.int32), seq_lens.astype(jnp.int32), *args)
+    )(page_tbl.astype(jnp.int32), seq_lens.astype(jnp.int32), layer, *args)
 
 
 # -- dispatch ---------------------------------------------------------------
@@ -394,10 +479,13 @@ def paged_attention(q, k_pages, v_pages, page_tbl, seq_lens, *,
             from deeplearning4j_tpu.runtime.backend import backend
 
             interpret = not backend().is_tpu
+        pools = k_pages, v_pages, k_scale, v_scale
+        if layer is None:
+            # a lone pool is a stack of one: a leading 1 moves no byte
+            pools = [a if a is None else a[None] for a in pools]
         return _pallas_paged_attention(
-            q, k_pages, v_pages, page_tbl, seq_lens,
-            k_scale=k_scale, v_scale=v_scale, layer=layer,
-            interpret=interpret,
+            q, *pools[:2], page_tbl, seq_lens, *pools[2:],
+            jnp.full((1,), layer or 0, jnp.int32), interpret=interpret,
         )
     return _xla_paged_attention(
         q, k_pages, v_pages, page_tbl, seq_lens,

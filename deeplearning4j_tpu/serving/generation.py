@@ -68,7 +68,7 @@ with no span around them, ``generation.decode_prepare`` ->
 ``generation.decode_readback`` -> ``generation.harvest``.  Each phase
 is timed ONCE, by its span: `req.lat` and the per-stream chain read the
 span's ``dur``.  Every step also counts what it served —
-``dl4jtpu_decode_{steps,slot_steps,rows_attended}_total``.
+``dl4jtpu_decode_{steps,slot_steps,rows_attended,pages_attended}_total``.
 """
 
 from __future__ import annotations
@@ -160,10 +160,12 @@ def _gen_breakdown_families() -> dict:
 
 
 #: what the decode steps served, process totals: the registry families
-#: behind the engine's `_steps`, `_slot_steps` and `_rows_attended`
+#: behind the engine's `_steps`, `_slot_steps`, `_rows_attended` and
+#: `_pages_attended`
 DECODE_COUNT_FAMILIES = ("dl4jtpu_decode_steps_total",
                          "dl4jtpu_decode_slot_steps_total",
-                         "dl4jtpu_decode_rows_attended_total")
+                         "dl4jtpu_decode_rows_attended_total",
+                         "dl4jtpu_decode_pages_attended_total")
 
 #: what the learned sparse selection did, process totals, counted on the
 #: host from the lengths (prefill and decode): context rows the indexers
@@ -192,6 +194,11 @@ PREFILL_QUERY_BLOCK = 256
 #: serving copies of the parameter tree made (`_serving_params`): one per
 #: installed tree, flushed with the decode counts
 PARAMS_CASTS_FAMILY = "dl4jtpu_serving_params_casts_total"
+
+#: the families `_flush_decode_counts` moves the engine's plain counts
+#: into, in the order it reads them
+_FLUSHED_FAMILIES = (DECODE_COUNT_FAMILIES + (PARAMS_CASTS_FAMILY,)
+                     + DSA_COUNT_FAMILIES)
 
 #: a prompt forward of fewer tokens than this spends longer READING f32
 #: block matrices than multiplying by them (2 FLOPs a token against 4
@@ -494,16 +501,17 @@ class GenerationEngine:
         )
         # what the steps served, counted where a step is built (engine
         # thread only): dispatches, live slots summed over them, and KV
-        # rows attended summed over them
+        # rows — and the pool pages that hold them — summed over them
         self._steps = 0
         self._slot_steps = 0
         self._rows_attended = 0
+        self._pages_attended = 0
         # `model.params` as the copies below were made from it, the tree
         # the programs are dispatched with, and the long prefill buckets'
         # (`_serving_params`); and how many were made
         self._served = (None, None, None)
         self._params_casts = 0
-        self._counts_flushed = (0, 0, 0, 0, 0, 0)
+        self._counts_flushed = (0,) * len(_FLUSHED_FAMILIES)
         self._tokens_out = 0
         # the compiled decode programs by chunk width c: 1 is the plain
         # step, spec_k + 1 the speculative verify (built at first use)
@@ -1459,14 +1467,19 @@ class GenerationEngine:
         # what the step serves, from the arrays it is dispatched with:
         # a slot is live where seq_len > 0, and attends its seq_len rows
         # plus the c it writes (a verify chunk's union, capped at the
-        # page table's span)
+        # page table's span), out of the pages that hold them: the
+        # paged kernel's loop runs over exactly those, and an idle slot
+        # adds none
         live = seq_lens[seq_lens > 0]
         n_live = int(live.size)
-        cap = self.config.max_pages_per_seq * self.kv.page_size
-        rows = int(np.minimum(live + c, cap).sum())
+        ps = self.kv.page_size
+        cap = self.config.max_pages_per_seq * ps
+        attended = np.minimum(live + c, cap)
+        rows = int(attended.sum())
         self._steps += 1
         self._slot_steps += n_live
         self._rows_attended += rows
+        self._pages_attended += int((-(-attended // ps)).sum())
         if self._dsa_layers:
             self._count_selection(
                 np.minimum(live[:, None] + 1 + np.arange(c), cap))
@@ -1959,6 +1972,7 @@ class GenerationEngine:
             "decode_steps": self._steps,
             "decode_slot_steps": self._slot_steps,
             "decode_rows_attended": self._rows_attended,
+            "decode_pages_attended": self._pages_attended,
             "serving_params_casts": self._params_casts,
             "dsa": {"rows_scored": self._dsa_scored,
                     "rows_selected": self._dsa_selected},
@@ -2080,6 +2094,7 @@ class GenerationEngine:
             moe = self._moe_counts_host()
             with self._stats_lock:
                 now = (self._steps, self._slot_steps, self._rows_attended,
+                       self._pages_attended,
                        self._params_casts, self._dsa_scored,
                        self._dsa_selected)
                 delta = [a - b for a, b in zip(now, self._counts_flushed)]
@@ -2088,9 +2103,7 @@ class GenerationEngine:
                     was = self._moe_flushed
                     moe_delta = moe if was is None else moe - was
                     self._moe_flushed = moe
-            for family, d in zip(
-                    DECODE_COUNT_FAMILIES + (PARAMS_CASTS_FAMILY,)
-                    + DSA_COUNT_FAMILIES, delta):
+            for family, d in zip(_FLUSHED_FAMILIES, delta):
                 if d > 0:
                     reg.counter(family).inc(d)
             if moe is not None:

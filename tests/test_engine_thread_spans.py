@@ -4,7 +4,7 @@ Every phase of the decode loop is a recorder span, so a `jax.profiler`
 session (here on the CPU, ring DISABLED) shows them on the engine
 thread's line of "/host:CPU": the admission spans nested, the step's
 four spans in a row, and the thread's time tiled without holes.  Each
-step also counts the slots and the KV rows it served."""
+step also counts the slots, the KV rows and the pool pages it served."""
 
 import time
 
@@ -248,7 +248,7 @@ def _run_scripted(eng, prompts, max_news):
     delta = [reg.counter(f).value() - b
              for f, b in zip(DECODE_COUNT_FAMILIES, before)]
     counted = (st["decode_steps"], st["decode_slot_steps"],
-               st["decode_rows_attended"])
+               st["decode_rows_attended"], st["decode_pages_attended"])
     assert tuple(delta) == counted
     return rows, counted
 
@@ -263,7 +263,9 @@ class TestStepCounts:
         # plain: a stream of max_new n rides n - 1 steps; the step at
         # seq_len t attends t + 1 rows (the row it writes counts).
         # A: 8 steps, rows 6..13 = 76.  B: 4 steps, rows 4..7 = 22.
-        assert counted == (8, 8 + 4, 76 + 22)
+        # Pages of 8 rows: A's rows 6..8 lie in one page, 9..13 in two
+        # (3 + 5 x 2); B's in one (4).
+        assert counted == (8, 8 + 4, 76 + 22, 13 + 4)
 
         eng = _engine(model, spec_k=3)
         eng.drafter = _Oracle(rows)
@@ -274,7 +276,8 @@ class TestStepCounts:
         # step 1: A at seq_len 5 and B at 3 attend 5 + 4 and 3 + 4 rows
         # (each slot's rows once); B is done (1 + 4 = 5 tokens).
         # step 2: A alone at seq_len 9 attends 13; done (1 + 4 + 4).
-        assert counted == (2, 2 + 1, (9 + 7) + 13)
+        # pages: 9 rows in two and 7 in one, then 13 in two
+        assert counted == (2, 2 + 1, (9 + 7) + 13, (2 + 1) + 2)
         assert eng.stats()["speculative"]["accepted"] == 9
 
     def test_counts_are_declared_and_engines_sum(self, model):
@@ -296,6 +299,39 @@ class TestStepCounts:
         reg.collect()           # stopped engines flushed, nothing twice
         after = [reg.counter(f).value() for f in DECODE_COUNT_FAMILIES]
         # each engine: one stream of 3 tokens = 2 one-slot steps, 5 + 6
-        assert [m - b for m, b in zip(mid, before)] == [4, 4, 22]
+        # rows, each time inside one page
+        assert [m - b for m, b in zip(mid, before)] == [4, 4, 22, 4]
         assert after == mid
+
+    def test_pages_attended_follow_the_live_pages(self, model):
+        """`dl4jtpu_decode_pages_attended_total`: per step the pages
+        that hold each live slot's rows, ceil(rows / page size) — what
+        the paged kernel's loop visits.  An engine with nothing to
+        decode adds nothing, and a slot that has finished adds nothing
+        to the steps that follow."""
+        pages = DECODE_COUNT_FAMILIES[3]
+        assert pages == "dl4jtpu_decode_pages_attended_total"
+        reg = registry()
+        idle = _engine(model).start()
+        try:
+            time.sleep(0.05)
+            st = idle.stats()
+        finally:
+            idle.stop()
+        assert st["decode_pages_attended"] == 0 == st["decode_steps"]
+        reg.collect()
+        before = reg.counter(pages).value()
+        # A: prompt 15, 4 tokens = 3 steps at 16, 17, 18 rows: 2, 3, 3
+        # pages of 8.  B: prompt 7, 3 tokens = 2 steps at 8 and 9 rows:
+        # 1 and 2 pages; its third step does not exist.
+        _, counted = _run_scripted(
+            _engine(model), [_prompt(15, seed=3), _prompt(7, seed=4)],
+            (4, 3))
+        assert counted == (3, 3 + 2, (16 + 17 + 18) + (8 + 9),
+                           (2 + 3 + 3) + (1 + 2))
+        reg.collect()
+        assert reg.counter(pages).value() - before == 11
+        # of the 3 steps x 4 slots x 4 pages the old grid visited
+        assert counted[3] / (counted[0] * CFG["slots"]
+                             * CFG["max_pages_per_seq"]) == 11 / 48
         assert gen_mod._collect_decode_counts in reg._collectors

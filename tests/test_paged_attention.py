@@ -237,6 +237,107 @@ class TestLayerIndexedPool:
             paged_attention(q, kp, vp, tbl, lens, layer=layer, impl="xla")
 
 
+class TestLivePageLoop:
+    """The kernel's iteration space is the slot's LIVE pages, several a
+    loop turn: every boundary of a page and of a turn, both element
+    types, the pool given alone and as a stack with ``layer``, always
+    through a shuffled (non-contiguous) page table — against the `xla`
+    route.  Pages of 16 rows make a turn of 4 pages, 64 rows, in a
+    table of 10."""
+
+    PS, MAXP, L = 16, 10, 3
+    TURN = 4 * PS
+    LENS = {"empty": 0, "one_row": 1, "page_less_one": PS - 1,
+            "one_page": PS, "page_plus_one": PS + 1, "one_turn": TURN,
+            "turn_plus_one": TURN + 1, "whole_span": MAXP * PS}
+
+    def _case(self, seq_len, kv_dtype, seed=0):
+        """Three slots — the length under test between an idle slot and
+        one of another length — over a pool whose other layers and pages
+        hold other values."""
+        rng = np.random.default_rng(seed)
+        lens = np.array([0, seq_len, 37], np.int32)
+        n_pool = 3 * self.MAXP + 1
+        tbl = (rng.permutation(np.arange(1, n_pool))
+               .reshape(3, self.MAXP).astype(np.int32))
+        shape = (self.L, n_pool, self.PS, H, DH)
+        k = rng.standard_normal(shape).astype(np.float32)
+        v = rng.standard_normal(shape).astype(np.float32)
+        q = jnp.asarray(rng.standard_normal((3, H, DH)).astype(np.float32))
+        pools = [k, v]
+        if kv_dtype == "int8":
+            kq, ks = quantize_page_rows(jnp.asarray(k))
+            vq, vs = quantize_page_rows(jnp.asarray(v))
+            pools = [np.array(a) for a in (kq, vq, ks, vs)]
+        return q, pools, tbl, lens
+
+    _programs = {}
+
+    @classmethod
+    def _call(cls, q, pools, tbl, lens, layer, **kw):
+        """One jitted program per route, element type and layer serves
+        every length: ``seq_lens`` is data, which is the point."""
+        if layer is None:                    # the 4-D pool, alone
+            pools = [a[1] for a in pools]
+        key = (len(pools), layer, tuple(sorted(kw.items())))
+        if key not in cls._programs:
+            def f(q, tbl, lens, k, v, ks=None, vs=None):
+                return paged_attention(q, k, v, tbl, lens, k_scale=ks,
+                                       v_scale=vs, layer=layer, **kw)
+            cls._programs[key] = jax.jit(f)
+        return np.asarray(cls._programs[key](
+            q, jnp.asarray(tbl), jnp.asarray(lens),
+            *(jnp.asarray(a) for a in pools)))
+
+    def test_turn_follows_the_shapes(self):
+        from deeplearning4j_tpu.ops.paged_attention import _pages_per_turn
+
+        assert _pages_per_turn(self.PS, self.PS * H * DH * 4,
+                               self.MAXP) * self.PS == self.TURN
+        # the cell's pages: 16 rows of 16 x 128 f32
+        assert _pages_per_turn(16, 16 * 16 * 128 * 4, 80) == 4
+        # a page that alone fills the buffers, and a table of one page
+        assert _pages_per_turn(16, 8 << 20, 80) == 1
+        assert _pages_per_turn(4, 256, 1) == 1
+
+    @pytest.mark.parametrize("layer", [None, 1], ids=["pool", "stack"])
+    @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+    @pytest.mark.parametrize("seq_len", list(LENS.values()),
+                             ids=list(LENS))
+    def test_matches_xla(self, seq_len, kv_dtype, layer):
+        q, pools, tbl, lens = self._case(seq_len, kv_dtype, seed=seq_len)
+        ref = self._call(q, pools, tbl, lens, layer, impl="xla")
+        got = self._call(q, pools, tbl, lens, layer, impl="pallas",
+                         interpret=True)
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+        # an idle slot writes zeros, whatever its table row names
+        np.testing.assert_array_equal(got[0], 0.0)
+        if seq_len == 0:
+            np.testing.assert_array_equal(got[1], 0.0)
+
+    @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+    @pytest.mark.parametrize("poison", [np.nan, np.inf], ids=["nan", "inf"])
+    def test_nothing_dead_reaches_the_output(self, poison, kv_dtype):
+        """Every page the table does not make live, and every row past
+        ``seq_len`` in the last live page, holds nan or inf (an int8
+        pool: in its scales): the output is finite and the reference's
+        over the clean pool.  0 x garbage is not 0."""
+        seq_len = self.TURN + self.PS + 3    # a turn, a page, three rows
+        q, pools, tbl, lens = self._case(seq_len, kv_dtype, seed=11)
+        ref = self._call(q, pools, tbl, lens, 1, impl="xla")
+        live = np.zeros(pools[0].shape[:3], bool)       # (L, P, ps)
+        for s, n in enumerate(lens):
+            for j in range(-(-int(n) // self.PS)):
+                live[:, tbl[s, j], :min(self.PS, n - j * self.PS)] = True
+        # an int8 row cannot hold a nan: its scale does
+        for a in (pools[2:] if kv_dtype == "int8" else pools):
+            a[~live] = poison
+        got = self._call(q, pools, tbl, lens, 1, impl="pallas",
+                         interpret=True)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+
+
 class TestSelection:
     def test_env_override_wins(self, monkeypatch):
         from deeplearning4j_tpu.ops import paged_attention as pa

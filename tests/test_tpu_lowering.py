@@ -138,6 +138,25 @@ def test_paged_attention_chunk_c5_layer_indexed():
                   sds((S, MP), jnp.int32), sds((S, c), jnp.int32))
 
 
+@pytest.mark.parametrize("c", [1, 5], ids=["step", "verify_c5"])
+def test_paged_call_is_one_kernel_at_serve_chat(c):
+    """The `serve_chat` cell's call — 16 slots x 80 pages over the whole
+    (24, 650, 16, 16, 128) f32 pool, a middle layer — is ONE Mosaic call,
+    the plain step's and the c = 5 verify chunk's alike: the benchmark's
+    roofline reader counts decode steps as Pallas calls / n_layer."""
+    slots, pages = 16, 80
+    pool = sds((24, 650, 16, 16, 128), jnp.float32)
+
+    def f(q, k, v, tbl, attend):
+        return pa.paged_attention_chunk(q, k, v, tbl, attend, layer=11,
+                                        impl="pallas", interpret=False)
+
+    exported = lower_for_tpu(
+        f, sds((slots, c, 16, 128), jnp.float32), pool, pool,
+        sds((slots, pages), jnp.int32), sds((slots, c), jnp.int32))
+    assert exported.mlir_module().count("@tpu_custom_call") == 1
+
+
 @pytest.mark.parametrize("m", [1, 8, 256])
 def test_dequant_matmul(m):
     lower_for_tpu(
