@@ -762,6 +762,18 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "paged kernel's loop runs over exactly these pages; over "
                 "decode steps x slots x pages per sequence it is the "
                 "share of the page tables that is live")
+    reg.counter("dl4jtpu_decode_steps_overlapped_total",
+                "Decode steps dispatched while the step before was still "
+                "unread (the loop's one-step lookahead): the host's work "
+                "for them lay under the device's.  Over "
+                "dl4jtpu_decode_steps_total it is the share of steps the "
+                "lookahead engaged for; the rest followed a drain (an "
+                "admission, a drafter, a stop)")
+    reg.counter("dl4jtpu_decode_slot_steps_discarded_total",
+                "Slot-rows a decode step computed for a stream that had "
+                "ended before the step's tokens were read (a stop token "
+                "or a cancel the step before brought to light): the "
+                "lookahead's cost; 0 where streams end by count")
     reg.counter("dl4jtpu_serving_params_casts_total",
                 "Serving copies of the parameter tree the generation "
                 "engines made (the matrices cast to the activation "

@@ -62,13 +62,30 @@ The engine THREAD's time is tiled by recorder spans of its own
 records, ring or no ring): ``generation.wait_for_work`` (no live slot:
 asleep in the queue) | ``generation.refill`` (an admission: every live
 stream waits) > ``generation.admit_to_slot`` per request >
-``generation.prefill`` + ``generation.kv_handoff`` | then, per step and
-with no span around them, ``generation.decode_prepare`` ->
+``generation.prefill`` + ``generation.kv_handoff`` | then, per loop turn
+and with no span around them, ``generation.decode_prepare`` ->
 ``generation.decode_dispatch`` (args ``slots``/``rows``) ->
 ``generation.decode_readback`` -> ``generation.harvest``.  Each phase
 is timed ONCE, by its span: `req.lat` and the per-stream chain read the
 span's ``dur``.  Every step also counts what it served —
 ``dl4jtpu_decode_{steps,slot_steps,rows_attended,pages_attended}_total``.
+
+The decode loop looks ONE step ahead (`_decode_step`): a turn prepares
+and dispatches step n + 1 while step n still runs on the device, and
+only then reads back and harvests step n — the host's work per step
+lies under the device's, and the device finds the next step queued
+when one ends.  Step n + 1's tokens are step n's output, handed on as
+the device array it is; everything else it needs is known at dispatch
+(a plain step gives every live slot one row and one token, and a stream
+that ends by count leaves the step after).  What is not known — a stop
+token, a cancel — costs one discarded row: the harvest hands a token
+only to the request that held the slot when the step was built.  The
+loop reads the step in flight back BEFORE it goes on (`_must_drain`,
+`_refill`, `stop()`) whenever the next action needs the host's view
+whole: an admission, a drafter (it reads host tokens), a stop, a
+failure.  ``dl4jtpu_decode_steps_overlapped_total`` over
+``dl4jtpu_decode_steps_total`` is the share of steps dispatched that
+way; ``dl4jtpu_decode_slot_steps_discarded_total`` the rows thrown away.
 """
 
 from __future__ import annotations
@@ -167,6 +184,13 @@ DECODE_COUNT_FAMILIES = ("dl4jtpu_decode_steps_total",
                          "dl4jtpu_decode_rows_attended_total",
                          "dl4jtpu_decode_pages_attended_total")
 
+#: how often the lookahead engages, process totals: steps dispatched
+#: while the step before was still unread, and slot-rows a step computed
+#: for a stream that had ended before its tokens were read (`_overlapped`,
+#: `_discarded`)
+DECODE_LOOKAHEAD_FAMILIES = ("dl4jtpu_decode_steps_overlapped_total",
+                             "dl4jtpu_decode_slot_steps_discarded_total")
+
 #: what the learned sparse selection did, process totals, counted on the
 #: host from the lengths (prefill and decode): context rows the indexers
 #: scored and rows they selected, per query row per indexer layer
@@ -198,7 +222,7 @@ PARAMS_CASTS_FAMILY = "dl4jtpu_serving_params_casts_total"
 #: the families `_flush_decode_counts` moves the engine's plain counts
 #: into, in the order it reads them
 _FLUSHED_FAMILIES = (DECODE_COUNT_FAMILIES + (PARAMS_CASTS_FAMILY,)
-                     + DSA_COUNT_FAMILIES)
+                     + DSA_COUNT_FAMILIES + DECODE_LOOKAHEAD_FAMILIES)
 
 #: a prompt forward of fewer tokens than this spends longer READING f32
 #: block matrices than multiplying by them (2 FLOPs a token against 4
@@ -399,6 +423,10 @@ def _to_row_width(rows, pool):
 _StepRows = collections.namedtuple(
     "_StepRows", "page_tbl page_of row_of attend_lens positions active")
 
+#: a decode step dispatched and not read back: its tokens on the device
+#: (the next step's ``toks``), and the harvest that hands them out
+_Flying = collections.namedtuple("_Flying", "toks harvest")
+
 
 def _slot_keys(seeds, gen_counts):
     """Per-slot sampling keys on the dense reference's schedule: the
@@ -506,6 +534,13 @@ class GenerationEngine:
         self._slot_steps = 0
         self._rows_attended = 0
         self._pages_attended = 0
+        # the lookahead: the newest step dispatched and not read back
+        # (written under `_mu` by the loop that owns it), how many steps
+        # were dispatched on top of one, and how many of their rows no
+        # stream was left to take
+        self._flying: Optional[_Flying] = None
+        self._overlapped = 0
+        self._discarded = 0
         # `model.params` as the copies below were made from it, the tree
         # the programs are dispatched with, and the long prefill buckets'
         # (`_serving_params`); and how many were made
@@ -1211,10 +1246,14 @@ class GenerationEngine:
                 if n_active == 0:
                     continue
                 self._decode_step(my_gen)
+            if self._flying is not None:
+                # stopped: the step in flight still hands out its tokens
+                self._land(my_gen, self._flying)
         except Exception as exc:                      # never die silently
             log.exception("generation loop died")
             with self._mu:
                 if self._loop_gen == my_gen:
+                    self._flying = None
                     self._fail_active_locked(
                         ServingError(f"generation loop died: {exc}"))
 
@@ -1229,6 +1268,11 @@ class GenerationEngine:
             return
         if self.queue.depth == 0 and not block:
             return
+        if self._flying is not None:
+            # an admission writes the slots the step in flight was built
+            # from, and a sleep in the queue would leave it unread
+            self._land(my_gen, self._flying)
+            free = self._free_slots()
         if block:
             # no slot is live: the engine thread sleeps in the queue
             with self._span("generation.wait_for_work"):
@@ -1371,23 +1415,38 @@ class GenerationEngine:
         self._gauge_occupancy()
 
     def _decode_step(self, my_gen: int) -> None:
-        """One dispatch for every live slot, as four spans in a row on
-        the engine thread with NO span around them (a gap of the device
-        between two steps straddles all four; a parent would take every
-        such gap for itself): ``generation.decode_prepare`` (fault
-        consult, drafts, argument copies, `_serving_params` — the hot-swap
-        boundary — watchdog arm) -> ``generation.decode_dispatch`` (the
-        jit call and the donated pool's rebinding from its result;
-        annotated with the live slots and the KV rows the step attends)
-        -> ``generation.decode_readback`` (the
-        blocking ``np.asarray``) -> ``generation.harvest`` (stop
-        conditions, callbacks, page release, slot free, gauges)."""
-        with self._span("generation.decode_prepare"):
-            plan = self._prepare_step(my_gen)
-        if plan is None:
+        """One turn of the decode loop.  With step n in flight it builds
+        and dispatches step n + 1 and only then reads step n back, as
+        four spans in a row on the engine thread with NO span around them
+        (a gap of the device between two steps straddles all four; a
+        parent would take every such gap for itself):
+        ``generation.decode_prepare`` (fault consult, drafts, argument
+        copies, `_serving_params` — the hot-swap boundary — watchdog arm)
+        -> ``generation.decode_dispatch`` (the jit call and the donated
+        pool's rebinding from its result; annotated with the live slots
+        and the KV rows the step attends) -> ``generation.decode_readback``
+        (the blocking ``np.asarray`` of the OLDER step's tokens) ->
+        ``generation.harvest`` (stop conditions, callbacks, page release,
+        slot free, gauges).  With nothing in flight the turn ends after
+        the dispatch and the next one lands on top of it — unless
+        `_must_drain` says the host needs this step's tokens first, when
+        all four spans are of one step; with a step in flight and
+        `_must_drain` the turn is its readback and harvest alone.
+
+        A step that cannot be built or dispatched leaves the one in
+        flight good: its tokens go out, then the streams fail once.  A
+        readback that fails takes the step dispatched on top of it along
+        (`_land`)."""
+        prev = self._flying
+        if prev is not None and self._must_drain():
+            self._land(my_gen, prev)
             return
-        fn, params, args, n_live, rows, harvest = plan
         try:
+            with self._span("generation.decode_prepare"):
+                plan = self._prepare_step(my_gen, prev)
+            if plan is None:
+                return                  # a stale loop: `_on_wedged` owns it
+            fn, params, args, n_live, rows, harvest = plan
             with self._span("generation.decode_dispatch",
                             slots=n_live, rows=rows) as disp:
                 out = fn(params, *self._program_state(), *args)
@@ -1396,33 +1455,84 @@ class GenerationEngine:
                 # fail.  A loop that `_on_wedged` replaced meanwhile has
                 # had its pool revived and drops this one
                 with self._mu:
-                    if self._loop_gen == my_gen:
-                        self._rebind_state(out[:-1])
-            with self._span("generation.decode_readback") as rb:
-                toks = np.asarray(out[-1])
+                    if self._loop_gen != my_gen:
+                        return
+                    self._rebind_state(out[:-1])
+                    self._flying = step = _Flying(out[-1], harvest)
         except Exception as exc:
+            if prev is None or self._land(my_gen, prev):
+                self.watchdog.disarm(None)
+                self._step_failed(my_gen, exc)
+            return
+        if prev is not None:
+            self._land(my_gen, prev, disp)
+        elif self._must_drain():
+            self._land(my_gen, step, disp)
+
+    def _must_drain(self) -> bool:
+        """With a step in flight: must the loop read it back before it
+        builds another?  Yes where the next action needs the host's view
+        whole — a drafter proposes from host tokens, a stop wants every
+        token out, a slot that is free (or ends by count with the step in
+        flight) while a request waits is an admission, and when every
+        stream ends with the step in flight there is no step to build."""
+        if self.drafter is not None or self._stop.is_set():
+            return True
+        with self._mu:
+            stays = [r is not None and g + 1 < r.max_new
+                     for r, g in zip(self._slot_req, self._gen_counts)]
+        return not any(stays) or (self.queue.depth > 0 and not all(stays))
+
+    def _land(self, my_gen: int, step: _Flying, disp=None) -> bool:
+        """Read a dispatched step's tokens back and harvest them: the
+        second half of a turn, ``disp`` the dispatch span of its first
+        half (of the step built on top of this one, or of this step
+        itself).  `req.lat["decode_compute"]` is that dispatch plus this
+        readback — the turn less prepare and harvest — and the watchdog's
+        EWMA is fed the same; it stays armed while another step flies.
+        False when the readback failed: the step dispatched on top of
+        this one attended rows that never came to be, so both are gone,
+        the streams fail ONCE and the pool is revived once."""
+        newest = step is self._flying
+        try:
+            with self._span("generation.decode_readback") as rb:
+                toks = np.asarray(step.toks)
+        except Exception as exc:
+            with self._mu:
+                if self._loop_gen == my_gen:
+                    self._flying = None
             self.watchdog.disarm(None)
             self._step_failed(my_gen, exc)
-            return
+            return False
         with self._span("generation.harvest") as hv:
-            # decode_compute is exactly dispatch + readback
-            step_s = disp.dur + rb.dur
-            self.watchdog.disarm(step_s)
-            harvest(my_gen, toks, disp.t0, step_s, hv.t0)
+            with self._mu:
+                if self._loop_gen != my_gen:
+                    return True            # wedged + respawned: stale
+                if newest:
+                    self._flying = None
+            t0, step_s = ((rb.t0, rb.dur) if disp is None
+                          else (disp.t0, disp.dur + rb.dur))
+            self.watchdog.disarm(None if disp is None else step_s)
+            if not newest:
+                self.watchdog.arm(self._steps)
+            step.harvest(my_gen, toks, t0, step_s, hv.t0)
+        return True
 
-    def _prepare_step(self, my_gen: int):
+    def _prepare_step(self, my_gen: int, prev: Optional[_Flying]):
         """Everything between the loop's decision to step and the jit
         call: returns ``(program, params, host args, live slots, KV rows
-        attended, harvest)`` — or None when a fault, a stale loop
-        generation or an injected failure ends the step here.  The
-        speculative verify program is picked when any stream drafted;
-        otherwise the plain one-token program (both are warm, so the mix
-        never compiles)."""
-        try:
-            faults.maybe_fail("serving.decode")
-        except Exception as exc:
-            self._step_failed(my_gen, exc)
-            return None
+        attended, harvest)`` — or None for a stale loop generation; an
+        injected fault raises.  The speculative verify program is picked
+        when any stream drafted; otherwise the plain one-token program
+        (both are warm, so the mix never compiles).
+
+        With ``prev`` in flight the host's view of the slots is one step
+        old, and what that step does to it is known: every live slot
+        gains one row and one token, and a stream whose count reaches
+        ``max_new`` leaves.  The step is built from the view so advanced,
+        its tokens are ``prev``'s output as it lies on the device, and
+        its harvest remembers who held each slot."""
+        faults.maybe_fail("serving.decode")
         drafts = None
         if self.drafter is not None:
             drafts = self._gather_drafts(my_gen)
@@ -1435,9 +1545,27 @@ class GenerationEngine:
         with self._mu:
             if self._loop_gen != my_gen:
                 return None
+            reqs = list(self._slot_req)
+            page_tbl = self._page_tbl.copy()
             seq_lens = self._seq_lens.copy()
             gen0 = self._gen_counts.copy()
-            if drafts is None:
+            seeds = self._seeds.copy()
+            temps = self._temps.copy()
+            top_ks = self._top_ks.copy()
+            if prev is not None:
+                toks_in = prev.toks
+                for s, req in enumerate(reqs):
+                    if req is None:
+                        continue
+                    if gen0[s] + 1 < req.max_new:
+                        seq_lens[s] += 1
+                        gen0[s] += 1
+                    else:           # ends by count with the step in flight
+                        reqs[s] = None
+                        page_tbl[s] = SCRATCH_PAGE
+                        for a in (seq_lens, gen0, seeds, temps, top_ks):
+                            a[s] = 0
+            elif drafts is None:
                 toks_in = self._last_tok.copy()
             else:
                 toks_in = np.zeros((self.config.slots, c), np.int32)
@@ -1449,19 +1577,26 @@ class GenerationEngine:
                     m = min(int(d.size), self.spec_k)
                     toks_in[s, 1:1 + m] = d[:m]
                     dl[s] = m
-            args = (self._page_tbl.copy(), seq_lens, toks_in,
-                    self._seeds.copy(), gen0, self._temps.copy(),
-                    self._top_ks.copy())
             # built at first use; `jax.jit` construction is lazy, so
             # cheap under the lock (see `_prefill_fn`)
             fn = self._step_fns.get(c)
             if fn is None:
                 fn = self._step_fns[c] = self._make_step(c)
-        if drafts is None:
-            harvest = self._harvest_plain
-        else:
+        if drafts is not None:
             def harvest(*a):
                 self._harvest_verify(*a, toks_in, dl, gen0)
+        else:
+            harvest = functools.partial(self._harvest_plain, reqs,
+                                        self._steps + 1)
+            if prev is None:
+                # the plain step takes its tokens as a device array, from
+                # the host as from the step before: ONE call signature,
+                # so one executable.  Placed as the pool is, which comes
+                # back from the same programs as the tokens do
+                like = self.kv.pool()[0]
+                toks_in = jax.device_put(
+                    toks_in, like.sharding if like.committed else None)
+        args = (page_tbl, seq_lens, toks_in, seeds, gen0, temps, top_ks)
 
         params = self._serving_params()
         # what the step serves, from the arrays it is dispatched with:
@@ -1477,6 +1612,7 @@ class GenerationEngine:
         attended = np.minimum(live + c, cap)
         rows = int(attended.sum())
         self._steps += 1
+        self._overlapped += prev is not None
         self._slot_steps += n_live
         self._rows_attended += rows
         self._pages_attended += int((-(-attended // ps)).sum())
@@ -1488,17 +1624,23 @@ class GenerationEngine:
         self.watchdog.arm(self._steps, n_steps=c)
         return fn, params, args, n_live, rows, harvest
 
-    def _harvest_plain(self, my_gen: int, nxt, t0: float,
-                       step_s: float, t_h0: float) -> None:
-        """One token for every live slot of a plain step."""
+    def _harvest_plain(self, reqs: list, step_no: int, my_gen: int, nxt,
+                       t0: float, step_s: float, t_h0: float) -> None:
+        """One token for every slot of plain step ``step_no`` whose
+        request still holds it: ``reqs`` is who held each slot when the
+        step was built.  A stream that ended meanwhile (a stop token or a
+        cancel the step before brought to light) left a row nobody takes."""
         with self._mu:
             if self._loop_gen != my_gen:
                 return                     # wedged + respawned: stale
             finished: list[tuple[GenerationRequest, bool]] = []
             stepped: list[tuple[GenerationRequest, int]] = []
             n_live = 0
-            for s, req in enumerate(self._slot_req):
+            for s, req in enumerate(reqs):
                 if req is None:
+                    continue
+                if self._slot_req[s] is not req:
+                    self._discarded += 1
                     continue
                 if req.cancelled:
                     self._clear_slot(s)
@@ -1524,7 +1666,7 @@ class GenerationEngine:
                 for req, _ in stepped:
                     self._trace_segment(
                         req, "generation.decode_step", t0, step_s,
-                        step=self._steps, batch=rids,
+                        step=step_no, batch=rids,
                         batch_tokens=counts,
                     )
         self._charge_step([r for r, _ in stepped], step_s, t_h0)
@@ -1799,6 +1941,8 @@ class GenerationEngine:
             gen = self._loop_gen
             # the wedged dispatch holds the donated pool: the respawned
             # loop gets a new one (no waiting on a device that is stuck)
+            # and no step in flight
+            self._flying = None
             self._revive_state()
             self._fail_active_locked(
                 ServingError(f"decode step wedged: {event.get('stage')}"),
@@ -1936,7 +2080,8 @@ class GenerationEngine:
         True when drained within the timeout."""
         t_end = time.monotonic() + timeout
         while time.monotonic() < t_end:
-            if self.active_streams() == 0 and self.queue.depth == 0:
+            if (self.active_streams() == 0 and self.queue.depth == 0
+                    and self._flying is None):
                 return True
             time.sleep(self.config.poll_s)
         return False
@@ -1973,6 +2118,8 @@ class GenerationEngine:
             "decode_slot_steps": self._slot_steps,
             "decode_rows_attended": self._rows_attended,
             "decode_pages_attended": self._pages_attended,
+            "decode_steps_overlapped": self._overlapped,
+            "decode_slot_steps_discarded": self._discarded,
             "serving_params_casts": self._params_casts,
             "dsa": {"rows_scored": self._dsa_scored,
                     "rows_selected": self._dsa_selected},
@@ -2096,7 +2243,8 @@ class GenerationEngine:
                 now = (self._steps, self._slot_steps, self._rows_attended,
                        self._pages_attended,
                        self._params_casts, self._dsa_scored,
-                       self._dsa_selected)
+                       self._dsa_selected, self._overlapped,
+                       self._discarded)
                 delta = [a - b for a, b in zip(now, self._counts_flushed)]
                 self._counts_flushed = now
                 if moe is not None:

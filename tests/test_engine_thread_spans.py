@@ -2,9 +2,14 @@
 
 Every phase of the decode loop is a recorder span, so a `jax.profiler`
 session (here on the CPU, ring DISABLED) shows them on the engine
-thread's line of "/host:CPU": the admission spans nested, the step's
+thread's line of "/host:CPU": the admission spans nested, a turn's
 four spans in a row, and the thread's time tiled without holes.  Each
-step also counts the slots, the KV rows and the pool pages it served."""
+step also counts the slots, the KV rows and the pool pages it served.
+
+ISSUE 36 — a turn of the loop is ``decode_prepare`` and
+``decode_dispatch`` of step n + 1, then ``decode_readback`` and
+``harvest`` of step n: the dispatch of a step ends before the readback
+of the step before it starts, and two more counters say how often."""
 
 import time
 
@@ -16,6 +21,7 @@ from deeplearning4j_tpu.observe.metrics import registry
 from deeplearning4j_tpu.serving import generation as gen_mod
 from deeplearning4j_tpu.serving.generation import (
     DECODE_COUNT_FAMILIES,
+    DECODE_LOOKAHEAD_FAMILIES,
     GEN_BREAKDOWN_SEGMENTS,
     GenerationConfig,
     GenerationEngine,
@@ -59,10 +65,10 @@ def _inside(inner, outer):
 
 
 @pytest.fixture(scope="module")
-def engine_line(model, tmp_path_factory):
-    """The engine thread's line of one profiler session: a warm engine
-    (no compile inside the session) serves 3 streams with the ring
-    disabled, then stops."""
+def session(model, tmp_path_factory):
+    """The engine thread's line of one profiler session (``events``): a
+    warm engine (no compile inside the session) serves 3 streams with the
+    ring disabled, then stops; and what the engine counted (``stats``)."""
     from conftest import HostProfile
 
     assert not tracer().enabled
@@ -71,6 +77,7 @@ def engine_line(model, tmp_path_factory):
     eng = _engine(model, max_pages_per_seq=8).start()
     try:
         eng.generate(_prompt(5, seed=9), 3, timeout=120.0)     # warm
+        warm = eng.stats()
         with HostProfile(str(tmp_path_factory.mktemp("prof"))) as prof:
             reqs = [eng.submit(_prompt(4 + i, seed=i), 20 + 10 * i)
                     for i in range(3)]
@@ -78,6 +85,8 @@ def engine_line(model, tmp_path_factory):
                 r.result(120.0)
             time.sleep(0.05)        # the idle loop goes back to sleep
             eng.stop()
+        stats = {k: v - warm[k] for k, v in eng.stats().items()
+                 if k.startswith("decode_")}
     finally:
         eng.stop()
     # ONE line holds every generation span: the engine thread's (named
@@ -87,7 +96,18 @@ def engine_line(model, tmp_path_factory):
         if other is not line:
             assert not any(e["name"].startswith("generation.")
                            for e in other)
-    return [e for e in line if e["name"].startswith("generation.")]
+    return {"events": [e for e in line
+                       if e["name"].startswith("generation.")],
+            "stats": stats}
+
+
+@pytest.fixture(scope="module")
+def engine_line(session):
+    return session["events"]
+
+
+def _named(events, phase):
+    return [e for e in events if e["name"] == "generation." + phase]
 
 
 class TestEngineThreadOnTheProfilersClock:
@@ -96,18 +116,49 @@ class TestEngineThreadOnTheProfilersClock:
         assert names == set(STEP_SPANS) | set(ADMIT_SPANS) | {
             "generation.wait_for_work"}
 
-    def test_step_spans_come_in_order_and_disjoint(self, engine_line):
+    def test_step_spans_come_in_turns_and_disjoint(self, engine_line):
+        """A turn is prepare + dispatch, readback + harvest, or all four
+        in that order: a prepare is followed by its dispatch, a readback
+        by its harvest, and nothing lies around any of them."""
         steps = [e for e in engine_line if e["name"] in STEP_SPANS]
-        assert len(steps) >= 4 * 39         # the longest stream: 39 steps
-        assert len(steps) % 4 == 0
-        for i, e in enumerate(steps):
-            assert e["name"] == STEP_SPANS[i % 4]
-            if i:
-                assert steps[i - 1]["end"] <= e["start"]
+        count = {n: sum(e["name"] == n for e in steps) for n in STEP_SPANS}
+        assert len(set(count.values())) == 1       # every step: all four
+        assert count[STEP_SPANS[0]] >= 39          # the longest stream
+        follows = dict(zip(STEP_SPANS[::2], STEP_SPANS[1::2]))
+        for a, b in zip(steps, steps[1:]):
+            assert a["end"] <= b["start"]
+            if a["name"] in follows:
+                assert b["name"] == follows[a["name"]]
+            else:       # a turn ended: the next starts with either half
+                assert b["name"] in follows
+        assert steps[0]["name"] == STEP_SPANS[0]
+        assert steps[-1]["name"] == STEP_SPANS[3]
         # no span lies around a step: its four are top-level
         for e in steps:
             assert not any(o is not e and _inside(e, o)
                            for o in engine_line)
+
+    def test_dispatch_of_the_next_step_ends_before_the_readback(
+            self, session):
+        """Step k is read back by the k-th readback.  It was dispatched
+        before that, and — where the lookahead engaged — so was step
+        k + 1: as many steps as the engine says it overlapped."""
+        events, stats = session["events"], session["stats"]
+        disp, back = _named(events, "decode_dispatch"), _named(
+            events, "decode_readback")
+        assert len(disp) == len(back) == stats["decode_steps"]
+        for d, r in zip(disp, back):
+            assert d["end"] <= r["start"]
+        ahead = sum(d["end"] <= r["start"]
+                    for d, r in zip(disp[1:], back))
+        assert ahead == stats["decode_steps_overlapped"]
+        # one refill admits the three streams; nothing waits after it, so
+        # only the first step was built with nothing in flight
+        assert ahead == stats["decode_steps"] - 1 == 38
+        assert stats["decode_slot_steps_discarded"] == 0
+        # a steady turn in full: prepare, dispatch, readback, harvest
+        names = [e["name"] for e in events if e["name"] in STEP_SPANS]
+        assert names[2:6] == STEP_SPANS
 
     def test_admission_spans_nest(self, engine_line):
         by = {n: [e for e in engine_line if e["name"] == n]
@@ -140,7 +191,10 @@ class TestEngineThreadOnTheProfilersClock:
     def test_top_level_spans_tile_the_thread(self, engine_line):
         """The guard against a later unmarked phase: between the first
         admission and the last finish, what no top-level span covers is
-        under 5 % of the interval."""
+        under 10 % of the interval (the loop's top between two turns, some
+        50 us here; since the host's work lies under the device's the
+        interval is the steps' time alone, and at this toy size that is a
+        millisecond a step)."""
         top = [e for e in engine_line if e["name"] in TOP_LEVEL]
         first = min(e["start"] for e in top
                     if e["name"] == "generation.refill")
@@ -150,7 +204,7 @@ class TestEngineThreadOnTheProfilersClock:
         holes = sum(max(0.0, b["start"] - a["end"])
                     for a, b in zip(inside, inside[1:]))
         assert inside[0]["start"] == first and inside[-1]["end"] == last
-        assert holes < 0.05 * (last - first), (holes, last - first)
+        assert holes < 0.10 * (last - first), (holes, last - first)
 
 
 class TestRingAndLatencyKeepTheirMeaning:
@@ -202,12 +256,23 @@ class TestRingAndLatencyKeepTheirMeaning:
             assert len(on_thread) == 4 and on_thread == in_chains
             assert sorted(round(r.lat[seg] * 1e6, 3)
                           for r in reqs) == on_thread
-        # decode_compute = dispatch + readback of the steps it rode
-        disp = sum(e["dur"] for e in ring if e["name"] in (
-            "generation.decode_dispatch", "generation.decode_readback"))
+        # decode_compute = the dispatch + the readback of the SAME turn
+        # (the turn less prepare and harvest): a readback's turn holds a
+        # dispatch when that is the step span just before it
+        engine = sorted((e for e in ring if e["name"] in STEP_SPANS),
+                        key=lambda e: e["ts"])
+        turns = [b["dur"] + (a["dur"] if a["name"] == STEP_SPANS[1] else 0.0)
+                 for a, b in zip(engine, engine[1:])
+                 if b["name"] == STEP_SPANS[2]]
         steps = {e["args"]["step"]: e["dur"] for e in ring
                  if e["name"] == "generation.decode_step"}
-        assert abs(sum(steps.values()) - disp) < 1.0      # microseconds
+        assert len(turns) == len(steps)
+        for n, turn in zip(sorted(steps), turns):
+            assert abs(steps[n] - turn) < 1.0             # microseconds
+        for r in reqs:
+            rode = [e["dur"] for e in rec_chain(ring, r.trace_id)
+                    if e["name"] == "generation.decode_step"]
+            assert abs(sum(rode) - 1e6 * r.lat["decode_compute"]) < 1.0
 
 
 def rec_chain(ring, trace_id):
@@ -231,26 +296,35 @@ class _Oracle:
         return np.zeros(0, np.int32)
 
 
-def _run_scripted(eng, prompts, max_news):
+COUNT_FAMILIES = DECODE_COUNT_FAMILIES + DECODE_LOOKAHEAD_FAMILIES
+
+
+def _run_scripted(eng, prompts, max_news, lookahead=False, **submit_kw):
     """Both streams are queued BEFORE the loop starts, so one refill
-    admits both and the schedule is fixed by the lengths alone."""
+    admits both and the schedule is fixed by the lengths alone.  Returns
+    the rows and the four step counts (``lookahead``: and the two of the
+    lookahead), which the registry's families must have moved by."""
     reg = registry()
     reg.collect()
-    before = [reg.counter(f).value() for f in DECODE_COUNT_FAMILIES]
-    reqs = [eng.submit(p, n) for p, n in zip(prompts, max_news)]
+    before = [reg.counter(f).value() for f in COUNT_FAMILIES]
+    reqs = [eng.submit(p, n, **submit_kw)
+            for p, n in zip(prompts, max_news)]
     eng.start()
     try:
         rows = [np.asarray(r.result(120.0)) for r in reqs]
+        assert eng.drain(timeout=30.0)
         st = eng.stats()
     finally:
         eng.stop()
     reg.collect()
     delta = [reg.counter(f).value() - b
-             for f, b in zip(DECODE_COUNT_FAMILIES, before)]
+             for f, b in zip(COUNT_FAMILIES, before)]
     counted = (st["decode_steps"], st["decode_slot_steps"],
-               st["decode_rows_attended"], st["decode_pages_attended"])
+               st["decode_rows_attended"], st["decode_pages_attended"],
+               st["decode_steps_overlapped"],
+               st["decode_slot_steps_discarded"])
     assert tuple(delta) == counted
-    return rows, counted
+    return rows, counted if lookahead else counted[:4]
 
 
 class TestStepCounts:
@@ -280,10 +354,43 @@ class TestStepCounts:
         assert counted == (2, 2 + 1, (9 + 7) + 13, (2 + 1) + 2)
         assert eng.stats()["speculative"]["accepted"] == 9
 
+    @pytest.mark.parametrize("loop", ["overlapped", "drained"])
+    def test_lookahead_counts_against_a_hand_count(self, model, loop,
+                                                   monkeypatch):
+        """Steps built on one in flight + steps built after a drain =
+        steps; a stop token costs the one row in flight behind it."""
+        if loop == "drained":
+            monkeypatch.setattr(GenerationEngine, "_must_drain",
+                                lambda self: True)
+        flew = loop == "overlapped"
+        prompts = [_prompt(n, seed=20 + n) for n in self.PROMPTS]
+        # sampled: this model's greedy streams repeat one token
+        kw = dict(temperature=1.0, seed=2)
+        rows, counted = _run_scripted(_engine(model), prompts, self.MAX_NEW,
+                                      lookahead=True, **kw)
+        # one refill, 8 steps (A's; B rides the first 4): the first was
+        # built with nothing in flight, and B's end by count at step 4 is
+        # known before step 5 is built — nothing discarded
+        assert counted == (8, 8 + 4, 76 + 22, 13 + 4, 7 * flew, 0)
+        # A stops at its 5th token (4 steps harvested), B runs to its
+        # count (4 steps): with the lookahead a 5th step was in flight —
+        # A's row in it (seq_len 9: 10 rows, 2 pages) goes to nobody
+        stop = int(rows[0][len(prompts[0]) + 4])
+        assert stop not in rows[0][len(prompts[0]):-5]
+        assert stop not in rows[1][len(prompts[1]):]
+        cut, counted = _run_scripted(
+            _engine(model), prompts, self.MAX_NEW, lookahead=True,
+            stop_tokens=(stop,), **kw)
+        assert np.array_equal(cut[0], rows[0][:len(prompts[0]) + 5])
+        assert np.array_equal(cut[1], rows[1])
+        assert counted == (
+            4 + flew, 4 + 4 + flew, (6 + 7 + 8 + 9) + 22 + 10 * flew,
+            (1 + 1 + 1 + 2) + 4 + 2 * flew, 4 * flew, 1 * flew)
+
     def test_counts_are_declared_and_engines_sum(self, model):
         reg = registry()
         text = reg.to_prometheus_text()
-        for fam in DECODE_COUNT_FAMILIES:
+        for fam in COUNT_FAMILIES:
             assert f"# TYPE {fam} counter" in text
         reg.collect()
         before = [reg.counter(f).value() for f in DECODE_COUNT_FAMILIES]

@@ -85,6 +85,12 @@ def _prompt(n, seed=0):
         0, VOCAB, n).astype(np.int32)
 
 
+def _drain_every_step(monkeypatch):
+    """The decode loop as it was before the lookahead: every step is read
+    back before the next is built (a test-only hook: no config field)."""
+    monkeypatch.setattr(GenerationEngine, "_must_drain", lambda self: True)
+
+
 # -- the paged KV allocator --------------------------------------------------
 
 class TestPagedKVCache:
@@ -314,6 +320,217 @@ class TestAdmission:
             eng.stop()
 
 
+# -- the one-step lookahead ---------------------------------------------------
+
+SAMPLING = {
+    "greedy": dict(),
+    "sampled": dict(temperature=1.0, seed=3),
+    "top_k": dict(temperature=1.3, top_k=5, seed=7),
+}
+
+
+def _serve_queued(eng, specs, **submit_kw):
+    """Queue every stream BEFORE the loop starts (one refill admits what
+    the slots hold, so the schedule is fixed by the lengths alone), serve
+    them, and return (rows, stats); the pool must be leak-free."""
+    reqs = [eng.submit(p, n, **kw, **submit_kw) for p, n, kw in specs]
+    eng.start()
+    try:
+        rows = [np.asarray(r.result(120.0)) for r in reqs]
+        assert eng.drain(timeout=30.0)
+        st = eng.stats()
+        assert eng.kv.used_pages == 0
+        assert eng.kv.leak_check() is None
+    finally:
+        eng.stop()
+    return rows, st
+
+
+class TestLookahead:
+    """ISSUE 36: the loop dispatches step n + 1 before it reads step n
+    back.  Same programs, same keys, so the same tokens in the same order
+    to the same streams as the loop that drains every step, and as the
+    dense reference."""
+
+    @pytest.mark.parametrize("sampling", list(SAMPLING))
+    def test_tokens_identical_to_the_drained_loop_and_to_dense(
+            self, model, sampling, monkeypatch):
+        kw = SAMPLING[sampling]
+        specs = [(_prompt(3, seed=61), 9, kw), (_prompt(6, seed=62), 6, kw),
+                 (_prompt(6, seed=63), 9, dict(kw, seed=11) if kw else kw)]
+        rows, st = _serve_queued(_engine(model), specs)
+        # one refill, then A and C ride 8 steps and B 5: only the first
+        # was built with nothing in flight
+        assert st["decode_steps"] == 8
+        assert st["decode_steps_overlapped"] == 7
+        assert st["decode_slot_steps"] == 8 + 5 + 8
+        assert st["decode_slot_steps_discarded"] == 0
+        _drain_every_step(monkeypatch)
+        drained, st = _serve_queued(_engine(model), specs)
+        assert st["decode_steps"] == 8
+        assert st["decode_steps_overlapped"] == 0
+        for row, same, (p, n, kw_) in zip(rows, drained, specs):
+            np.testing.assert_array_equal(row, same)
+            np.testing.assert_array_equal(row, _dense(model, p, n, **kw_))
+
+    @pytest.mark.parametrize("loop", ["overlapped", "drained"])
+    def test_stop_token_mid_stream_costs_one_discarded_row(
+            self, model, loop, monkeypatch):
+        """The step after the stop token's was in flight when the token
+        came to light: its row reaches no stream and is counted."""
+        if loop == "drained":
+            _drain_every_step(monkeypatch)
+        p = _prompt(5, seed=6)
+        ref = _dense(model, p, 12)
+        gen = ref[len(p):]
+        stop = int(gen[3])
+        first = int(np.argmax(gen == stop))
+        assert 0 < first < 10              # mid-stream: steps follow it
+        seen = []
+        (out,), st = _serve_queued(
+            _engine(model), [(p, 12, dict())], stop_tokens=(stop,),
+            on_token=lambda tok, idx: seen.append((idx, tok)))
+        np.testing.assert_array_equal(out, ref[: len(p) + first + 1])
+        assert seen == list(enumerate(gen[: first + 1].tolist()))
+        flew = loop == "overlapped"
+        assert st["decode_slot_steps_discarded"] == flew
+        assert st["decode_steps"] == first + flew
+
+    @pytest.mark.parametrize("loop", ["overlapped", "drained"])
+    def test_cancel_mid_stream_keeps_the_tokens_before_it(
+            self, model, loop, monkeypatch):
+        if loop == "drained":
+            _drain_every_step(monkeypatch)
+        p = _prompt(4, seed=64)
+        ref = _dense(model, p, 20)[len(p):]
+        eng = _engine(model)
+        seen = []
+
+        def on_token(tok, idx):
+            seen.append(tok)
+            if idx == 5:
+                req.cancel()               # on the engine thread itself
+
+        req = eng.submit(p, 20, on_token=on_token)
+        eng.start()
+        try:
+            with pytest.raises(ServingRejected):
+                req.result(60.0)
+            assert eng.drain(timeout=30.0)
+            # the next harvest sees the flag and hands out nothing more
+            assert seen == req.tokens_so_far() == ref[:6].tolist()
+            assert eng.kv.used_pages == 0
+            assert eng.kv.leak_check() is None
+            st = eng.stats()
+            assert st["decode_slot_steps_discarded"] == (loop == "overlapped")
+            # and the slot serves the next stream
+            q = _prompt(5, seed=65)
+            np.testing.assert_array_equal(
+                np.asarray(eng.generate(q, 5, timeout=120.0)),
+                _dense(model, q, 5))
+        finally:
+            eng.stop()
+
+    @pytest.mark.parametrize("max_new", [1, 2])
+    def test_streams_of_one_and_two_tokens(self, model, max_new):
+        """No step, and one step that nothing is built on top of."""
+        specs = [(_prompt(4, seed=66), max_new, dict()),
+                 (_prompt(7, seed=67), max_new, SAMPLING["sampled"])]
+        rows, st = _serve_queued(_engine(model), specs)
+        for row, (p, n, kw) in zip(rows, specs):
+            np.testing.assert_array_equal(row, _dense(model, p, n, **kw))
+        assert st["decode_steps"] == max_new - 1
+        assert st["decode_steps_overlapped"] == 0
+
+    @pytest.mark.parametrize("ends_by", ["stop_token", "count"])
+    def test_admission_into_a_slot_freed_one_step_earlier(self, model,
+                                                          ends_by):
+        """One slot, a second stream waiting for it.  The first ends with
+        a step in flight (a stop token) or just before one would be built
+        (its count): what that step computed for the old occupant must
+        not reach the stream admitted into the slot."""
+        a, b = _prompt(5, seed=6), _prompt(4, seed=68)
+        ref_a, ref_b = _dense(model, a, 12), _dense(model, b, 7)
+        gen_a = ref_a[len(a):]
+        if ends_by == "stop_token":
+            first = int(np.argmax(gen_a == gen_a[3]))
+            spec_a = (a, 12, dict(stop_tokens=(int(gen_a[3]),)))
+        else:
+            first = 4
+            spec_a = (a, first + 1, dict())
+        got = {"a": [], "b": []}
+        eng = _engine(model, slots=1)
+        ra = eng.submit(*spec_a[:2], **spec_a[2],
+                        on_token=lambda t, i: got["a"].append(t))
+        rb = eng.submit(b, 7, on_token=lambda t, i: got["b"].append(t))
+        eng.start()
+        try:
+            np.testing.assert_array_equal(
+                np.asarray(ra.result(120.0)), ref_a[: len(a) + first + 1])
+            np.testing.assert_array_equal(np.asarray(rb.result(120.0)),
+                                          ref_b)
+            assert eng.drain(timeout=30.0)
+            st = eng.stats()
+            assert eng.kv.leak_check() is None
+        finally:
+            eng.stop()
+        assert got["a"] == gen_a[: first + 1].tolist()
+        assert got["b"] == ref_b[len(b):].tolist()
+        assert st["decode_slot_steps_discarded"] == (ends_by == "stop_token")
+        # every step but each stream's first was built on one in flight
+        # (A's count ends it before a step is built on its last)
+        assert st["decode_steps"] - st["decode_steps_overlapped"] == 2
+
+    def test_sixteen_slots_fill_and_empty(self, model):
+        """Twenty streams through sixteen slots: the slots fill, streams
+        leave at different steps, the waiting four are admitted as slots
+        free, and the batch empties — every stream its own reference."""
+        specs = []
+        for i in range(20):
+            kw = dict() if i % 3 else dict(temperature=1.0, seed=i)
+            specs.append((_prompt((3, 6)[i % 2], seed=70 + i),
+                          (3, 6, 9)[i % 3], kw))
+        refs = [_dense(model, p, n, **kw) for p, n, kw in specs]
+        rows, st = _serve_queued(_engine(model, slots=16, max_queue=32),
+                                 specs)
+        for row, ref in zip(rows, refs):
+            np.testing.assert_array_equal(row, ref)
+        assert st["decode_slot_steps"] == sum(n - 1 for _, n, _ in specs)
+        assert 0 < st["decode_steps_overlapped"] < st["decode_steps"]
+        assert st["decode_slot_steps_discarded"] == 0
+
+    @pytest.mark.parametrize("params", ["uncommitted", "committed"])
+    def test_one_call_signature_for_both_token_sources(self, params):
+        """The plain step takes its tokens as a device array whether they
+        come from the host (after a drain) or from the step before, placed
+        as the pool is (committed where the parameters are: the pool comes
+        back from the same programs as the tokens): one cache entry once
+        the host's source is warm, so no later step traces, lowers or
+        compiles."""
+        model = TransformerEncoder(
+            vocab_size=VOCAB, d_model=D, n_heads=HEADS, n_layers=LAYERS,
+            causal=True, seed=5).init_model()
+        if params == "committed":
+            model.params = jax.device_put(model.params, jax.devices()[0])
+        eng = _engine(model).start()
+        try:
+            # warm: max_new 2 is one step, from the host's tokens (what
+            # the benchmark's warm-up runs: it never overlaps a step)
+            for i in range(2):
+                eng.generate(_prompt(4, seed=79 + i), 2, timeout=120.0)
+            assert eng.stats()["decode_steps_overlapped"] == 0
+            assert eng._step_fns[1]._cache_size() == 1
+            for i in range(3):
+                p = _prompt(4 + i, seed=80 + i)
+                np.testing.assert_array_equal(
+                    np.asarray(eng.generate(p, 6, timeout=120.0)),
+                    _dense(model, p, 6))
+            assert eng.stats()["decode_steps_overlapped"] > 0
+            assert eng._step_fns[1]._cache_size() == 1
+        finally:
+            eng.stop()
+
+
 # -- the degradation ladder --------------------------------------------------
 
 class TestLadder:
@@ -350,6 +567,172 @@ class TestLadder:
                 _dense(model, p, 5))
         finally:
             eng.stop()
+
+    @pytest.mark.faults
+    @pytest.mark.parametrize("loop", ["overlapped", "drained"])
+    def test_fault_at_prepare_delivers_the_step_in_flight_first(
+            self, model, loop, monkeypatch):
+        """The 4th step cannot be built; the 3rd, in flight, is still
+        good: its tokens go out, then the stream fails — once."""
+        if loop == "drained":
+            _drain_every_step(monkeypatch)
+        p = _prompt(4, seed=33)
+        ref = _dense(model, p, 10)[len(p):]
+        eng = _engine(model)
+        failed = []
+        real = eng._step_failed
+        eng._step_failed = lambda *a: (failed.append(a), real(*a))
+        faults.arm("serving.decode:raise:nth=4")
+        req = eng.submit(p, 10)
+        eng.start()
+        try:
+            with pytest.raises(ServingError, match="decode step failed"):
+                req.result(60.0)
+            assert req.tokens_so_far() == ref[:4].tolist()
+            assert len(failed) == 1
+            st = eng.stats()
+            assert st["streams"]["outcomes"] == {"error": 1}
+            assert st["decode_steps"] == 3
+            assert st["kv"]["pool_rebuilds"] == 0
+            assert eng.kv.used_pages == 0
+            faults.disarm()
+            np.testing.assert_array_equal(
+                np.asarray(eng.generate(p, 10, timeout=120.0))[len(p):], ref)
+            assert eng.kv.leak_check() is None
+        finally:
+            eng.stop()
+
+    @pytest.mark.faults
+    def test_failed_readback_takes_the_step_after_it_along(self, model):
+        """Step 3's tokens cannot be read; step 4, dispatched on top of
+        it, is dead with it: ONE failure, one revive, no page leaked."""
+        p = _prompt(4, seed=34)
+        ref = _dense(model, p, 10)[len(p):]
+        eng = _engine(model)
+        calls = {"failed": 0, "revive": 0, "readback": 0}
+        real_failed, real_revive, real_span = (
+            eng._step_failed, eng.kv.revive, eng._span)
+
+        def span(name, **a):
+            if name == "generation.decode_readback":
+                calls["readback"] += 1
+                if calls["readback"] == 3:
+                    raise RuntimeError("device lost")
+            return real_span(name, **a)
+
+        def counted(key, real):
+            def call(*a, **kw):
+                calls[key] += 1
+                return real(*a, **kw)
+            return call
+
+        eng._span = span
+        eng._step_failed = counted("failed", real_failed)
+        eng.kv.revive = counted("revive", real_revive)
+        req = eng.submit(p, 10)
+        eng.start()
+        try:
+            with pytest.raises(ServingError, match="device lost"):
+                req.result(60.0)
+            assert req.tokens_so_far() == ref[:3].tolist()
+            assert (calls["failed"], calls["revive"]) == (1, 1)
+            st = eng.stats()
+            assert st["decode_steps"] == 4          # the 4th was in flight
+            assert st["streams"]["outcomes"] == {"error": 1}
+            assert eng._flying is None
+            assert eng.kv.used_pages == 0
+            assert not any(_deleted(eng.kv.pool()))
+            np.testing.assert_array_equal(
+                np.asarray(eng.generate(p, 10, timeout=120.0))[len(p):], ref)
+            assert eng.kv.leak_check() is None
+        finally:
+            eng.stop()
+
+    @pytest.mark.parametrize("loop", ["overlapped", "drained"])
+    def test_stop_with_a_step_in_flight_returns_its_tokens(
+            self, model, loop, monkeypatch):
+        """Every step that was dispatched hands out its tokens, the one
+        in flight when the stop came included."""
+        if loop == "drained":
+            _drain_every_step(monkeypatch)
+        p = _prompt(4, seed=35)
+        ref = _dense(model, p, 12)[len(p):]
+        eng = _engine(model)
+
+        def on_token(tok, idx):
+            if idx == 5:
+                eng._stop.set()            # what `stop()` does first
+
+        req = eng.submit(p, 12, on_token=on_token)
+        eng.start()
+        try:
+            eng._thread.join(60.0)
+            assert not eng._thread.is_alive()
+            assert eng._flying is None
+            steps = eng.stats()["decode_steps"]
+            assert steps == 5 + (loop == "overlapped")
+            assert req.tokens_so_far() == ref[: steps + 1].tolist()
+        finally:
+            eng.stop()
+        with pytest.raises(ServingRejected):
+            req.result(1.0)
+        assert eng.kv.used_pages == 0
+        assert eng.kv.leak_check() is None
+
+    def test_drain_waits_for_the_step_in_flight(self, model):
+        p = _prompt(5, seed=36)
+        eng = _engine(model).start()
+        try:
+            req = eng.submit(p, 14, stop_tokens=(int(_dense(
+                model, p, 14)[len(p) + 6]),))
+            deadline = time.monotonic() + 60.0
+            while eng.active_streams() == 0 and not req.done:
+                assert time.monotonic() < deadline
+                time.sleep(0.001)
+            assert eng.drain(timeout=60.0)
+            assert req.done and eng._flying is None
+            assert not eng.watchdog._armed
+            assert eng.kv.used_pages == 0
+        finally:
+            eng.stop()
+
+    def test_watchdog_is_fed_the_turns_period_not_two_walls(self, model):
+        """50 overlapped steps: what the watchdog's EWMA is fed per step
+        is the turn's dispatch + readback — time that lies between two
+        harvests — and never a step's own wall from ITS dispatch to ITS
+        readback, which overlaps its neighbours' and would sum to twice
+        the run."""
+        eng = _engine(model, max_pages_per_seq=8)
+        fed, stamps = [], []
+        real = eng.watchdog.disarm
+
+        def disarm(dur=None):
+            if dur is not None:
+                fed.append(dur)
+            real(dur)
+
+        eng.watchdog.disarm = disarm
+        req = eng.submit(_prompt(4, seed=37), 52,
+                         on_token=lambda t, i: stamps.append(
+                             time.perf_counter()))
+        eng.start()
+        try:
+            req.result(120.0)
+            assert eng.drain(timeout=30.0)
+            st = eng.stats()
+        finally:
+            eng.stop()
+        assert st["decode_steps"] == 51
+        assert st["decode_steps_overlapped"] == 50
+        # one feed per turn that dispatched and read back: the first turn
+        # only dispatched, the last only read back
+        assert len(fed) == 50
+        # the k-th feed lies between the harvests of tokens k and k + 1
+        gaps = np.diff(stamps)
+        assert len(gaps) == 51
+        assert all(f <= g for f, g in zip(fed, gaps))
+        assert sum(fed) <= stamps[-1] - stamps[0]
+        assert 0 < eng.watchdog.ewma <= max(fed)
 
     @pytest.mark.slow
     def test_watchdog_abort_releases_pages_and_respawns(self, model):
@@ -783,10 +1166,16 @@ class TestServingCopy:
         assert seen <= own
         assert eng.stats()["serving_params_casts"] == 1 + swaps
 
-    def test_swap_in_flight_serves_the_new_tree_from_the_next_step(self):
+    @pytest.mark.parametrize("loop", ["overlapped", "drained"])
+    def test_swap_in_flight_serves_the_new_tree_from_the_next_step(
+            self, loop, monkeypatch):
         """The tree is swapped from inside the engine thread's own token
-        callback, so the position it lands at is known: every token after
-        it is the new weights' — and the copy was remade once."""
+        callback, so the position it lands at is known: the tree is read
+        where a step is BUILT, so every token after the step in flight
+        (none, when the loop drains every step) is the new weights' — and
+        the copy was remade once."""
+        if loop == "drained":
+            _drain_every_step(monkeypatch)
         fresh = lambda: TransformerEncoder(
             vocab_size=VOCAB, d_model=D, n_heads=HEADS, n_layers=LAYERS,
             causal=True, seed=5).init_model()
@@ -801,10 +1190,12 @@ class TestServingCopy:
         swapped.params = new
         srv = InferenceServer(live)
         eng = GenerationEngine(server=srv, config=GenerationConfig(**CFG))
-        at, pushed = 4, []
+        swap_at, pushed = 4, []
+        # overlapped, the step after the callback's was dispatched already
+        at = swap_at + (loop == "overlapped")
 
         def on_token(tok, idx):
-            if idx == at - 1:           # `at` tokens are out: swap now
+            if idx == swap_at - 1:      # `swap_at` tokens are out: swap now
                 pushed.append(srv.push_weights(new, source="test"))
 
         try:
