@@ -774,6 +774,17 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "ended before the step's tokens were read (a stop token "
                 "or a cancel the step before brought to light): the "
                 "lookahead's cost; 0 where streams end by count")
+    reg.counter("dl4jtpu_decode_drains_total",
+                "Steps the decode loop landed so the host could act, by "
+                "reason (admit: a request waits and a slot is or will "
+                "be free; drafter; stop; idle: every stream ends with "
+                "the step in flight): where the one-step lookahead let "
+                "go.  Bridged by the decode counts' pull collector")
+    reg.counter("dl4jtpu_decode_drain_seconds_total",
+                "Seconds of the generation.drain spans by reason (the "
+                "readback and harvest of the drained step): over "
+                "dl4jtpu_decode_drains_total{reason=\"admit\"} it is "
+                "what an arrival waits for the step in flight")
     reg.counter("dl4jtpu_serving_params_casts_total",
                 "Serving copies of the parameter tree the generation "
                 "engines made (the matrices cast to the activation "
@@ -810,9 +821,10 @@ def _declare_core(reg: MetricsRegistry) -> None:
                   "Time-to-first-token per stream: submit to the "
                   "prefill program emitting the first sampled token")
     reg.gauge("dl4jtpu_decode_batch_occupancy",
-              "Live streams / decode slots after the latest step or "
-              "admission (1.0 = the batch is full; sustained low "
-              "values mean the slot count outruns the traffic)")
+              "Live streams / decode slots of the running generation "
+              "engines, sampled at scrape time (1.0 = the batch is "
+              "full; sustained low values mean the slot count outruns "
+              "the traffic)")
     reg.counter("dl4jtpu_paged_attention_total",
                 "Paged-attention sites lowered into compiled "
                 "programs, by impl (pallas = online-softmax TPU "
@@ -858,10 +870,6 @@ def _declare_core(reg: MetricsRegistry) -> None:
     reg.histogram("dl4jtpu_generation_sampling_seconds",
                   "Per-stream accumulated host-side harvest/sampling "
                   "bookkeeping after each decode step")
-    reg.gauge("dl4jtpu_generation_tokens_per_s",
-              "Recent aggregate decode token rate (trailing-window "
-              "estimate refreshed as steps complete) — the live "
-              "numerator behind the throughput SLO")
     reg.gauge("dl4jtpu_flight_records",
               "Per-stream records currently held in the serving "
               "flight-recorder ring")

@@ -60,14 +60,19 @@ stream is one causal chain across replicas on ``/api/trace/cluster``.
 The engine THREAD's time is tiled by recorder spans of its own
 (`_span`; each a `jax.profiler` annotation whenever a profiler session
 records, ring or no ring): ``generation.wait_for_work`` (no live slot:
-asleep in the queue) | ``generation.refill`` (an admission: every live
-stream waits) > ``generation.admit_to_slot`` per request >
-``generation.prefill`` + ``generation.kv_handoff`` | then, per loop turn
-and with no span around them, ``generation.decode_prepare`` ->
-``generation.decode_dispatch`` (args ``slots``/``rows``) ->
-``generation.decode_readback`` -> ``generation.harvest``.  Each phase
-is timed ONCE, by its span: `req.lat` and the per-stream chain read the
-span's ``dur``.  Every step also counts what it served —
+asleep in the queue) | ``generation.drain`` (arg ``reason``: a step
+landed so the host can act) > ``generation.decode_readback`` +
+``generation.harvest`` | ``generation.take`` (the queue's hand-over while
+streams are live) | ``generation.refill`` (an admission: every live stream
+waits) > ``generation.admit_to_slot`` per request >
+``generation.prefill`` (> ``generation.prefill_dispatch`` per program +
+``generation.prefill_readback``) + ``generation.kv_handoff`` +
+``generation.first_token`` | then, per loop turn and with no span around
+them, ``generation.decode_prepare`` -> ``generation.decode_dispatch``
+(args ``slots``/``rows``) -> ``generation.decode_readback`` ->
+``generation.harvest``.  Each phase is timed ONCE, by its span:
+`req.lat` and the per-stream chain read the span's ``dur``, and so do the
+drain counters.  Every step also counts what it served —
 ``dl4jtpu_decode_{steps,slot_steps,rows_attended,pages_attended}_total``.
 
 The decode loop looks ONE step ahead (`_decode_step`): a turn prepares
@@ -85,7 +90,10 @@ loop reads the step in flight back BEFORE it goes on (`_must_drain`,
 whole: an admission, a drafter (it reads host tokens), a stop, a
 failure.  ``dl4jtpu_decode_steps_overlapped_total`` over
 ``dl4jtpu_decode_steps_total`` is the share of steps dispatched that
-way; ``dl4jtpu_decode_slot_steps_discarded_total`` the rows thrown away.
+way; ``dl4jtpu_decode_slot_steps_discarded_total`` the rows thrown away;
+``dl4jtpu_decode_drains_total{reason}`` and
+``dl4jtpu_decode_drain_seconds_total{reason}`` how often the loop let go
+of the lookahead, why, and what the ``generation.drain`` spans took.
 """
 
 from __future__ import annotations
@@ -191,6 +199,16 @@ DECODE_COUNT_FAMILIES = ("dl4jtpu_decode_steps_total",
 DECODE_LOOKAHEAD_FAMILIES = ("dl4jtpu_decode_steps_overlapped_total",
                              "dl4jtpu_decode_slot_steps_discarded_total")
 
+#: why the loop landed a step so the host could act (`_must_drain`): a
+#: request waits and a slot is or will be free, a drafter reads host
+#: tokens, the engine stops, every stream ends with the step in flight
+DRAIN_REASONS = ("admit", "drafter", "stop", "idle")
+
+#: the drains by reason, process totals: how many (`_drains[r][0]`) and
+#: the seconds of their ``generation.drain`` spans (`_drains[r][1]`)
+DECODE_DRAIN_FAMILIES = ("dl4jtpu_decode_drains_total",
+                         "dl4jtpu_decode_drain_seconds_total")
+
 #: what the learned sparse selection did, process totals, counted on the
 #: host from the lengths (prefill and decode): context rows the indexers
 #: scored and rows they selected, per query row per indexer layer
@@ -241,11 +259,27 @@ _ENGINES_LOCK = threading.Lock()
 def _collect_decode_counts() -> None:
     """Pull collector: the engines count their steps in plain ints on
     their own thread (no registry lock per step); a scrape moves what
-    was added since the last one into the registry's counters."""
+    was added since the last one into the registry's counters, and
+    samples ``dl4jtpu_decode_batch_occupancy``: the live streams over the
+    slots of the engines whose decode loop runs (read without their
+    locks — a sample, like any gauge)."""
     with _ENGINES_LOCK:
         engines = list(_ENGINES)
+    live = slots = 0
     for eng in engines:
         eng._flush_decode_counts()
+        t = eng._thread
+        if t is not None and t.is_alive():
+            live += sum(r is not None for r in eng._slot_req)
+            slots += eng.config.slots
+    if slots:
+        try:
+            from deeplearning4j_tpu.observe.metrics import registry
+
+            registry().gauge("dl4jtpu_decode_batch_occupancy").set(
+                live / slots)
+        except Exception as e:
+            log.debug("occupancy gauge failed: %s", e)
 
 
 @dataclass
@@ -541,6 +575,10 @@ class GenerationEngine:
         self._flying: Optional[_Flying] = None
         self._overlapped = 0
         self._discarded = 0
+        # the drains by reason: [count, seconds of their spans] (every
+        # reason present from the start, so a flush never sees a new key)
+        self._drains = {r: [0, 0.0] for r in DRAIN_REASONS}
+        self._drains_flushed = {r: (0, 0.0) for r in DRAIN_REASONS}
         # `model.params` as the copies below were made from it, the tree
         # the programs are dispatched with, and the long prefill buckets'
         # (`_serving_params`); and how many were made
@@ -880,7 +918,11 @@ class GenerationEngine:
         k/v shaped (n_layers, t_bucket, H, Dh) f32 for `write_prefill`.
         Row pools: the prompt's chunk programs in a row, each writing the
         stream's pages in place (the pool is donated through every one)
-        and only the last chunk's token read back; k and v are None."""
+        and only the last chunk's token read back; k and v are None.
+        Inside the caller's ``generation.prefill``: one
+        ``generation.prefill_dispatch`` per program (the host's cost to
+        launch it; the device idles under it when nothing is queued) and
+        the ``generation.prefill_readback`` of the first token."""
         t_p = req.prompt.shape[0]
         t_b = bucket_length(t_p, self._quantum)
         params = self._serving_params(t_b)
@@ -889,8 +931,11 @@ class GenerationEngine:
         if self.kv.kv_layout:
             pad = np.zeros((1, t_b), np.int32)
             pad[0, :t_p] = req.prompt
-            k, v, first = self._prefill_fn(t_b)(params, pad, *sampling)
-            return k, v, int(first), req.t_submit
+            with self._span("generation.prefill_dispatch", bucket=t_b):
+                k, v, first = self._prefill_fn(t_b)(params, pad, *sampling)
+            with self._span("generation.prefill_readback"):
+                first = int(first)
+            return k, v, first, req.t_submit
         c_rows = self._quantum
         pad = np.zeros(t_b, np.int32)
         pad[:t_p] = req.prompt
@@ -898,12 +943,15 @@ class GenerationEngine:
         tbl = self.kv.table(req.rid)
         row[: len(tbl)] = tbl
         for ci in range(t_b // c_rows):
-            out = self._prefill_fn(ci)(
-                params, *self._program_state(), row,
-                pad[ci * c_rows:(ci + 1) * c_rows], *sampling)
-            self._rebind_state(out[:-1])
+            with self._span("generation.prefill_dispatch", chunk=ci):
+                out = self._prefill_fn(ci)(
+                    params, *self._program_state(), row,
+                    pad[ci * c_rows:(ci + 1) * c_rows], *sampling)
+                self._rebind_state(out[:-1])
         self._count_selection(np.arange(1, t_p + 1))
-        return None, None, int(out[-1]), req.t_submit
+        with self._span("generation.prefill_readback"):
+            first = int(out[-1])
+        return None, None, first, req.t_submit
 
     def _count_selection(self, contexts) -> None:
         """Query rows at the contexts ``contexts`` (each row's own
@@ -1248,7 +1296,7 @@ class GenerationEngine:
                 self._decode_step(my_gen)
             if self._flying is not None:
                 # stopped: the step in flight still hands out its tokens
-                self._land(my_gen, self._flying)
+                self._drain(my_gen, self._flying, "stop")
         except Exception as exc:                      # never die silently
             log.exception("generation loop died")
             with self._mu:
@@ -1271,14 +1319,19 @@ class GenerationEngine:
         if self._flying is not None:
             # an admission writes the slots the step in flight was built
             # from, and a sleep in the queue would leave it unread
-            self._land(my_gen, self._flying)
+            self._drain(my_gen, self._flying,
+                        "admit" if self.queue.depth else "idle")
             free = self._free_slots()
         if block:
             # no slot is live: the engine thread sleeps in the queue
             with self._span("generation.wait_for_work"):
                 batch = self._take(len(free))
         else:
-            batch = self._take(len(free))
+            # the hand-over while streams are live (args known at its end)
+            with self._span("generation.take") as sp:
+                batch = self._take(len(free))
+                sp.args["taken"] = len(batch)
+                sp.set_metadata(taken=len(batch))
         if batch:
             # every live stream waits for this span: no decode step runs
             # until the last admission returns
@@ -1386,6 +1439,15 @@ class GenerationEngine:
                     self._fail_active_locked(ServingError(
                         f"prefill failed and took the pool: {exc}"))
             return
+        with self._span("generation.first_token"):
+            self._seat(my_gen, slot, req, first, tbl)
+
+    def _seat(self, my_gen: int, slot: int, req: GenerationRequest,
+              first: int, tbl) -> None:
+        """The first token out (callbacks, TTFT, the token count), then
+        the stream into its slot — or settled here, when that token ends
+        it."""
+        t_p = req.prompt.shape[0]
         req._record(first)
         self._observe_ttft(req)
         self._count_tokens(1)
@@ -1412,7 +1474,6 @@ class GenerationEngine:
             self._seeds[slot] = np.uint32(req.seed)
             self._slot_req[slot] = req
             req.t_slot = time.perf_counter()
-        self._gauge_occupancy()
 
     def _decode_step(self, my_gen: int) -> None:
         """One turn of the decode loop.  With step n in flight it builds
@@ -1427,20 +1488,23 @@ class GenerationEngine:
         and the KV rows the step attends) -> ``generation.decode_readback``
         (the blocking ``np.asarray`` of the OLDER step's tokens) ->
         ``generation.harvest`` (stop conditions, callbacks, page release,
-        slot free, gauges).  With nothing in flight the turn ends after
+        slot free).  With nothing in flight the turn ends after
         the dispatch and the next one lands on top of it — unless
         `_must_drain` says the host needs this step's tokens first, when
         all four spans are of one step; with a step in flight and
-        `_must_drain` the turn is its readback and harvest alone.
+        `_must_drain` the turn is its readback and harvest alone.  Either
+        way such a landing is a ``generation.drain`` (`_drain`).
 
         A step that cannot be built or dispatched leaves the one in
         flight good: its tokens go out, then the streams fail once.  A
         readback that fails takes the step dispatched on top of it along
         (`_land`)."""
         prev = self._flying
-        if prev is not None and self._must_drain():
-            self._land(my_gen, prev)
-            return
+        if prev is not None:
+            reason = self._must_drain()
+            if reason:
+                self._drain(my_gen, prev, reason)
+                return
         try:
             with self._span("generation.decode_prepare"):
                 plan = self._prepare_step(my_gen, prev)
@@ -1466,22 +1530,40 @@ class GenerationEngine:
             return
         if prev is not None:
             self._land(my_gen, prev, disp)
-        elif self._must_drain():
-            self._land(my_gen, step, disp)
+        else:
+            reason = self._must_drain()
+            if reason:
+                self._drain(my_gen, step, reason, disp)
 
-    def _must_drain(self) -> bool:
+    def _must_drain(self) -> Optional[str]:
         """With a step in flight: must the loop read it back before it
-        builds another?  Yes where the next action needs the host's view
-        whole — a drafter proposes from host tokens, a stop wants every
-        token out, a slot that is free (or ends by count with the step in
-        flight) while a request waits is an admission, and when every
-        stream ends with the step in flight there is no step to build."""
-        if self.drafter is not None or self._stop.is_set():
-            return True
+        builds another?  The reason (one of `DRAIN_REASONS`) where the
+        next action needs the host's view whole — a drafter proposes from
+        host tokens, a stop wants every token out, a slot that is free
+        (or ends by count with the step in flight) while a request waits
+        is an admission, and when every stream ends with the step in
+        flight there is no step to build; None where it need not."""
+        if self.drafter is not None:
+            return "drafter"
+        if self._stop.is_set():
+            return "stop"
         with self._mu:
             stays = [r is not None and g + 1 < r.max_new
                      for r, g in zip(self._slot_req, self._gen_counts)]
-        return not any(stays) or (self.queue.depth > 0 and not all(stays))
+        if self.queue.depth > 0 and not all(stays):
+            return "admit"
+        return None if any(stays) else "idle"
+
+    def _drain(self, my_gen: int, step: _Flying, reason: str,
+               disp=None) -> None:
+        """Land ``step`` so the host can act — not as the second half of
+        a turn — under a ``generation.drain {reason}`` span, and count it
+        by reason with the span's duration."""
+        with self._span("generation.drain", reason=reason) as sp:
+            self._land(my_gen, step, disp)
+        counts = self._drains[reason]
+        counts[0] += 1
+        counts[1] += sp.dur
 
     def _land(self, my_gen: int, step: _Flying, disp=None) -> bool:
         """Read a dispatched step's tokens back and harvest them: the
@@ -1695,7 +1777,6 @@ class GenerationEngine:
             else:
                 self._finish(req, "cancelled",
                              ServingRejected("shutdown", "cancelled"))
-        self._gauge_occupancy()
 
     # -- speculative decode ------------------------------------------------
     def _req_spec_k(self, req: GenerationRequest) -> int:
@@ -1906,7 +1987,6 @@ class GenerationEngine:
             self._revive_state(wait=True)
             self._fail_active_locked(
                 ServingError(f"decode step failed: {exc}"))
-        self._gauge_occupancy()
         if tripped:
             try:
                 self.flight.dump("breaker_open",
@@ -1948,7 +2028,6 @@ class GenerationEngine:
                 ServingError(f"decode step wedged: {event.get('stage')}"),
                 outcome="wedged",
             )
-        self._gauge_occupancy()
         try:
             self.flight.dump("watchdog_abort", context=dict(event))
         except Exception as e:
@@ -2120,6 +2199,8 @@ class GenerationEngine:
             "decode_pages_attended": self._pages_attended,
             "decode_steps_overlapped": self._overlapped,
             "decode_slot_steps_discarded": self._discarded,
+            "decode_drains": {r: {"count": n, "seconds": round(secs, 6)}
+                              for r, (n, secs) in self._drains.items()},
             "serving_params_casts": self._params_casts,
             "dsa": {"rows_scored": self._dsa_scored,
                     "rows_selected": self._dsa_selected},
@@ -2224,10 +2305,7 @@ class GenerationEngine:
         try:
             from deeplearning4j_tpu.observe.metrics import registry
 
-            reg = registry()
-            reg.counter("dl4jtpu_decode_tokens_total").inc(n)
-            reg.gauge("dl4jtpu_generation_tokens_per_s").set(
-                round(self.tokens_per_s(), 4))
+            registry().counter("dl4jtpu_decode_tokens_total").inc(n)
         except Exception as e:
             log.debug("decode token metric failed: %s", e)
 
@@ -2247,6 +2325,12 @@ class GenerationEngine:
                        self._discarded)
                 delta = [a - b for a, b in zip(now, self._counts_flushed)]
                 self._counts_flushed = now
+                drains = {r: tuple(c) for r, c in self._drains.items()}
+                drain_delta = {
+                    r: (n - self._drains_flushed[r][0],
+                        secs - self._drains_flushed[r][1])
+                    for r, (n, secs) in drains.items()}
+                self._drains_flushed = drains
                 if moe is not None:
                     was = self._moe_flushed
                     moe_delta = moe if was is None else moe - was
@@ -2254,6 +2338,12 @@ class GenerationEngine:
             for family, d in zip(_FLUSHED_FAMILIES, delta):
                 if d > 0:
                     reg.counter(family).inc(d)
+            n_fam, s_fam = (reg.counter(f) for f in DECODE_DRAIN_FAMILIES)
+            for reason, (n, secs) in drain_delta.items():
+                if n > 0:
+                    n_fam.inc(n, reason=reason)
+                if secs > 0:
+                    s_fam.inc(secs, reason=reason)
             if moe is not None:
                 blocks = [b for b in self._stack.blocks
                           if b.name in self._moe_index]
@@ -2319,14 +2409,3 @@ class GenerationEngine:
                     req.ttft_s)
         except Exception as e:
             log.debug("ttft metric failed: %s", e)
-
-    def _gauge_occupancy(self) -> None:
-        try:
-            from deeplearning4j_tpu.observe.metrics import registry
-
-            with self._mu:
-                active = sum(r is not None for r in self._slot_req)
-            registry().gauge("dl4jtpu_decode_batch_occupancy").set(
-                active / max(1, self.config.slots))
-        except Exception as e:
-            log.debug("occupancy gauge failed: %s", e)

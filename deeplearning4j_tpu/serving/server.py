@@ -628,13 +628,16 @@ class InferenceServer:
                 self._slice_sequence(rows[j][i], r)
                 for j in range(len(rows))
             )
-            r.complete(result if len(result) > 1 else result[0])
+            # counted, observed and traced BEFORE the client wakes (as
+            # GenerationEngine._finish does): a caller that reads the
+            # metrics after `infer` returns finds its request in them
             lat = now - r.t_admit
             self._observe_latency(lat)
             self._observe_breakdown(r)
             self._count_outcome("ok")
             self._trace_finish(r, "ok")
             self._note_slow(r, "ok", lat)
+            r.complete(result if len(result) > 1 else result[0])
         self._gauge_batch(len(reqs), bucket)
 
     @staticmethod

@@ -385,7 +385,6 @@ class TestTier1Gate:
             "dl4jtpu_generation_decode_queue_seconds",
             "dl4jtpu_generation_decode_compute_seconds",
             "dl4jtpu_generation_sampling_seconds",
-            "dl4jtpu_generation_tokens_per_s",
             "dl4jtpu_flight_records",
             "dl4jtpu_flight_dumps_total",
         } <= fams

@@ -9,8 +9,17 @@ step also counts the slots, the KV rows and the pool pages it served.
 ISSUE 36 — a turn of the loop is ``decode_prepare`` and
 ``decode_dispatch`` of step n + 1, then ``decode_readback`` and
 ``harvest`` of step n: the dispatch of a step ends before the readback
-of the step before it starts, and two more counters say how often."""
+of the step before it starts, and two more counters say how often.
 
+ISSUE 37 — the admission path is tiled too: a step landed so the host
+can act is a ``generation.drain {reason}`` around its readback and
+harvest, the queue's hand-over while streams are live is
+``generation.take``, a prefill is its programs' ``prefill_dispatch`` and
+the ``prefill_readback`` of its first token, and ``first_token`` seats
+the stream; the drains are counted by reason, and the engine thread sets
+no gauge per step."""
+
+import threading
 import time
 
 import numpy as np
@@ -19,6 +28,7 @@ import pytest
 from deeplearning4j_tpu.observe import tracer
 from deeplearning4j_tpu.observe.metrics import registry
 from deeplearning4j_tpu.serving import generation as gen_mod
+from deeplearning4j_tpu.serving.admission import ServingRejected
 from deeplearning4j_tpu.serving.generation import (
     DECODE_COUNT_FAMILIES,
     DECODE_LOOKAHEAD_FAMILIES,
@@ -37,9 +47,19 @@ CFG = dict(slots=4, page_size=8, num_pages=64, max_pages_per_seq=4,
 STEP_SPANS = ["generation.decode_prepare", "generation.decode_dispatch",
               "generation.decode_readback", "generation.harvest"]
 ADMIT_SPANS = ["generation.refill", "generation.admit_to_slot",
-               "generation.prefill", "generation.kv_handoff"]
+               "generation.prefill", "generation.prefill_dispatch",
+               "generation.prefill_readback", "generation.kv_handoff",
+               "generation.first_token"]
+# around a readback and harvest when a step is landed so the host can act
+DRAIN = "generation.drain"
 TOP_LEVEL = set(STEP_SPANS) | {"generation.wait_for_work",
-                               "generation.refill"}
+                               "generation.refill", "generation.take",
+                               DRAIN}
+# what tiles an admission, from its drain (or take) to the next dispatch
+ADMISSION_LEAVES = {"generation.decode_readback", "generation.harvest",
+                    "generation.take", "generation.prefill_dispatch",
+                    "generation.prefill_readback", "generation.kv_handoff",
+                    "generation.first_token", "generation.decode_prepare"}
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +84,17 @@ def _inside(inner, outer):
     return outer["start"] <= inner["start"] and inner["end"] <= outer["end"]
 
 
+def _holes(events, lo, hi):
+    """What no event of ``events`` covers between ``lo`` and ``hi``."""
+    covered, reach = 0, lo
+    for e in sorted(events, key=lambda e: e["start"]):
+        s, t = max(e["start"], reach), min(e["end"], hi)
+        if t > s:
+            covered += t - s
+            reach = t
+    return (hi - lo) - covered
+
+
 @pytest.fixture(scope="module")
 def session(model, tmp_path_factory):
     """The engine thread's line of one profiler session (``events``): a
@@ -86,7 +117,7 @@ def session(model, tmp_path_factory):
             time.sleep(0.05)        # the idle loop goes back to sleep
             eng.stop()
         stats = {k: v - warm[k] for k, v in eng.stats().items()
-                 if k.startswith("decode_")}
+                 if k.startswith("decode_") and isinstance(v, int)}
     finally:
         eng.stop()
     # ONE line holds every generation span: the engine thread's (named
@@ -113,8 +144,11 @@ def _named(events, phase):
 class TestEngineThreadOnTheProfilersClock:
     def test_every_span_of_the_loop_is_on_the_engine_line(self, engine_line):
         names = {e["name"] for e in engine_line}
-        assert names == set(STEP_SPANS) | set(ADMIT_SPANS) | {
-            "generation.wait_for_work"}
+        # a take and an admission's drain appear where a submit found
+        # streams live, which the timing of three submits decides
+        assert set(STEP_SPANS) | set(ADMIT_SPANS) | {
+            "generation.wait_for_work", DRAIN} <= names
+        assert names <= TOP_LEVEL | set(ADMIT_SPANS)
 
     def test_step_spans_come_in_turns_and_disjoint(self, engine_line):
         """A turn is prepare + dispatch, readback + harvest, or all four
@@ -133,10 +167,21 @@ class TestEngineThreadOnTheProfilersClock:
                 assert b["name"] in follows
         assert steps[0]["name"] == STEP_SPANS[0]
         assert steps[-1]["name"] == STEP_SPANS[3]
-        # no span lies around a step: its four are top-level
+        # no span lies around a step's four but a drain, and a drain lies
+        # around exactly one readback and its harvest
         for e in steps:
-            assert not any(o is not e and _inside(e, o)
-                           for o in engine_line)
+            around = [o for o in engine_line
+                      if o is not e and _inside(e, o)]
+            assert all(o["name"] == DRAIN for o in around)
+            assert len(around) <= 1
+            assert not around or e["name"] in STEP_SPANS[2:]
+        for d in _named(engine_line, "drain"):
+            held = [e["name"] for e in steps if _inside(e, d)]
+            assert held == STEP_SPANS[2:]
+            assert d["stats"]["reason"] in gen_mod.DRAIN_REASONS
+        # the last stream ends with a step in flight: that step is landed
+        # with nothing left to build
+        assert _named(engine_line, "drain")[-1]["stats"]["reason"] == "idle"
 
     def test_dispatch_of_the_next_step_ends_before_the_readback(
             self, session):
@@ -163,16 +208,24 @@ class TestEngineThreadOnTheProfilersClock:
     def test_admission_spans_nest(self, engine_line):
         by = {n: [e for e in engine_line if e["name"] == n]
               for n in ADMIT_SPANS}
-        assert len(by["generation.admit_to_slot"]) == 3
-        assert len(by["generation.prefill"]) == 3
-        assert len(by["generation.kv_handoff"]) == 3
+        for n in ("admit_to_slot", "prefill", "prefill_dispatch",
+                  "prefill_readback", "kv_handoff", "first_token"):
+            assert len(by["generation." + n]) == 3, n
         for adm in by["generation.admit_to_slot"]:
             assert sum(_inside(adm, r) for r in by["generation.refill"]) == 1
-            pre = [p for p in by["generation.prefill"] if _inside(p, adm)]
-            hand = [h for h in by["generation.kv_handoff"]
-                    if _inside(h, adm)]
-            assert len(pre) == 1 and len(hand) == 1
-            assert pre[0]["end"] <= hand[0]["start"]
+            kids = [[k for k in by["generation." + n] if _inside(k, adm)]
+                    for n in ("prefill", "kv_handoff", "first_token")]
+            assert [len(k) for k in kids] == [1, 1, 1]
+            pre, hand, first = (k[0] for k in kids)
+            assert pre["end"] <= hand["start"]
+            assert hand["end"] <= first["start"]
+            # the prefill: its program's dispatch, then its first token
+            disp, back = ([k for k in by["generation." + n]
+                           if _inside(k, pre)]
+                          for n in ("prefill_dispatch", "prefill_readback"))
+            assert len(disp) == len(back) == 1
+            assert disp[0]["end"] <= back[0]["start"]
+            assert disp[0]["stats"]["bucket"] == pre["stats"]["bucket"]
             assert "slot" in adm["stats"]
         assert all("bucket" in p["stats"] for p in by["generation.prefill"])
         assert all(r["stats"]["taken"] >= 1
@@ -201,9 +254,9 @@ class TestEngineThreadOnTheProfilersClock:
         last = max(e["end"] for e in top
                    if e["name"] == "generation.harvest")
         inside = [e for e in top if e["start"] >= first and e["end"] <= last]
-        holes = sum(max(0.0, b["start"] - a["end"])
-                    for a, b in zip(inside, inside[1:]))
-        assert inside[0]["start"] == first and inside[-1]["end"] == last
+        holes = _holes(inside, first, last)
+        assert inside[0]["start"] == first
+        assert max(e["end"] for e in inside) == last
         assert holes < 0.10 * (last - first), (holes, last - first)
 
 
@@ -361,7 +414,7 @@ class TestStepCounts:
         steps; a stop token costs the one row in flight behind it."""
         if loop == "drained":
             monkeypatch.setattr(GenerationEngine, "_must_drain",
-                                lambda self: True)
+                                lambda self: "drafter")
         flew = loop == "overlapped"
         prompts = [_prompt(n, seed=20 + n) for n in self.PROMPTS]
         # sampled: this model's greedy streams repeat one token
@@ -442,3 +495,271 @@ class TestStepCounts:
         assert counted[3] / (counted[0] * CFG["slots"]
                              * CFG["max_pages_per_seq"]) == 11 / 48
         assert gen_mod._collect_decode_counts in reg._collectors
+
+
+def _wait_for(cond, timeout=60.0):
+    t_end = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < t_end, "timed out"
+        time.sleep(0.002)
+
+
+class TestAnAdmissionIsTiled:
+    """ONE admission while a stream decodes, in a profiler session: from
+    the start of its drain to the start of the next step's dispatch, the
+    leaves of the engine line cover all but under 10 %.  At a width whose
+    admission takes the CPU a few milliseconds: what no leaf covers is
+    some 60-120 us of bookkeeping and span entries (3-4 % here), which at
+    the 16-wide toy's 1 ms would already be 6-8 %."""
+
+    @pytest.fixture(scope="class")
+    def admission(self, tmp_path_factory):
+        from conftest import HostProfile
+
+        wide = TransformerEncoder(
+            vocab_size=VOCAB, d_model=128, n_heads=4, n_layers=4,
+            causal=True, seed=5,
+        ).init_model()
+        eng = _engine(wide, max_pages_per_seq=8).start()
+        try:
+            for n in (5, 20):                   # warm both buckets
+                eng.generate(_prompt(n, seed=9), 3, timeout=120.0)
+            with HostProfile(str(tmp_path_factory.mktemp("adm"))) as prof:
+                a = eng.submit(_prompt(6, seed=1), 40)
+                _wait_for(lambda: len(a.tokens) >= 4)
+                b = eng.submit(_prompt(20, seed=2), 6)
+                a.result(120.0)
+                b.result(120.0)
+                eng.stop()
+        finally:
+            eng.stop()
+        line = prof.line_with("generation.decode_dispatch")
+        return [e for e in line if e["name"].startswith("generation.")]
+
+    def test_a_drain_then_a_take_then_the_refill(self, admission):
+        drains = _named(admission, "drain")
+        assert [d["stats"]["reason"] for d in drains] == ["admit", "idle"]
+        take, = _named(admission, "take")
+        refill = [r for r in _named(admission, "refill")
+                  if r["start"] >= take["end"]]
+        assert drains[0]["end"] <= take["start"]
+        assert int(take["stats"]["taken"]) == 1
+        assert refill and int(refill[0]["stats"]["taken"]) == 1
+
+    def test_the_children_of_admit_to_slot_tile_it(self, admission):
+        """What prefill, handoff and first token leave uncovered of
+        `admit_to_slot` (the allocation, the fault consult, the stream's
+        bookkeeping) stays under 10 % of it: A's admission and B's."""
+        adm = _named(admission, "admit_to_slot")
+        assert len(adm) == 2
+        kids = [e for e in admission if e["name"] in (
+            "generation.prefill", "generation.kv_handoff",
+            "generation.first_token")]
+        holes = sum(_holes([k for k in kids if _inside(k, a)],
+                           a["start"], a["end"]) for a in adm)
+        whole = sum(a["end"] - a["start"] for a in adm)
+        assert holes < 0.10 * whole, (holes, whole)
+
+    def test_leaves_tile_it_from_the_drain_to_the_next_dispatch(
+            self, admission):
+        start = _named(admission, "drain")[0]["start"]
+        end = min(d["start"] for d in _named(admission, "decode_dispatch")
+                  if d["start"] > start)
+        leaves = [e for e in admission if e["name"] in ADMISSION_LEAVES
+                  and e["start"] >= start and e["end"] <= end]
+        assert {e["name"] for e in leaves} == ADMISSION_LEAVES
+        holes = _holes(leaves, start, end)
+        assert holes < 0.10 * (end - start), (holes, end - start)
+
+
+DRAINS, DRAIN_SECONDS = gen_mod.DECODE_DRAIN_FAMILIES
+
+
+class TestDrainsByReason:
+    """A hand count of the lookahead's drains on scripted schedules, by
+    the engine's stats and by the registry's two families after a
+    scrape."""
+
+    @staticmethod
+    def _counted(run):
+        """The drains ``run()`` made (it returns the engine it ran), by
+        reason, as the engine's stats and the registry's counters (moved
+        by a scrape) say."""
+        reg = registry()
+        reg.collect()
+        before = {r: (reg.counter(DRAINS).value(reason=r),
+                      reg.counter(DRAIN_SECONDS).value(reason=r))
+                  for r in gen_mod.DRAIN_REASONS}
+        eng = run()
+        st = eng.stats()["decode_drains"]
+        reg.collect()
+        for r, (n, secs) in before.items():
+            assert reg.counter(DRAINS).value(reason=r) - n == \
+                st[r]["count"]
+            assert reg.counter(DRAIN_SECONDS).value(reason=r) - secs == \
+                pytest.approx(st[r]["seconds"], abs=1e-5)
+            assert (st[r]["seconds"] > 0) == (st[r]["count"] > 0)
+        return {r: st[r]["count"] for r in gen_mod.DRAIN_REASONS}
+
+    def test_an_arrival_mid_stream_and_the_last_end(self, model):
+        """A decodes; B arrives: ONE drain for its admission.  B ends by
+        count while A goes on: no drain.  A ends with the step in flight
+        and nothing waits: one drain with nothing left to build."""
+        def run():
+            eng = _engine(model, max_pages_per_seq=8).start()
+            try:
+                a = eng.submit(_prompt(6, seed=1), 30)
+                _wait_for(lambda: len(a.tokens) >= 4)
+                b = eng.submit(_prompt(5, seed=2), 5)
+                b.result(120.0)
+                a.result(120.0)
+                assert eng.drain(timeout=30.0)
+            finally:
+                eng.stop()
+            return eng
+
+        assert self._counted(run) == {"admit": 1, "drafter": 0, "stop": 0,
+                                      "idle": 1}
+
+    def test_a_stop_mid_stream(self, model):
+        """stop() while a stream decodes lands the step in flight once."""
+        def run():
+            eng = _engine(model, max_pages_per_seq=8).start()
+            a = eng.submit(_prompt(6, seed=1), 50)
+            _wait_for(lambda: len(a.tokens) >= 4)
+            eng.stop()
+            with pytest.raises(ServingRejected):
+                a.result(5.0)
+            return eng
+
+        assert self._counted(run) == {"admit": 0, "drafter": 0, "stop": 1,
+                                      "idle": 0}
+
+    def test_a_drafter_drains_every_step(self, model):
+        prompts = [_prompt(n, seed=20 + n) for n in (5, 3)]
+        ref = _engine(model)
+        rows, _ = _run_scripted(ref, prompts, (9, 5))
+
+        def run():
+            eng = _engine(model, spec_k=3)
+            eng.drafter = _Oracle(rows)
+            _run_scripted(eng, prompts, (9, 5))
+            assert eng.stats()["decode_steps"] == 2
+            return eng
+
+        assert self._counted(run) == {"admit": 0, "drafter": 2, "stop": 0,
+                                      "idle": 0}
+
+
+class TestNoGaugePerStep:
+    def _engine_gauges(self, model, monkeypatch, max_new):
+        """The gauges set on the engine thread while it serves ONE
+        stream of ``max_new`` tokens, queued before it starts."""
+        reg = registry()
+        calls = []
+        real = reg.gauge
+
+        def spy(name, *a, **kw):
+            if threading.current_thread().name == "dl4jtpu-generation":
+                calls.append(name)
+            return real(name, *a, **kw)
+
+        monkeypatch.setattr(reg, "gauge", spy)
+        eng = _engine(model)
+        req = eng.submit(_prompt(5, seed=3), max_new)
+        eng.start()
+        try:
+            req.result(120.0)
+            assert eng.drain(timeout=30.0)
+        finally:
+            eng.stop()
+            monkeypatch.setattr(reg, "gauge", real)
+        return sorted(calls), eng.stats()["decode_steps"]
+
+    def test_a_longer_stream_sets_no_more_gauges(self, model, monkeypatch):
+        """Per stream the pool's page gauge and the flight ring's move
+        (allocation, release, the fate point); per STEP nothing does."""
+        short, steps_short = self._engine_gauges(model, monkeypatch, 4)
+        long, steps_long = self._engine_gauges(model, monkeypatch, 20)
+        assert steps_long - steps_short == 16
+        assert long == short
+        assert "dl4jtpu_decode_batch_occupancy" not in long
+
+    def test_occupancy_is_sampled_at_the_scrape(self, model):
+        """`dl4jtpu_decode_batch_occupancy` = live streams / slots of the
+        running engines when the registry collects: here with A live and
+        the engine thread held inside B's first token (B not seated)."""
+        reg = registry()
+        held, go = threading.Event(), threading.Event()
+
+        def first(token, index):
+            held.set()
+            assert go.wait(30.0)
+
+        def others():
+            live = slots = 0
+            for e in list(gen_mod._ENGINES):
+                if e is not eng and e._thread is not None \
+                        and e._thread.is_alive():
+                    live += e.active_streams()
+                    slots += e.config.slots
+            return live, slots
+
+        eng = _engine(model, max_pages_per_seq=8).start()
+        try:
+            a = eng.submit(_prompt(6, seed=1), 30)
+            _wait_for(lambda: len(a.tokens) >= 2)
+            b = eng.submit(_prompt(5, seed=2), 4, on_token=first)
+            assert held.wait(30.0)
+            live, slots = others()
+            reg.collect()
+            got = reg.gauge("dl4jtpu_decode_batch_occupancy").value()
+            assert got == (1 + live) / (CFG["slots"] + slots)
+            go.set()
+            b.result(120.0)
+            a.result(120.0)
+            assert eng.drain(timeout=30.0)
+            live, slots = others()
+            reg.collect()
+            got = reg.gauge("dl4jtpu_decode_batch_occupancy").value()
+            assert got == live / (CFG["slots"] + slots)
+        finally:
+            go.set()
+            eng.stop()
+
+
+def test_one_prefill_dispatch_per_chunk_on_a_row_pool():
+    """A stack over row pools (the latent toy of test_latent_serving)
+    prefills a prompt as one chunk program after another: one
+    `generation.prefill_dispatch {chunk}` each, in order, inside the
+    prefill, then one `prefill_readback`; and `generation.prefill`'s
+    duration is still `req.lat["prefill"]`."""
+    from test_latent_serving import ENGINE, _model
+
+    rec = tracer()
+    eng = GenerationEngine(model=_model(),
+                           config=GenerationConfig(**ENGINE)).start()
+    try:
+        rec.enable()
+        rec.clear()
+        req = eng.submit(np.arange(1, 36, dtype=np.int32) % 90, 2)
+        req.result(300.0)
+        tid = eng._thread.ident
+    finally:
+        eng.stop()
+        rec.disable()
+    ring = sorted((e for e in rec.to_chrome_trace()["traceEvents"]
+                   if e["ph"] == "X" and e["cat"] == "engine"
+                   and e["tid"] == tid), key=lambda e: e["ts"])
+    rec.clear()
+    pre, = [e for e in ring if e["name"] == "generation.prefill"]
+    inner = [e for e in ring if e["name"] in (
+        "generation.prefill_dispatch", "generation.prefill_readback")]
+    # 35 tokens in chunks of 16: a bucket of 48, three chunk programs
+    assert [e["name"].rsplit("_", 1)[1] for e in inner] == [
+        "dispatch"] * 3 + ["readback"]
+    assert [e["args"].get("chunk") for e in inner[:3]] == [0, 1, 2]
+    for e in inner:
+        assert pre["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= pre["ts"] + pre["dur"] + 1e-3
+    assert round(req.lat["prefill"] * 1e6, 3) == pre["dur"]

@@ -415,8 +415,11 @@ class TestLatencySurfaces:
         http_srv = ServingHTTPServer(srv).start()
         ui = UIServer(port=0)
         try:
+            mine = set()
             for i in range(2):
-                eng.generate(_prompt(4, seed=50 + i), 4, timeout=120.0)
+                req = eng.submit(_prompt(4, seed=50 + i), 4)
+                req.result(120.0)
+                mine.add(req.rid)
             with urllib.request.urlopen(
                     http_srv.url + "v1/status") as r:
                 status = json.loads(r.read())
@@ -430,12 +433,17 @@ class TestLatencySurfaces:
             health = srv.health()
             assert health["generation"]["stream_outcomes"]["ok"] == 2
             assert "kv_occupancy" in health["generation"]
-            # the generation-plane exemplar endpoint
+            # the generation-plane exemplar endpoint: it merges every
+            # live engine of the process, so read this engine's streams
+            # (another test's engine in the same worker may hold slower
+            # ones, untraced)
             with urllib.request.urlopen(
-                    ui.url + "api/generation/slow?limit=5") as r:
+                    ui.url + "api/generation/slow?limit=1000") as r:
                 rows = json.loads(r.read())
             assert rows and all(r["kind"] == "generate" for r in rows)
-            assert "spans" in rows[0]
+            ours = [r for r in rows if r["rid"] in mine]
+            assert len(ours) == 2
+            assert all("spans" in r for r in ours)
             # ... and the merged serving view tags both planes
             with urllib.request.urlopen(
                     ui.url + "api/serving/slow?limit=20") as r:

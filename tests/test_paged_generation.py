@@ -88,7 +88,8 @@ def _prompt(n, seed=0):
 def _drain_every_step(monkeypatch):
     """The decode loop as it was before the lookahead: every step is read
     back before the next is built (a test-only hook: no config field)."""
-    monkeypatch.setattr(GenerationEngine, "_must_drain", lambda self: True)
+    monkeypatch.setattr(GenerationEngine, "_must_drain",
+                        lambda self: "drafter")
 
 
 # -- the paged KV allocator --------------------------------------------------
