@@ -769,6 +769,13 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "dl4jtpu_decode_steps_total it is the share of steps the "
                 "lookahead engaged for; the rest followed a drain (an "
                 "admission, a drafter, a stop)")
+    reg.counter("dl4jtpu_decode_sampler_steps_total",
+                "Decode steps by the branch of the sampler they ran, from "
+                "the sampling parameters of their live rows (greedy: no "
+                "row at temperature > 0, the arg-max alone; sampled: a "
+                "draw, no row with a top-k; top_k: the draw and the k-th "
+                "largest count over the vocabulary).  Bridged by the "
+                "decode counts' pull collector")
     reg.counter("dl4jtpu_decode_slot_steps_discarded_total",
                 "Slot-rows a decode step computed for a stream that had "
                 "ended before the step's tokens were read (a stop token "

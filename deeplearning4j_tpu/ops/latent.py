@@ -21,6 +21,8 @@ step through `ops/generation.block`:
 - The selection is EXACT: `topk_mask` finds each row's k-th largest score
   by counting over the bits of the float (32 compare-and-count passes, no
   sort) and breaks ties toward the lower index, as `lax.top_k` does.
+  `kth_largest` is the same count, read back as a float: the serving
+  sampler's top-k threshold over the vocabulary.
 
 `latent_block(cfg, lp, x, rows)` is the block; ``rows`` (`LatentRows`) is
 the caller's side of it: the rows' positions and
@@ -91,6 +93,29 @@ def _ordered_bits(scores):
     return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
 
 
+def _kth_largest_key(key, k):
+    """key (q, n) uint32, k an int or (q,) int32 -> (q,) uint32: per row
+    the largest ``t`` with at least ``k`` keys ``>= t`` — the k-th largest
+    key — built bit by bit from counts of ``key >= candidate`` (32
+    compare-and-count passes, no sort); 0 where no candidate passes."""
+
+    def bit(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n_ge = jnp.sum((key >= cand[:, None]).astype(jnp.int32), axis=-1)
+        return jnp.where(n_ge >= k, cand, prefix)
+
+    return lax.fori_loop(0, 32, bit, jnp.zeros(key.shape[0], jnp.uint32))
+
+
+def kth_largest(x, k):
+    """x (q, n) f32, k (q,) int32 in ``1..n`` -> (q,) f32: each row's k-th
+    largest value, equal (``==``) to element ``k - 1`` of the row sorted
+    in descending order, found without sorting."""
+    thr = _kth_largest_key(_ordered_bits(x), k)
+    u = jnp.where(thr >> 31 == 1, thr & jnp.uint32(0x7FFFFFFF), ~thr)
+    return lax.bitcast_convert_type(u, jnp.float32)
+
+
 def topk_mask(scores, valid, k: int):
     """scores, valid: (q, n) -> bool (q, n): per row the ``min(k, number
     valid)`` valid entries of largest score, ties to the lower index —
@@ -99,16 +124,9 @@ def topk_mask(scores, valid, k: int):
     with jax.named_scope("dsa_topk"):
         key = jnp.where(valid, _ordered_bits(lax.stop_gradient(scores)),
                         jnp.uint32(0))                 # no float maps to 0
-
-        def bit(i, prefix):
-            cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-            n_ge = jnp.sum((key >= cand[:, None]).astype(jnp.int32), axis=-1)
-            return jnp.where(n_ge >= k, cand, prefix)
-
         # fewer than k valid: no candidate passes, the threshold stays 0
         # and every valid entry is taken
-        thr = lax.fori_loop(0, 32, bit,
-                            jnp.zeros(scores.shape[0], jnp.uint32))[:, None]
+        thr = _kth_largest_key(key, k)[:, None]
         above = key > thr
         tied = (key == thr) & valid
         room = k - jnp.sum(above.astype(jnp.int32), axis=-1, keepdims=True)

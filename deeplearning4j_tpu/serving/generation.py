@@ -94,6 +94,11 @@ way; ``dl4jtpu_decode_slot_steps_discarded_total`` the rows thrown away;
 ``dl4jtpu_decode_drains_total{reason}`` and
 ``dl4jtpu_decode_drain_seconds_total{reason}`` how often the loop let go
 of the lookahead, why, and what the ``generation.drain`` spans took.
+
+Every program samples with `_sample_tokens`: greedy rows take the arg-max,
+and the draw and the top-k count run only when some row of the batch asks
+for them (``dl4jtpu_decode_sampler_steps_total{branch}`` says how often a
+decode step did); no program sorts the vocabulary.
 """
 
 from __future__ import annotations
@@ -132,6 +137,7 @@ from deeplearning4j_tpu.ops.latent import (
     blocked,
     expand_latents,
     index_scores,
+    kth_largest,
     topk_mask,
 )
 from deeplearning4j_tpu.ops.paged_attention import paged_attention_chunk
@@ -208,6 +214,14 @@ DRAIN_REASONS = ("admit", "drafter", "stop", "idle")
 #: the seconds of their ``generation.drain`` spans (`_drains[r][1]`)
 DECODE_DRAIN_FAMILIES = ("dl4jtpu_decode_drains_total",
                          "dl4jtpu_decode_drain_seconds_total")
+
+#: which branch of the sampler (`_sample_tokens`) the decode steps ran,
+#: counted on the host from the arrays a step is dispatched with: greedy
+#: (no row at temperature > 0; an idle slot's is 0), sampled (a draw, no row with a
+#: top-k), top_k (the draw and the k-th largest count); process totals by
+#: ``branch`` in the family
+SAMPLER_BRANCHES = ("greedy", "sampled", "top_k")
+DECODE_SAMPLER_FAMILY = "dl4jtpu_decode_sampler_steps_total"
 
 #: what the learned sparse selection did, process totals, counted on the
 #: host from the lengths (prefill and decode): context rows the indexers
@@ -418,25 +432,47 @@ class GenerationRequest:
         )
 
 
-def _sample_token(logits, temp, top_k, key):
-    """`ops.generation._sample` with RUNTIME sampling params, for one
-    (V,) logits row — temperature/top_k ride the batch as traced
-    per-slot scalars so the sampling config never recompiles the step.
-    The kth-largest threshold (descending sort at [k-1]) is the exact
-    value `lax.top_k(x, k)[0][..., -1]` gives the dense reference, and
-    greedy argmaxes the UNSCALED logits exactly like the reference's
-    ``temperature <= 0`` branch."""
+def _sample_tokens(logits, temps, top_ks, keys):
+    """`ops.generation._sample` with RUNTIME sampling params, for a batch
+    of rows: logits (n, V), temps (n,), top_ks (n,), keys (n,) -> (n,)
+    int32.  Temperature and top-k ride the batch as traced per-row values,
+    so the sampling config never recompiles a program; what runs follows
+    them.  Greedy argmaxes the UNSCALED logits, exactly like the
+    reference's ``temperature <= 0`` branch, and is all an all-greedy
+    batch runs: the draw (scale, threshold, mask, `categorical` with each
+    row's key) sits under ONE `lax.cond` on the batch, and the top-k
+    threshold under another inside it.  The threshold is each row's k-th
+    largest scaled logit (`kth_largest`, counted, not sorted: the value
+    `lax.top_k(x, k)[0][..., -1]` gives the dense reference), k clipped to
+    ``1..V``; a row with ``top_k <= 0`` keeps every logit."""
     logits = logits.astype(jnp.float32)
     v = logits.shape[-1]
-    greedy = jnp.argmax(logits).astype(jnp.int32)
-    t = jnp.where(temp > 0.0, temp, 1.0)
-    scaled = logits / t
-    order = jnp.sort(scaled)[::-1]
-    kth = jnp.where(top_k > 0, order[jnp.clip(top_k - 1, 0, v - 1)],
-                    -jnp.inf)
-    masked = jnp.where(scaled < kth, -jnp.inf, scaled)
-    samp = jax.random.categorical(key, masked).astype(jnp.int32)
-    return jnp.where(temp <= 0.0, greedy, samp)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampled = temps > 0.0
+    no_threshold = lambda scaled: jnp.full(scaled.shape[:1], -jnp.inf)
+
+    def top_k(scaled):
+        kth = kth_largest(scaled, jnp.clip(top_ks, 1, v))
+        return jnp.where(top_ks > 0, kth, -jnp.inf)
+
+    def draw(_):
+        scaled = logits / jnp.where(sampled, temps, 1.0)[:, None]
+        kth = jax.lax.cond(jnp.any(sampled & (top_ks > 0)), top_k,
+                           no_threshold, scaled)
+        masked = jnp.where(scaled < kth[:, None], -jnp.inf, scaled)
+        samp = jax.vmap(jax.random.categorical)(keys, masked)
+        return jnp.where(sampled, samp.astype(jnp.int32), greedy)
+
+    return jax.lax.cond(jnp.any(sampled), draw, lambda _: greedy, None)
+
+
+def _sampler_branch(temps, top_ks) -> str:
+    """The branch of `_sample_tokens` a step runs, from the temps and
+    top_ks (S,) it is dispatched with (an idle slot's are 0)."""
+    samples = temps > 0.0
+    if not samples.any():
+        return "greedy"
+    return "top_k" if (samples & (top_ks > 0)).any() else "sampled"
 
 
 def _stored_row(row: tuple) -> tuple:
@@ -579,6 +615,9 @@ class GenerationEngine:
         # reason present from the start, so a flush never sees a new key)
         self._drains = {r: [0, 0.0] for r in DRAIN_REASONS}
         self._drains_flushed = {r: (0, 0.0) for r in DRAIN_REASONS}
+        # the steps by the branch of the sampler they ran
+        self._sampler_steps = dict.fromkeys(SAMPLER_BRANCHES, 0)
+        self._sampler_flushed = dict.fromkeys(SAMPLER_BRANCHES, 0)
         # `model.params` as the copies below were made from it, the tree
         # the programs are dispatched with, and the long prefill buckets'
         # (`_serving_params`); and how many were made
@@ -889,10 +928,9 @@ class GenerationEngine:
             x, kvs = prompt_forward(stack, params, prompt_pad,
                                     _act_dtype(self.model))
             logits = _head_logits(stack, params, x[0, prompt_len - 1])
-            first = _sample_token(
-                logits, temp, top_k,
-                jax.random.fold_in(jax.random.key(seed), 0),
-            )
+            first = _sample_tokens(
+                logits[None], temp[None], top_k[None],
+                jax.random.fold_in(jax.random.key(seed), 0)[None])[0]
             return (jnp.stack([k[0] for k, _ in kvs]).astype(jnp.float32),
                     jnp.stack([v[0] for _, v in kvs]).astype(jnp.float32),
                     first)
@@ -1084,9 +1122,10 @@ class GenerationEngine:
                               counts_to, MOE_ROW_TILE)
             x = self._run_blocks(params, x, lambda li: rows)
             last = jnp.clip(prompt_len - 1 - start, 0, c_rows - 1)
-            first = _sample_token(
-                _head_logits(stack, params, x[last]), temp, top_k,
-                jax.random.fold_in(jax.random.key(seed), 0))
+            first = _sample_tokens(
+                _head_logits(stack, params, x[last])[None], temp[None],
+                top_k[None],
+                jax.random.fold_in(jax.random.key(seed), 0)[None])[0]
             return (*pools.values(), *counts, first)
 
         return jax.jit(prefill_chunk,
@@ -1265,10 +1304,7 @@ class GenerationEngine:
                 rows(seeds),
                 (gen_counts[:, None] + jnp.arange(c)[None, :]).reshape(n),
             )
-            nxt = jax.vmap(_sample_token)(
-                logits.astype(jnp.float32), rows(temps), rows(top_ks),
-                keys,
-            )
+            nxt = _sample_tokens(logits, rows(temps), rows(top_ks), keys)
             nxt = jnp.where(rows(active), nxt, 0)
             return (*pool, *counts, nxt.reshape(toks.shape))
 
@@ -1695,6 +1731,7 @@ class GenerationEngine:
         rows = int(attended.sum())
         self._steps += 1
         self._overlapped += prev is not None
+        self._sampler_steps[_sampler_branch(temps, top_ks)] += 1
         self._slot_steps += n_live
         self._rows_attended += rows
         self._pages_attended += int((-(-attended // ps)).sum())
@@ -2201,6 +2238,7 @@ class GenerationEngine:
             "decode_slot_steps_discarded": self._discarded,
             "decode_drains": {r: {"count": n, "seconds": round(secs, 6)}
                               for r, (n, secs) in self._drains.items()},
+            "decode_sampler_steps": dict(self._sampler_steps),
             "serving_params_casts": self._params_casts,
             "dsa": {"rows_scored": self._dsa_scored,
                     "rows_selected": self._dsa_selected},
@@ -2331,6 +2369,10 @@ class GenerationEngine:
                         secs - self._drains_flushed[r][1])
                     for r, (n, secs) in drains.items()}
                 self._drains_flushed = drains
+                sampler = dict(self._sampler_steps)
+                sampler_delta = {b: n - self._sampler_flushed[b]
+                                 for b, n in sampler.items()}
+                self._sampler_flushed = sampler
                 if moe is not None:
                     was = self._moe_flushed
                     moe_delta = moe if was is None else moe - was
@@ -2344,6 +2386,10 @@ class GenerationEngine:
                     n_fam.inc(n, reason=reason)
                 if secs > 0:
                     s_fam.inc(secs, reason=reason)
+            b_fam = reg.counter(DECODE_SAMPLER_FAMILY)
+            for branch, n in sampler_delta.items():
+                if n > 0:
+                    b_fam.inc(n, branch=branch)
             if moe is not None:
                 blocks = [b for b in self._stack.blocks
                           if b.name in self._moe_index]
