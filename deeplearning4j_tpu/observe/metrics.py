@@ -762,6 +762,27 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "paged kernel's loop runs over exactly these pages; over "
                 "decode steps x slots x pages per sequence it is the "
                 "share of the page tables that is live")
+    reg.counter("dl4jtpu_shared_kv_rows_attended_total",
+                "Rows of a hybrid stack's shared K/V pool (its one "
+                "full-attention layer's keys and values) a decode step "
+                "attends, summed over its live slots: seq_len + 1 per "
+                "slot, per step.  Each of the full layer and the cross "
+                "layers reads them once (a shared_kv_attn call each), so "
+                "times those layers x 2 x kv heads x head size x bytes "
+                "per element it is the bytes the kernel must read")
+    reg.counter("dl4jtpu_window_rows_attended_total",
+                "Rows of the sliding-window rings a hybrid stack's decode "
+                "step attends, per window layer, summed over its live "
+                "slots: min(seq_len + 1, window) per slot, per step")
+    reg.counter("dl4jtpu_prefill_rows_total",
+                "Prompt rows the prefill programs of a hybrid stack took "
+                "in (the prompts' lengths, pad rows left out)")
+    reg.counter("dl4jtpu_prefill_rows_skipped_total",
+                "Prompt rows a part of a hybrid stack did not run, by "
+                "part: cross, the full layer's attention and every layer "
+                "after it, which run on a prompt's last row only "
+                "(prompt length - 1 rows skipped per prompt).  Over "
+                "dl4jtpu_prefill_rows_total it is the share skipped")
     reg.counter("dl4jtpu_decode_steps_overlapped_total",
                 "Decode steps dispatched while the step before was still "
                 "unread (the loop's one-step lookahead): the host's work "

@@ -47,6 +47,7 @@ from deeplearning4j_tpu.nn.conf.attention import (
     PositionalEncoding,
     TransformerEncoderBlock,
 )
+from deeplearning4j_tpu.nn.conf.hybrid import HybridBlock, HybridDecoder
 from deeplearning4j_tpu.nn.conf.layers import (
     ChunkedSoftmaxOutputLayer,
     Embedding,
@@ -54,6 +55,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
 )
 from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
 from deeplearning4j_tpu.ops.attention import mha
+from deeplearning4j_tpu.ops.hybrid import final_norm, hybrid_block
 from deeplearning4j_tpu.ops.latent import latent_block, rms_norm
 from deeplearning4j_tpu.quant.qtensor import QuantizedTensor
 
@@ -67,16 +69,25 @@ _Stack = collections.namedtuple("_Stack", "embed pos blocks head d final")
 def cache_rows(cfg) -> dict:
     """What one token caches in a block: pool name -> the row's shape.  A
     `TransformerEncoderBlock` caches a key and a value of ``(heads,
-    head_dim)`` each; a `LatentBlock` states its own widths."""
-    if isinstance(cfg, LatentBlock):
+    head_dim)`` each; a `LatentBlock` or a `HybridBlock` states its own
+    widths (a hybrid layer other than the full one caches nothing per
+    token: what it keeps is per stream, `slot_rows`)."""
+    if isinstance(cfg, (LatentBlock, HybridBlock)):
         return {name: (width,) for name, width in cfg.cache_rows.items()}
     row = (cfg.n_heads, cfg.d_model // cfg.n_heads)
     return {"k": row, "v": row}
 
 
+def slot_rows(cfg) -> dict:
+    """What a block keeps per STREAM (per decode slot), whatever its
+    length: name -> (shape, "f32" | "kv"); "kv" is the pool's row type.
+    Only a `HybridBlock` keeps anything so."""
+    return cfg.slot_rows if isinstance(cfg, HybridBlock) else {}
+
+
 def block_params(params, cfg):
     """The block's entry of the model's params tree."""
-    if isinstance(cfg, LatentBlock):
+    if isinstance(cfg, (LatentBlock, HybridBlock)):
         return params[cfg.path[0]][cfg.path[1]]
     return params[cfg.name]
 
@@ -102,6 +113,16 @@ def _plan(model):
         final = layers[i]
         blocks = list(final.blocks())
         i += 1
+    elif i < len(layers) and isinstance(layers[i], HybridDecoder):
+        # no position encoding (the scans and the window carry order) and
+        # no head layer: the logits are the embedding's matrix, tied
+        if pos is not None or i != len(layers) - 1:
+            raise ValueError("a HybridDecoder takes no PositionalEncoding "
+                             "and is the last layer: its head is the "
+                             "embedding's matrix")
+        final = layers[i]
+        return _Stack(embed, None, tuple(final.blocks()), final,
+                      embed.n_out, final)
     else:
         while (i < len(layers)
                and isinstance(layers[i], TransformerEncoderBlock)):
@@ -109,9 +130,10 @@ def _plan(model):
             i += 1
     if i != len(layers) - 1:
         raise ValueError(
-            "generate() supports [Embedding, PositionalEncoding?, "
-            "TransformerEncoderBlock*, head] and [Embedding, "
-            "LatentSparseDecoder, head] stacks; layer "
+            "generate() supports three stacks: [Embedding, "
+            "PositionalEncoding?, TransformerEncoderBlock*, head], "
+            "[Embedding, LatentSparseDecoder, head] and [Embedding, "
+            "HybridDecoder]; layer "
             f"{type(layers[i]).__name__} at position {i} is not supported"
         )
     head = layers[-1]
@@ -232,6 +254,8 @@ def block(cfg, lp, x, attend):
     count.  Either way the block's output rows come back."""
     if isinstance(cfg, LatentBlock):
         return latent_block(cfg, lp, x, attend)
+    if isinstance(cfg, HybridBlock):
+        return hybrid_block(cfg, lp, x, attend)
     dt = x.dtype
     rows, h_ = x.shape[:-1], cfg.n_heads
     heads = rows + (h_, cfg.d_model // h_)
@@ -253,7 +277,7 @@ def prompt_forward(stack, params, toks, dt):
     (B, T, H, Dh) — the cache seed."""
     if stack.final is not None:
         raise ValueError("prompt_forward seeds a dense K/V cache; a "
-                         "LatentSparseDecoder stack has none")
+                         f"{type(stack.final).__name__} stack has none")
     x = embed_tokens(stack, params, toks, None, dt)
     kvs = []
 
@@ -289,7 +313,14 @@ def cache_row_attention(k_cache, v_cache, pos, grown):
 
 
 def _head_logits(stack, params, h):
-    """h: (..., D), the last block's output -> (..., vocab) logits."""
+    """h: (..., D), the last block's output -> (..., vocab) logits.  A
+    `HybridDecoder`'s head is ``LN_f`` and the embedding's matrix, tied."""
+    if isinstance(stack.final, HybridDecoder):
+        e = params[stack.embed.name]["W"]
+        return jnp.einsum(
+            "...d,vd->...v",
+            final_norm(stack.final, params, h).astype(e.dtype), e,
+            preferred_element_type=jnp.float32)
     head, lp = stack.head, params[stack.head.name]
     if stack.final is not None:
         h = rms_norm(h, params[stack.final.name]["norm_f"],
@@ -329,8 +360,8 @@ def generate(model, prompt_ids, max_new_tokens: int, *,
     if stack.final is not None:
         raise ValueError(
             "generate() decodes against a dense K/V cache; a "
-            "LatentSparseDecoder stack is served by GenerationEngine "
-            "(serving/generation.py) over its paged latent pool")
+            f"{type(stack.final).__name__} stack is served by "
+            "GenerationEngine (serving/generation.py) over its pools")
     pos = stack.pos
     prompt = jnp.asarray(prompt_ids).astype(jnp.int32)
     if prompt.ndim == 1:

@@ -128,6 +128,14 @@ from deeplearning4j_tpu.ops.generation import (
     embed_tokens,
     prompt_forward,
     serving_params,
+    slot_rows,
+)
+from deeplearning4j_tpu.ops.hybrid import (
+    SHARED_KV,
+    context_rows,
+    full_kv_rows,
+    prefill_rows,
+    step_rows,
 )
 from deeplearning4j_tpu.ops.latent import (
     MOE_ROW_TILE,
@@ -141,6 +149,9 @@ from deeplearning4j_tpu.ops.latent import (
     topk_mask,
 )
 from deeplearning4j_tpu.ops.paged_attention import paged_attention_chunk
+from deeplearning4j_tpu.ops.shared_kv_attention import (
+    shared_kv_attention,
+)
 from deeplearning4j_tpu.runtime import faults
 from deeplearning4j_tpu.runtime.flags import bucket_length
 from deeplearning4j_tpu.runtime.watchdog import StepWatchdog
@@ -251,10 +262,26 @@ PREFILL_QUERY_BLOCK = 256
 #: installed tree, flushed with the decode counts
 PARAMS_CASTS_FAMILY = "dl4jtpu_serving_params_casts_total"
 
+#: what a hybrid stack's state was read for, process totals counted on the
+#: host from the lengths: rows of the shared K/V pool a decode step's
+#: full and cross layers attend (per layer: seq_len + 1 per live slot),
+#: rows of its ring a window layer attends (min(seq_len + 1, window)),
+#: and the prompt rows the prefill programs took in
+HYBRID_COUNT_FAMILIES = ("dl4jtpu_shared_kv_rows_attended_total",
+                         "dl4jtpu_window_rows_attended_total",
+                         "dl4jtpu_prefill_rows_total")
+#: prompt rows a part of the stack did not run, by ``part``: the cross
+#: layers (and the full layer's attention) should run on a prompt's last
+#: row only; counted as the prompt's length less the rows the prefill
+#: program reports it ran them on
+PREFILL_SKIPPED_FAMILY = "dl4jtpu_prefill_rows_skipped_total"
+
 #: the families `_flush_decode_counts` moves the engine's plain counts
-#: into, in the order it reads them
+#: into, in the order it reads them (then the cross-decoder's skipped rows,
+#: under their label)
 _FLUSHED_FAMILIES = (DECODE_COUNT_FAMILIES + (PARAMS_CASTS_FAMILY,)
-                     + DSA_COUNT_FAMILIES + DECODE_LOOKAHEAD_FAMILIES)
+                     + DSA_COUNT_FAMILIES + DECODE_LOOKAHEAD_FAMILIES
+                     + HYBRID_COUNT_FAMILIES)
 
 #: a prompt forward of fewer tokens than this spends longer READING f32
 #: block matrices than multiplying by them (2 FLOPs a token against 4
@@ -498,6 +525,27 @@ _StepRows = collections.namedtuple(
 _Flying = collections.namedtuple("_Flying", "toks harvest")
 
 
+def _real_rows(prompt_len, start: int, c_rows: int):
+    """How many of a prefill chunk's ``c_rows`` rows, from position
+    ``start``, are the prompt's: the rows the slot state takes in (the rest
+    are pad, and leave it as it is)."""
+    return jnp.clip(prompt_len - start, 0, c_rows)
+
+
+class _PoolView:
+    """The program state's arrays by pool name, written through to the
+    list the step returns."""
+
+    def __init__(self, arrays: list, at: dict):
+        self.arrays, self.at = arrays, at
+
+    def __getitem__(self, name):
+        return self.arrays[self.at[name]]
+
+    def __setitem__(self, name, array):
+        self.arrays[self.at[name]] = array
+
+
 def _slot_keys(seeds, gen_counts):
     """Per-slot sampling keys on the dense reference's schedule: the
     g-th generated token of a stream seeded ``s`` uses
@@ -540,20 +588,31 @@ class GenerationEngine:
 
         self._stack = stack = _plan(self.model)
         # what the stack's blocks cache decides the pool: per named row,
-        # the layers that hold one (and which of them each block's is)
+        # the layers that hold one (and which of them each block's is),
+        # and per named slot pool the same
         held: dict = {}
+        per_slot: dict = {}
         self._pool_index = {}
         for b in stack.blocks:
             self._pool_index[b.name] = at = {}
             for name, row in cache_rows(b).items():
                 at[name] = held.setdefault(name, [0, row])[0]
                 held[name][0] += 1
+            for name, (shape, dtype) in slot_rows(b).items():
+                at[name] = per_slot.setdefault(name, [0, shape, dtype])[0]
+                per_slot[name][0] += 1
         # a stack with no block keeps the empty K/V pool it always had
         self.kv = PagedKVCache(
             num_pages=cfg.num_pages, page_size=cfg.page_size,
             kv_dtype=cfg.kv_dtype,
             rows={name: (n, _stored_row(row))
-                  for name, (n, row) in held.items()} or None)
+                  for name, (n, row) in held.items()} or None,
+            slot_rows={name: (n, cfg.slots, shape,
+                              cfg.kv_dtype if dtype == "kv" else dtype)
+                       for name, (n, shape, dtype) in per_slot.items()})
+        # where each named pool sits among the program state's arrays
+        self._state_at = {name: i for i, name in enumerate(
+            list(self.kv.rows) + list(self.kv.slot_rows))}
         # expert layers: their place in the device-side assignment counts
         self._moe_index = {
             b.name: i for i, b in enumerate(
@@ -567,6 +626,13 @@ class GenerationEngine:
                              default=0)
         self._dsa_scored = 0
         self._dsa_selected = 0
+        # a hybrid stack's state, read and skipped (`HYBRID_COUNT_FAMILIES`)
+        self._window = max((b.window for b in stack.blocks
+                            if getattr(b, "kind", "") == "swa"), default=0)
+        self._shared_rows = 0
+        self._window_rows = 0
+        self._prefill_rows = 0
+        self._cross_skipped = 0
         self._quantum = cfg.prefill_quantum or self.kv.page_size
         if self._quantum % self.kv.page_size:
             raise ValueError(
@@ -574,6 +640,11 @@ class GenerationEngine:
                 f"the page size {self.kv.page_size} (prompt KV must land "
                 "page-aligned)"
             )
+        if self._window and self._quantum % self._window:
+            raise ValueError(
+                f"prefill_quantum {self._quantum} must be a multiple of "
+                f"the attention window {self._window}: a chunk starts at "
+                "row 0 of the window layers' rings")
 
         s, mp = cfg.slots, cfg.max_pages_per_seq
         # host slot state; the decode step consumes these by value, so
@@ -623,7 +694,7 @@ class GenerationEngine:
         # (`_serving_params`); and how many were made
         self._served = (None, None, None)
         self._params_casts = 0
-        self._counts_flushed = (0,) * len(_FLUSHED_FAMILIES)
+        self._counts_flushed = (0,) * (len(_FLUSHED_FAMILIES) + 1)
         self._tokens_out = 0
         # the compiled decode programs by chunk width c: 1 is the plain
         # step, spec_k + 1 the speculative verify (built at first use)
@@ -637,6 +708,12 @@ class GenerationEngine:
              else speculative.spec_k_from_env(0))
         self.spec_k = max(0, int(k))
         self.drafter: Optional[speculative.DraftSource] = None
+        if self.spec_k > 0 and self.kv.slot_rows:
+            raise ValueError(
+                f"speculative decoding (spec_k {self.spec_k}) needs rejected "
+                "draft rows rolled back, and this stack keeps recurrent "
+                f"state per stream ({sorted(self.kv.slot_rows)}): a scan "
+                "that took in a rejected token cannot be truncated")
         if self.spec_k > 0:
             self.drafter = speculative.make_drafter(
                 cfg.spec_drafter or speculative.drafter_from_env(),
@@ -943,6 +1020,7 @@ class GenerationEngine:
         # cheap even with the decode loop live.  A K/V pool's programs
         # are keyed by the prompt's bucket, row pools' by the chunk's index
         make = (self._make_prefill if self.kv.kv_layout
+                else self._make_hybrid_prefill_chunk if self.kv.slot_rows
                 else self._make_prefill_chunk)
         with self._mu:
             fn = self._prefill_fns.get(key)
@@ -950,7 +1028,7 @@ class GenerationEngine:
                 fn = self._prefill_fns[key] = make(key)
         return fn
 
-    def _run_prefill(self, req: GenerationRequest):
+    def _run_prefill(self, req: GenerationRequest, slot: int = 0):
         """Dispatch one request's prefill; returns (k, v, first_token,
         ttft_anchor).  A K/V pool: the bucketed whole-prompt program,
         k/v shaped (n_layers, t_bucket, H, Dh) f32 for `write_prefill`.
@@ -960,7 +1038,9 @@ class GenerationEngine:
         Inside the caller's ``generation.prefill``: one
         ``generation.prefill_dispatch`` per program (the host's cost to
         launch it; the device idles under it when nothing is queued) and
-        the ``generation.prefill_readback`` of the first token."""
+        the ``generation.prefill_readback`` of the first token.  A stack
+        with slot pools is also handed ``slot``, whose rows the first
+        chunk writes from scratch: the slot's reset."""
         t_p = req.prompt.shape[0]
         t_b = bucket_length(t_p, self._quantum)
         params = self._serving_params(t_b)
@@ -980,16 +1060,23 @@ class GenerationEngine:
         row = np.full(self.config.max_pages_per_seq, SCRATCH_PAGE, np.int32)
         tbl = self.kv.table(req.rid)
         row[: len(tbl)] = tbl
+        at_slot = (np.int32(slot),) if self.kv.slot_rows else ()
         for ci in range(t_b // c_rows):
             with self._span("generation.prefill_dispatch", chunk=ci):
                 out = self._prefill_fn(ci)(
                     params, *self._program_state(), row,
-                    pad[ci * c_rows:(ci + 1) * c_rows], *sampling)
+                    pad[ci * c_rows:(ci + 1) * c_rows], *sampling,
+                    *at_slot)
                 self._rebind_state(out[:-1])
         self._count_selection(np.arange(1, t_p + 1))
         with self._span("generation.prefill_readback"):
-            first = int(out[-1])
-        return None, None, first, req.t_submit
+            got = np.asarray(out[-1]).reshape(-1)
+        if self.kv.slot_rows:
+            # the rows the cross-decoder skipped, by the rows the last
+            # chunk reports it ran
+            self._prefill_rows += t_p
+            self._cross_skipped += max(t_p - int(got[1]), 0)
+        return None, None, int(got[0]), req.t_submit
 
     def _count_selection(self, contexts) -> None:
         """Query rows at the contexts ``contexts`` (each row's own
@@ -1131,6 +1218,70 @@ class GenerationEngine:
         return jax.jit(prefill_chunk,
                        donate_argnums=tuple(range(1, 1 + n_state)))
 
+    def _make_hybrid_prefill_chunk(self, ci: int):
+        """Prefill chunk ``ci`` of a hybrid stack (`HybridDecoder`): the
+        prompt's rows ``[ci * C, (ci + 1) * C)`` through the self-decoder,
+        carrying the slot's state from the chunk before and stopping it at
+        ``prompt_len`` (`ops/hybrid.prefill_rows`; chunk 0 starts from
+        zeros, which is the slot's reset).  The full layer's keys and
+        values of every row go into the stream's pages of the shared pool;
+        its attention and every layer after it run only for the prompt's
+        LAST row, in the chunk that holds it (a ``lax.cond``): nothing
+        they compute is cached, and only that row's logits are wanted.
+        Returns the state, then (the token sampled after the last row, the
+        rows the cross-decoder ran: 1 in that chunk, 0 in the others)."""
+        stack, ps = self._stack, self.kv.page_size
+        c_rows = self._quantum
+        start, ctx = ci * c_rows, (ci + 1) * c_rows
+        new_pg = slice(start // ps, ctx // ps)
+        n_state = len(self._program_state())
+        names = list(self._state_at)
+        at_full = [b.kind for b in stack.blocks].index("full")
+        full = stack.blocks[at_full]
+        dt = _act_dtype(self.model)
+
+        def prefill_chunk(params, *rest):
+            state = rest[:n_state]
+            page_row, toks, prompt_len, seed, temp, top_k, slot = rest[
+                n_state:]
+            pools = dict(zip(names, state))
+            x = embed_tokens(stack, params, toks,
+                             start + jnp.arange(c_rows), dt)
+            rows = prefill_rows(
+                pools, self._pool_index, slot, start,
+                _real_rows(prompt_len, start, c_rows), fresh=ci == 0,
+                dt=dt, query_block=PREFILL_QUERY_BLOCK)
+            for cfg in stack.blocks[:at_full]:
+                x = block(cfg, block_params(params, cfg), x, rows)
+            # the full layer's keys and values of every row, into the pages
+            pool = pools[SHARED_KV]
+            tail = pool.shape[2:]
+            flat = pool.reshape((-1,) + tail).at[page_row[new_pg]].set(
+                full_kv_rows(full, block_params(params, full), x, pool,
+                             dt).reshape((-1,) + tail))
+            pools[SHARED_KV] = flat.reshape(pool.shape)
+            last = jnp.clip(prompt_len - 1 - start, 0, c_rows - 1)
+
+            def last_row(_):
+                y = x[last][None]           # the rows the cross-decoder runs
+                tail_rows = context_rows(
+                    flat[page_row[: ctx // ps]].reshape(ctx, -1),
+                    prompt_len, rows.memory[0][last][None])
+                ran = y.shape[0]
+                for cfg in stack.blocks[at_full:]:
+                    y = block(cfg, block_params(params, cfg), y, tail_rows)
+                token = _sample_tokens(
+                    _head_logits(stack, params, y), temp[None], top_k[None],
+                    jax.random.fold_in(jax.random.key(seed), 0)[None])[0]
+                return jnp.stack([token, jnp.int32(ran)])
+
+            out = jax.lax.cond(prompt_len <= ctx, last_row,
+                               lambda _: jnp.zeros(2, jnp.int32), None)
+            return (*pools.values(), out)
+
+        return jax.jit(prefill_chunk,
+                       donate_argnums=tuple(range(1, 1 + n_state)))
+
     # -- the decode step's `attend`, by what the pool holds ------------------
     def _kv_step_attend(self, c: int, pool: list, at: "_StepRows",
                         counts_to):
@@ -1230,6 +1381,25 @@ class GenerationEngine:
                           MOE_ROW_TILE)
         return lambda li: rows
 
+    def _hybrid_step_attend(self, c: int, pool: list, at: "_StepRows",
+                            counts_to):
+        """A hybrid stack's state, one row per slot (`ops/hybrid.step_rows`
+        over the pools by name): the full layer's row lands where the
+        step's write guard put it, and it and every cross layer read the
+        shared pool through the ``shared_kv_attn`` kernel."""
+        lens = at.attend_lens.reshape(self.config.slots)
+
+        def read_shared(q, kv, kp):
+            return shared_kv_attention(
+                q, kv, at.page_tbl, lens, kv_pairs=kp,
+                impl=self.config.attention_impl,
+                interpret=self.config.attention_interpret)
+
+        rows = step_rows(_PoolView(pool, self._state_at), self._pool_index,
+                         at.positions, at.page_of, at.row_of, read_shared,
+                         _act_dtype(self.model))
+        return lambda li: rows
+
     def _make_step(self, c: int = 1):
         """The decode program: ONE dispatch advances every slot by a
         ``c``-token chunk through the paged pool.  ``c == 1`` is the
@@ -1260,6 +1430,7 @@ class GenerationEngine:
         n_pool = len(self.kv.pool())
         n_state = len(self._program_state())
         step_attend = (self._kv_step_attend if self.kv.kv_layout
+                       else self._hybrid_step_attend if self.kv.slot_rows
                        else self._row_step_attend)
         # a per-slot value, once for each of the slot's c chunk rows
         rows = lambda a: jnp.repeat(a, c, axis=0)
@@ -1425,6 +1596,8 @@ class GenerationEngine:
                 log.debug("kv spike note failed: %s", e)
             return
         req.pages = self.kv.pages_for(span)
+        if self.kv.slot_rows:
+            self.kv.claim_slot(req.rid, slot)
         if self._req_spec_k(req) > 0:
             # best-effort overhang so draft rows land in real pages;
             # a short pool (or a full page table) just means drafts
@@ -1437,7 +1610,7 @@ class GenerationEngine:
             if req.prefilled is None:
                 faults.maybe_fail("serving.prefill")
                 with self._span("generation.prefill", bucket=t_b) as sp:
-                    k, v, first, _ = self._run_prefill(req)
+                    k, v, first, _ = self._run_prefill(req, slot)
                 req.lat["prefill"] = sp.dur
                 self._trace_segment(req, "generation.prefill",
                                     sp.t0, sp.dur, bucket=t_b)
@@ -1735,6 +1908,9 @@ class GenerationEngine:
         self._slot_steps += n_live
         self._rows_attended += rows
         self._pages_attended += int((-(-attended // ps)).sum())
+        if self.kv.slot_rows:
+            self._shared_rows += rows
+            self._window_rows += int(np.minimum(live + 1, self._window).sum())
         if self._dsa_layers:
             self._count_selection(
                 np.minimum(live[:, None] + 1 + np.arange(c), cap))
@@ -2242,6 +2418,10 @@ class GenerationEngine:
             "serving_params_casts": self._params_casts,
             "dsa": {"rows_scored": self._dsa_scored,
                     "rows_selected": self._dsa_selected},
+            "hybrid": {"shared_kv_rows_attended": self._shared_rows,
+                       "window_rows_attended": self._window_rows,
+                       "prefill_rows": self._prefill_rows,
+                       "prefill_rows_skipped_cross": self._cross_skipped},
             "moe": self._moe_stats(),
             "tokens_generated": self._tokens_out,
             "tokens_per_s": round(self.tokens_per_s(), 4),
@@ -2360,7 +2540,9 @@ class GenerationEngine:
                        self._pages_attended,
                        self._params_casts, self._dsa_scored,
                        self._dsa_selected, self._overlapped,
-                       self._discarded)
+                       self._discarded, self._shared_rows,
+                       self._window_rows, self._prefill_rows,
+                       self._cross_skipped)
                 delta = [a - b for a, b in zip(now, self._counts_flushed)]
                 self._counts_flushed = now
                 drains = {r: tuple(c) for r, c in self._drains.items()}
@@ -2377,9 +2559,12 @@ class GenerationEngine:
                     was = self._moe_flushed
                     moe_delta = moe if was is None else moe - was
                     self._moe_flushed = moe
-            for family, d in zip(_FLUSHED_FAMILIES, delta):
+            *plain, skipped = delta
+            for family, d in zip(_FLUSHED_FAMILIES, plain):
                 if d > 0:
                     reg.counter(family).inc(d)
+            if skipped > 0:
+                reg.counter(PREFILL_SKIPPED_FAMILY).inc(skipped, part="cross")
             n_fam, s_fam = (reg.counter(f) for f in DECODE_DRAIN_FAMILIES)
             for reason, (n, secs) in drain_delta.items():
                 if n > 0:

@@ -27,6 +27,19 @@ under the same allocator, page tables, donation and `revive`; K/V above is
 the same thing with two rows of ``(n_heads, head_dim)`` per layer, plus
 the int8 scales and the paged kernel that only that layout has.
 
+A stack that keeps state per STREAM, whatever the stream's length, states
+it as SLOT POOLS (``slot_rows=``): for each named state the layers that
+hold one, the decode slots, its shape and its type — a Mamba layer's scan
+state and conv inputs, a window layer's ring of its last keys and values::
+
+    <name>_slots:  (layers holding it, slots, *shape)   f32 | bf16
+
+A slot pool's rows belong to a slot, not to a position: the stream seated
+in the slot owns them (`claim_slot`, given back by `release`), the prefill
+program that seats it writes them from scratch, and they are donated,
+revived and reported with the pages.  They cannot be rolled back: a scan
+state has no earlier version to return to (`truncate_to`).
+
 Position ``p`` of a request lives at row ``p % page_size`` of pool page
 ``table[p // page_size]``.  Page 0 is RESERVED as the engine's scratch
 page (idle decode slots write their garbage rows there), so the
@@ -158,11 +171,22 @@ class PagedKVCache:
 
     def __init__(self, n_layers: int = 0, n_heads: int = 0,
                  head_dim: int = 0, *, num_pages: int, page_size: int,
-                 kv_dtype: str = "f32", rows: Optional[dict] = None):
+                 kv_dtype: str = "f32", rows: Optional[dict] = None,
+                 slot_rows: Optional[dict] = None):
         """One pool per named row of ``rows`` = {name: (layers holding
         it, row shape)} (``<name>_pages``).  ``n_layers``, ``n_heads``
         and ``head_dim`` are the short form of a key and a value of
-        ``(n_heads, head_dim)`` in each of ``n_layers`` layers."""
+        ``(n_heads, head_dim)`` in each of ``n_layers`` layers.
+        ``slot_rows`` = {name: (layers holding it, slots, shape, "f32" |
+        "bf16")} adds one slot pool per name (``<name>_slots``), beside
+        row pools."""
+        self.slot_rows = {
+            name: (int(layers), int(slots), tuple(int(w) for w in shape),
+                   dtype)
+            for name, (layers, slots, shape, dtype) in (
+                slot_rows or {}).items()}
+        if self.slot_rows and rows is None:
+            raise ValueError("slot pools live beside row pools (rows=)")
         if rows is None:
             rows = {name: (n_layers, (n_heads, head_dim))
                     for name in ("k", "v")}
@@ -191,7 +215,8 @@ class PagedKVCache:
         #: the pool's arrays, as attributes, in the order of `pool()`
         self._names = (("k_pages", "v_pages", "k_scales", "v_scales")
                        if self.kv_layout else
-                       tuple(f"{name}_pages" for name in self.rows))
+                       tuple(f"{name}_pages" for name in self.rows)
+                       + tuple(f"{name}_slots" for name in self.slot_rows))
         # recompile hygiene: a page size of 13 would give every distinct
         # prompt-length bucket its own page count AND its own tail shape
         self.page_size = bucket_length(page_size, PAGE_QUANTUM)
@@ -202,6 +227,7 @@ class PagedKVCache:
         self._lock = threading.Lock()
         self._free: list[int] = list(range(self.num_pages - 1, 0, -1))
         self._tables: dict[object, list[int]] = {}
+        self._slots: dict[object, int] = {}        # rid -> slot it holds
         self._spec_extra: dict[object, int] = {}   # rid -> overhang pages
         self._alloc_failures = 0
         self._gauge_total()
@@ -213,7 +239,9 @@ class PagedKVCache:
             return tuple(
                 jnp.zeros((layers, self.num_pages, self.page_size) + row,
                           _ROW_DTYPES[self.kv_dtype])
-                for layers, row in self.rows.values())
+                for layers, row in self.rows.values()) + tuple(
+                jnp.zeros((layers, slots) + shape, _ROW_DTYPES[dtype])
+                for layers, slots, shape, dtype in self.slot_rows.values())
         shape = (self.n_layers, self.num_pages, self.page_size,
                  self.n_heads, self.head_dim)
         if self.kv_dtype != "int8":
@@ -226,9 +254,9 @@ class PagedKVCache:
 
     def pool(self) -> tuple:
         """``(k_pages, v_pages, k_scales, v_scales)`` — or one array per
-        named row — the donated arguments of every program that writes
-        the pool, in the order those programs return them (scales are
-        None for an f32 pool)."""
+        named row, then one per slot pool — the donated arguments of every
+        program that writes the pool, in the order those programs return
+        them (scales are None for an f32 pool)."""
         return tuple(getattr(self, name) for name in self._names)
 
     def rebind(self, *arrays) -> None:
@@ -279,6 +307,13 @@ class PagedKVCache:
         if self.kv_dtype == "int8":
             return elems + self.n_layers * 2 * self.n_heads * 4
         return elems * 4
+
+    def slot_pool_bytes(self) -> int:
+        """HBM bytes of the slot pools, whatever the streams' lengths."""
+        return sum(layers * slots * int(np.prod(shape))
+                   * jnp.dtype(_ROW_DTYPES[dtype]).itemsize
+                   for layers, slots, shape, dtype
+                   in self.slot_rows.values())
 
     # -- allocation --------------------------------------------------------
     def alloc(self, rid, n_pages: int) -> list[int]:
@@ -332,7 +367,9 @@ class PagedKVCache:
         an error here — speculation is optional capacity, the stream's
         admission guarantee is already funded — so exhaustion returns
         ``[]`` without counting an alloc failure or consulting the
-        ``kv.alloc`` fault site.  Returns the pages added."""
+        ``kv.alloc`` fault site.  Returns the pages added.  Pages are all
+        that speculation can reserve: rejected draft rows are rolled back
+        by `truncate_to`, and a slot pool cannot be (see there)."""
         with self._lock:
             have = len(self._tables.get(rid, ()))
             need = self.pages_for(length) - have
@@ -351,7 +388,10 @@ class PagedKVCache:
         stream whose drafter was disabled mid-flight).  The kept prefix
         is untouched — garbage rows past ``length`` inside the kept
         pages are masked by seq_len and overwritten as the stream
-        grows, exactly like plain decode's own write-ahead row.
+        grows, exactly like plain decode's own write-ahead row.  Pages
+        only: a slot pool's state has taken in every row it was handed
+        and keeps no earlier version, so a stack with slot pools has
+        nothing to truncate to (its engine refuses a drafter).
         Returns the freed pages (possibly [])."""
         keep = self.pages_for(length)
         with self._lock:
@@ -366,11 +406,18 @@ class PagedKVCache:
         self._gauge_used(used)
         return freed
 
-    def release(self, rid) -> int:
-        """Free every page ``rid`` holds (finish, cancel, watchdog
-        abort — all exits funnel here).  Idempotent; returns the number
-        of pages freed."""
+    def claim_slot(self, rid, slot: int) -> None:
+        """``rid``, which holds pages, is seated in decode slot ``slot``:
+        the slot pools' rows of that slot are its own until `release`."""
         with self._lock:
+            self._slots[rid] = int(slot)
+
+    def release(self, rid) -> int:
+        """Free every page ``rid`` holds, and its slot (finish, cancel,
+        watchdog abort — all exits funnel here).  Idempotent; returns the
+        number of pages freed."""
+        with self._lock:
+            self._slots.pop(rid, None)
             pages = self._tables.pop(rid, None)
             self._spec_extra.pop(rid, None)
             if pages:
@@ -417,6 +464,13 @@ class PagedKVCache:
                 # what a token caches: row name -> [layers, *row shape]
                 "rows": {name: [layers, *row]
                          for name, (layers, row) in self.rows.items()},
+                # what a stream keeps whatever its length: name ->
+                # [layers, slots, *shape], their bytes, the slots held
+                "slot_rows": {name: [layers, slots, *shape]
+                              for name, (layers, slots, shape, _)
+                              in self.slot_rows.items()},
+                "slot_pool_bytes": self.slot_pool_bytes(),
+                "slots_held": len(self._slots),
             }
 
     def leak_check(self) -> Optional[str]:
@@ -432,6 +486,11 @@ class PagedKVCache:
                 return "page both free and owned"
             if SCRATCH_PAGE in seen:
                 return "scratch page handed out"
+            held = list(self._slots.values())
+            if len(set(held)) != len(held):
+                return "slot held by two streams"
+            if set(self._slots) - set(self._tables):
+                return "slot held by a stream that holds no page"
             total = len(self._free) + len(owned)
             if total != self.num_pages - 1:
                 return (f"{self.num_pages - 1 - total} page(s) leaked "

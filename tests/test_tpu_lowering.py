@@ -17,6 +17,7 @@ import pytest
 from deeplearning4j_tpu.ops import dequant_matmul as dm
 from deeplearning4j_tpu.ops import flash_attention as fa
 from deeplearning4j_tpu.ops import paged_attention as pa
+from deeplearning4j_tpu.ops import shared_kv_attention as skv
 
 # the smoke's shapes: flash (B, T, H, D); paged S slots of H x Dh heads
 # over 16-row pages, 34 pages/seq; dequant (M, K) @ (K, N)
@@ -155,6 +156,25 @@ def test_paged_call_is_one_kernel_at_serve_chat(c):
         f, sds((slots, c, 16, 128), jnp.float32), pool, pool,
         sds((slots, pages), jnp.int32), sds((slots, c), jnp.int32))
     assert exported.mlir_module().count("@tpu_custom_call") == 1
+
+
+def test_shared_kv_attn_is_one_kernel_at_phi4flash_reason_sat():
+    """The `phi4flash_reason_sat` cell's call — 32 slots of 20 query pairs x
+    64 over the shared (1, 3585, 64, 2560) bf16 pool of 10 key pairs and
+    their values, 112 pages a slot — is ONE Mosaic call named
+    `shared_kv_attn` (the roofline reader counts decode steps as its calls
+    over the layers that read the pool)."""
+    exported = lower_for_tpu(
+        lambda q, pool, tbl, lens: skv.shared_kv_attention(
+            q, pool, tbl, lens, kv_pairs=10, impl="pallas",
+            interpret=False),
+        sds((32, 20, 2, 64), jnp.bfloat16),
+        sds((1, 3585, 64, 2560), jnp.bfloat16),
+        sds((32, 112), jnp.int32), sds((32,), jnp.int32))
+    text = exported.mlir_module()
+    assert text.count("@tpu_custom_call") == 1
+    assert "shared_kv_attn" in text
+    assert [a.shape for a in exported.out_avals] == [(32, 20, 2, 128)]
 
 
 @pytest.mark.parametrize("m", [1, 8, 256])
