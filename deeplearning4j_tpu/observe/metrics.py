@@ -839,6 +839,15 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "The held assignments by layer and expert (expert = "
                 "its index among all routed experts): the load each "
                 "held expert saw")
+    reg.counter("dl4jtpu_dsa_prefill_tiles_total",
+                "Tiles of the latent prefill's score plane times head "
+                "groups that the dsa_prefill_attn kernel ran (state=run: "
+                "the selection keeps a pair of the tile) and skipped "
+                "(state=skipped: it keeps none, so no copy and no "
+                "product), per layer and chunk; counted on the device "
+                "into the array the programs carry, read at scrape.  "
+                "run over run + skipped is the share of the dense plane "
+                "still computed")
     reg.gauge("dl4jtpu_kv_pages_used",
               "KV pool pages currently owned by live streams "
               "(page 0, the scratch page, never counts)")

@@ -137,13 +137,16 @@ from deeplearning4j_tpu.ops.hybrid import (
     prefill_rows,
     step_rows,
 )
+from deeplearning4j_tpu.ops.dsa_prefill_attention import (
+    carry,
+    dsa_prefill_attention,
+    tile_counts,
+)
 from deeplearning4j_tpu.ops.latent import (
     MOE_ROW_TILE,
     LatentRows,
-    attend_expanded,
     attend_gathered,
     blocked,
-    expand_latents,
     index_scores,
     kth_largest,
     topk_mask,
@@ -241,7 +244,7 @@ DSA_COUNT_FAMILIES = ("dl4jtpu_dsa_rows_scored_total",
                       "dl4jtpu_dsa_rows_selected_total")
 
 #: expert assignments of the rows served, counted ON THE DEVICE into a
-#: small int32 array the programs carry (`_moe_counts`) and read only on
+#: small int32 array the programs carry (`_device_counts`) and read only on
 #: demand: all of them by whether the expert is held here (``held``), and
 #: the held ones by layer and expert
 MOE_ASSIGNMENTS_FAMILY = "dl4jtpu_moe_assignments_total"
@@ -254,8 +257,14 @@ MOE_EXPERT_FAMILY = "dl4jtpu_moe_expert_assignments_total"
 #: temporaries and two pool copies a step at 576, none at 640)
 ROW_LANES = 128
 
-#: query rows per block of a latent prefill chunk's attention: bounds the
-#: f32 scores a program holds to (heads, this, context) at a time
+#: the latent prefill kernel's tiles, counted ON THE DEVICE into the same
+#: array, by state: ``run`` (a tile the selection keeps any pair of, times
+#: head groups) and ``skipped`` (one it keeps none of: no copy, no product)
+DSA_PREFILL_TILES_FAMILY = "dl4jtpu_dsa_prefill_tiles_total"
+
+#: query rows per block of a prefill chunk's indexer scores (latent) and
+#: attention (hybrid): bounds the f32 scores a program holds to (heads,
+#: this, context) at a time
 PREFILL_QUERY_BLOCK = 256
 
 #: serving copies of the parameter tree made (`_serving_params`): one per
@@ -613,14 +622,17 @@ class GenerationEngine:
         # where each named pool sits among the program state's arrays
         self._state_at = {name: i for i, name in enumerate(
             list(self.kv.rows) + list(self.kv.slot_rows))}
-        # expert layers: their place in the device-side assignment counts
+        # expert layers: their place in the device counts; a stack with an
+        # indexer: the row of its prefill kernel's tiles
         self._moe_index = {
             b.name: i for i, b in enumerate(
                 b for b in stack.blocks if getattr(b, "ffn", "") == "sparse")}
-        self._moe_counts = self._fresh_moe_counts()
-        self._moe_flushed = None
         self._dsa_layers = sum(getattr(b, "indexer", "") == "full"
                                for b in stack.blocks)
+        self._tiles_row = (len(self._moe_index) if self._dsa_layers
+                           else None)
+        self._device_counts = self._fresh_device_counts()
+        self._device_flushed = None
         self._dsa_topk = max((b.index_topk for b in stack.blocks
                               if getattr(b, "indexer", "") == "full"),
                              default=0)
@@ -1090,32 +1102,38 @@ class GenerationEngine:
     # -- what every program carries ---------------------------------------
     def _program_state(self) -> tuple:
         """The device arrays every program that writes them takes DONATED
-        and returns first: the pool, and the expert assignment counts
-        where the stack has expert layers."""
-        extra = () if self._moe_counts is None else (self._moe_counts,)
+        and returns first: the pool, and the device counts where the
+        stack keeps any (`_fresh_device_counts`)."""
+        extra = (() if self._device_counts is None
+                 else (self._device_counts,))
         return self.kv.pool() + extra
 
     def _rebind_state(self, out) -> None:
         n = len(self.kv.pool())
         self.kv.rebind(*out[:n])
-        if self._moe_counts is not None:
-            self._moe_counts = out[n]
+        if self._device_counts is not None:
+            self._device_counts = out[n]
 
-    def _fresh_moe_counts(self):
-        if not self._moe_index:
+    def _fresh_device_counts(self):
+        """What the programs count on the device, int32: a row per expert
+        layer (its assignments to each held expert, then those to experts
+        held elsewhere) and, for a stack with an indexer, one more:
+        the prefill kernel's tiles ``[run, skipped]`` (`_tiles_row`)."""
+        rows = len(self._moe_index) + (self._tiles_row is not None)
+        if not rows:
             return None
-        held = self._stack.final._held()
-        return jnp.zeros((len(self._moe_index), held + 1), jnp.int32)
+        width = self._stack.final._held() + 1 if self._moe_index else 2
+        return jnp.zeros((rows, width), jnp.int32)
 
     def _revive_state(self, wait: bool = False) -> bool:
         """After a failed dispatch: the pool anew if it was consumed, and
-        the assignment counts with it (they restart at zero).  True when
+        the device counts with it (they restart at zero).  True when
         the pool was made anew: no cached row of any stream survives."""
         dead = self.kv.revive(wait=wait)
-        c = self._moe_counts
+        c = self._device_counts
         if c is not None and c.is_deleted():
-            self._moe_counts = self._fresh_moe_counts()
-            self._moe_flushed = None
+            self._device_counts = self._fresh_device_counts()
+            self._device_flushed = None
         return dead
 
     def _run_blocks(self, params, x, attend_of):
@@ -1126,11 +1144,11 @@ class GenerationEngine:
         return x
 
     def _counts_sink(self, state):
-        """The expert assignment counts a program carries — the last of
-        its donated arguments ``state`` where the stack has expert layers
-        — as a one-element list (empty without) and the ``counts_to`` that
-        adds a layer's counts into it."""
-        counts = [state[-1]] if self._moe_counts is not None else []
+        """The device counts a program carries — the last of its donated
+        arguments ``state`` where the stack counts any — as a one-element
+        list (empty without) and the ``counts_to`` that adds an expert
+        layer's assignment counts into it."""
+        counts = [state[-1]] if self._device_counts is not None else []
 
         def counts_to(cfg, new):
             counts[0] = counts[0].at[self._moe_index[cfg.name]].add(new)
@@ -1147,9 +1165,11 @@ class GenerationEngine:
         written into the stream's pages, the context ``[0, (ci + 1) * C)``
         is read back through the page table, a full layer scores it with
         the indexer and keeps each query's exact top ``index_topk`` as a
-        mask (carried to the shared layers after it), and the chunk
-        attends under that mask in blocks of `PREFILL_QUERY_BLOCK`
-        queries: no (heads, T, T) array exists.  Rows past the prompt's
+        mask (carried to the shared layers after it, with the bitmap of
+        the tiles it keeps any pair of), and the chunk attends under that
+        mask through `ops/dsa_prefill_attention` — the ``dsa_prefill_attn``
+        kernel on a TPU, which counts its tiles run and skipped into the
+        device counts: no (heads, T, T) array exists.  Rows past the prompt's
         end are pad: causal attention keeps them from every real row, and
         the decode step overwrites their cached rows as the stream grows.
         Every chunk returns the token sampled after row ``prompt_len - 1``;
@@ -1160,6 +1180,7 @@ class GenerationEngine:
         new_pg = slice(start // ps, ctx // ps)
         n_state = len(self._program_state())
         bq = PREFILL_QUERY_BLOCK
+        interp = self.config.attention_interpret
 
         def prefill_chunk(params, *rest):
             state = rest[:n_state]
@@ -1193,16 +1214,19 @@ class GenerationEngine:
                                          k_i)[:, :k_i.shape[-1]]
                     seen = jnp.arange(ctx)
                     with jax.named_scope("dsa_index"):
-                        carried[:] = [blocked(
+                        carried[:] = carry(cfg, blocked(
                             lambda qb, wb, pb: topk_mask(
                                 index_scores(qb, wb, keys),
                                 seen[None, :] <= pb[:, None],
                                 cfg.index_topk),
-                            bq, q_i, w, positions)]
-                expanded = expand_latents(cfg, context, wkvb)
-                return blocked(
-                    lambda qb, mb: attend_expanded(cfg, qb, *expanded, mb),
-                    bq, q, carried[0])
+                            bq, q_i, w, positions),
+                            self.config.attention_impl)
+                mask, live = carried
+                if live is not None:
+                    counts[0] = counts[0].at[self._tiles_row, :2].add(
+                        tile_counts(live, cfg.n_heads))
+                return dsa_prefill_attention(cfg, q, context, wkvb, mask,
+                                             live, interpret=interp)
 
             counts, counts_to = self._counts_sink(state)
             rows = LatentRows(attend, positions, positions < prompt_len,
@@ -2388,6 +2412,7 @@ class GenerationEngine:
             slow_n = len(self._slow)
             spec = dict(self._spec_counts)
         total_s = sum(totals.values())
+        counts = self._device_counts_host()
         # per-token normalization: a speculative step emits 1..k+1
         # tokens per dispatch, so cross-config comparisons read the
         # seconds_per_token view, not raw segment walls
@@ -2422,7 +2447,8 @@ class GenerationEngine:
                        "window_rows_attended": self._window_rows,
                        "prefill_rows": self._prefill_rows,
                        "prefill_rows_skipped_cross": self._cross_skipped},
-            "moe": self._moe_stats(),
+            "moe": self._moe_stats(counts),
+            "dsa_prefill_tiles": self._tiles_stats(counts),
             "tokens_generated": self._tokens_out,
             "tokens_per_s": round(self.tokens_per_s(), 4),
             "streams": {"settled": settled, "outcomes": outcomes},
@@ -2453,14 +2479,13 @@ class GenerationEngine:
         }
         return out
 
-    def _moe_counts_host(self):
-        """The device-side assignment counts, read now: (expert layers,
-        held experts + 1) int64, the last column the assignments that
-        fell on experts held elsewhere; None for a stack without expert
-        layers.  The engine thread donates the array with every dispatch,
-        so a reader that catches it mid-step tries again."""
+    def _device_counts_host(self):
+        """The device counts (`_fresh_device_counts`), read now, int64;
+        None for a stack that counts nothing on the device.  The engine
+        thread donates the array with every dispatch, so a reader that
+        catches it mid-step tries again."""
         for _ in range(50):
-            a = self._moe_counts
+            a = self._device_counts
             if a is None:
                 return None
             try:
@@ -2469,13 +2494,22 @@ class GenerationEngine:
                 time.sleep(0.002)
         return None
 
-    def _moe_stats(self) -> Optional[dict]:
-        counts = self._moe_counts_host()
-        if counts is None:
+    def _moe_stats(self, counts) -> Optional[dict]:
+        if counts is None or not self._moe_index:
             return None
+        counts = counts[:len(self._moe_index)]
         return {"assignments_held": int(counts[:, :-1].sum()),
                 "assignments_elsewhere": int(counts[:, -1].sum()),
                 "expert_assignments": counts[:, :-1].tolist()}
+
+    def _tiles_stats(self, counts) -> Optional[dict]:
+        """The latent prefill kernel's (tile, head group) steps, process
+        totals: ``run`` and ``skipped``; over their sum, ``run`` is the
+        share of the dense score plane still computed."""
+        if counts is None or self._tiles_row is None:
+            return None
+        run, skipped = counts[self._tiles_row, :2].tolist()
+        return {"run": run, "skipped": skipped}
 
     def health_summary(self) -> dict:
         """Compact generation block for `InferenceServer.health()` —
@@ -2534,7 +2568,7 @@ class GenerationEngine:
             from deeplearning4j_tpu.observe.metrics import registry
 
             reg = registry()
-            moe = self._moe_counts_host()
+            dev = self._device_counts_host()
             with self._stats_lock:
                 now = (self._steps, self._slot_steps, self._rows_attended,
                        self._pages_attended,
@@ -2555,10 +2589,10 @@ class GenerationEngine:
                 sampler_delta = {b: n - self._sampler_flushed[b]
                                  for b, n in sampler.items()}
                 self._sampler_flushed = sampler
-                if moe is not None:
-                    was = self._moe_flushed
-                    moe_delta = moe if was is None else moe - was
-                    self._moe_flushed = moe
+                if dev is not None:
+                    was = self._device_flushed
+                    dev_delta = dev if was is None else dev - was
+                    self._device_flushed = dev
             *plain, skipped = delta
             for family, d in zip(_FLUSHED_FAMILIES, plain):
                 if d > 0:
@@ -2575,12 +2609,18 @@ class GenerationEngine:
             for branch, n in sampler_delta.items():
                 if n > 0:
                     b_fam.inc(n, branch=branch)
-            if moe is not None:
+            if dev is not None and self._tiles_row is not None:
+                tiles = reg.counter(DSA_PREFILL_TILES_FAMILY)
+                for state, d in zip(("run", "skipped"),
+                                    dev_delta[self._tiles_row, :2]):
+                    if d > 0:
+                        tiles.inc(int(d), state=state)
+            if dev is not None and self._moe_index:
                 blocks = [b for b in self._stack.blocks
                           if b.name in self._moe_index]
                 total = reg.counter(MOE_ASSIGNMENTS_FAMILY)
                 per = reg.counter(MOE_EXPERT_FAMILY)
-                for b, row in zip(blocks, moe_delta):
+                for b, row in zip(blocks, dev_delta):
                     for e, d in enumerate(row[:-1]):
                         if d > 0:
                             per.inc(int(d), layer=b.name,

@@ -315,6 +315,65 @@ def test_two_streams_of_unequal_length_share_a_step(engine, model,
                                                    - steps[1])
 
 
+def _tiles_by_hand(model, prompt, c=16, b=8):
+    """[run, skipped] of the prefill kernel over one prompt: per chunk and
+    layer, the b x b tiles of the chunk's (c, context) selection that keep a
+    pair — the selections are the reference's own, over the prompt padded
+    to whole chunks as the engine pads it; layer 0 and the three shared
+    layers after it use the first full layer's, layer 4 its own; one head
+    group (4 heads)."""
+    t_b = -(-len(prompt) // c) * c
+    padded = np.zeros(t_b, np.int32)
+    padded[:len(prompt)] = prompt
+    picks = []
+    with jax.default_matmul_precision("highest"):
+        FAMILY.reference_hidden(TINY, model.params, jnp.asarray(padded),
+                                selections=picks)
+    run = skipped = 0
+    for ci in range(t_b // c):
+        for full in (0, 0, 0, 0, 1):
+            sel = np.asarray(picks[full])[ci * c:(ci + 1) * c,
+                                          :(ci + 1) * c]
+            live = sel.reshape(c // b, b, (ci + 1) * c // b, b).any(
+                axis=(1, 3))
+            run += int(live.sum())
+            skipped += int(live.size - live.sum())
+    return run, skipped
+
+
+def test_prefill_kernel_emits_the_xla_paths_tokens_and_counts_its_tiles(
+        engine, model, reference):
+    """The ``dsa_prefill_attn`` kernel (interpret mode) in the engine's
+    chunk programs: two streams of unequal length, each over two chunks,
+    emit the tokens of the ``xla`` path and of the reference, and the
+    engine reports the tiles the kernel ran and skipped as counted by
+    hand from the reference's selections."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, VOCAB, t, dtype=np.int32) for t in (21, 30)]
+    eng = GenerationEngine(model=model, config=GenerationConfig(
+        **ENGINE, attention_impl="pallas", attention_interpret=True)).start()
+    try:
+        before = eng.stats()["dsa_prefill_tiles"]
+        reqs = [eng.submit(p, 6) for p in prompts]
+        rows = [r.result(timeout=300) for r in reqs]
+        after = eng.stats()["dsa_prefill_tiles"]
+        assert eng.drain(timeout=60)
+        assert eng.kv.used_pages == 0 and eng.kv.leak_check() is None
+    finally:
+        eng.stop()
+    for p, row in zip(prompts, rows):
+        assert (row == engine.generate(p, 6, timeout=300)).all()
+        assert _gap(reference, model, row, len(p)) < 1e-4
+    by_hand = np.sum([_tiles_by_hand(model, p) for p in prompts], axis=0)
+    assert [after[s] - before[s] for s in ("run", "skipped")] == (
+        by_hand.tolist())
+    # each chunk of each layer has one causal-dead tile; the selection
+    # leaves some more empty
+    assert by_hand[1] > 2 * 5 * 2
+    # the xla path runs no kernel and counts nothing
+    assert engine.stats()["dsa_prefill_tiles"] == {"run": 0, "skipped": 0}
+
+
 def test_speculative_verify_chunk_emits_the_plain_tokens(model, engine):
     prompt = np.tile(np.arange(7, dtype=np.int32), 6)          # 42, repeats
     plain = engine.generate(prompt, 8, timeout=300)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from deeplearning4j_tpu.ops import dequant_matmul as dm
+from deeplearning4j_tpu.ops import dsa_prefill_attention as dpa
 from deeplearning4j_tpu.ops import flash_attention as fa
 from deeplearning4j_tpu.ops import paged_attention as pa
 from deeplearning4j_tpu.ops import shared_kv_attention as skv
@@ -175,6 +176,32 @@ def test_shared_kv_attn_is_one_kernel_at_phi4flash_reason_sat():
     assert text.count("@tpu_custom_call") == 1
     assert "shared_kv_attn" in text
     assert [a.shape for a in exported.out_avals] == [(32, 20, 2, 128)]
+
+
+@pytest.mark.parametrize("ci", [0, 5])
+def test_dsa_prefill_attn_is_one_kernel_at_glm52_longdoc_sat(ci):
+    """The `glm52_longdoc_sat` cell's prefill chunks 0 and 5 — 2,048
+    queries of 64 heads x (192 + 64) over a context of 2,048 or 12,288
+    latent rows (stored 640 wide), under the (2,048, context) selection —
+    attend in ONE Mosaic call named `dsa_prefill_attn` a layer (the
+    busy-share reader finds it by that name), bf16 in and out."""
+    import types
+
+    ctx = (ci + 1) * 2048
+    cfg = types.SimpleNamespace(
+        n_heads=64, qk_nope_head_dim=192, qk_rope_head_dim=64,
+        v_head_dim=256, kv_lora_rank=512, softmax_scale=256 ** -0.5)
+    exported = lower_for_tpu(
+        lambda q, lat, wkvb, mask: dpa.dsa_prefill_attention(
+            cfg, q, lat, wkvb, *dpa.carry(cfg, mask, "pallas"),
+            interpret=False),
+        sds((2048, 64, 256), jnp.bfloat16), sds((ctx, 640), jnp.bfloat16),
+        sds((512, 64 * 448), jnp.bfloat16), sds((2048, ctx), jnp.bool_))
+    text = exported.mlir_module()
+    assert text.count("@tpu_custom_call") == 1
+    assert "dsa_prefill_attn" in text
+    assert [(a.shape, a.dtype) for a in exported.out_avals] == [
+        ((2048, 64 * 256), jnp.bfloat16)]
 
 
 @pytest.mark.parametrize("m", [1, 8, 256])
