@@ -389,6 +389,15 @@ class TransformerEncoderBlock(LayerConfig):
         return x + h, state
 
 
+#: the slot pool of the window layers' latent rings
+LATENT_RING = "latent_ring"
+
+#: what a `LatentSparseDecoder` layer's ``indexer_types`` entry may say: it
+#: holds an indexer and selects (``full``), attends the selection of the
+#: nearest full layer before it (``shared``), or attends every row of its
+#: causal window with no selection (``none``)
+INDEXER_KINDS = ("full", "shared", "none")
+
 @dataclasses.dataclass(frozen=True)
 class LatentBlock:
     """One layer of a `LatentSparseDecoder`, as `ops/latent.latent_block`
@@ -417,18 +426,61 @@ class LatentBlock:
     routed_scale: float
     held_first: int
     causal: bool = True
+    window: int = 0               # 0: every row before; else the last n
+    n_kv_groups: int = 0          # 0: one key/value group per head
+    n_noise_heads: int = 0
+    output_gate: bool = False
+    ffn_act: str = "silu"         # "silu" | "polynorm"
+    polynorm_scale: float = 1.0
+    polynorm_clamp: float = 0.5
+    residual: str = "plain"       # "plain" | "mhc"
+    mhc_streams: int = 1
+    mhc_iters: int = 0
+    hidden_clamp: float = 0.0
+    rope_factor: float = 1.0      # > 1: YaRN frequencies on the full layers
+    rope_original: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    swa_rope_theta: float = 0.0   # the window layers' theta (0: rope_theta)
 
     @property
     def softmax_scale(self) -> float:
         return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
 
     @property
+    def latent_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def groups(self) -> int:
+        """Key/value groups: ``W_kvb`` makes one key and one value each."""
+        return self.n_kv_groups or self.n_heads
+
+    @property
+    def signal_heads(self) -> int:
+        """Query heads whose outputs go on to ``W_o``; the noise heads
+        after them are only taken away from them."""
+        return self.n_heads - self.n_noise_heads
+
+    @property
     def cache_rows(self) -> dict:
-        """What one token caches in this layer: pool name -> row width."""
-        rows = {"latent": self.kv_lora_rank + self.qk_rope_head_dim}
+        """What one token caches in this layer's pages: pool name -> row
+        width.  A window layer keeps its rows in a ring per stream
+        instead (`slot_rows`)."""
+        if self.window:
+            return {}
+        rows = {"latent": self.latent_width}
         if self.indexer == "full":
             rows["index_key"] = self.index_head_dim
         return rows
+
+    @property
+    def slot_rows(self) -> dict:
+        """What a window layer keeps per stream: a ring of its last
+        ``window`` latent rows, row ``p mod window`` holding position p."""
+        if not self.window:
+            return {}
+        return {LATENT_RING: ((self.window, self.latent_width), "kv")}
 
 
 @serde.register
@@ -441,14 +493,28 @@ class LatentSparseDecoder(LayerConfig):
     ``norm_f``.  Equations and names: `ops/latent.py`, `ops/moe.py`.
 
     ``indexer_types[i]`` is ``"full"`` (the layer holds an indexer and
-    selects each query's ``index_topk`` rows) or ``"shared"`` (it attends
-    the selection of the nearest full layer before it); ``mlp_types[i]`` is
-    ``"dense"`` (gated SiLU FFN of width ``d_ff``) or ``"sparse"``
+    selects each query's ``index_topk`` rows), ``"shared"`` (it attends
+    the selection of the nearest full layer before it) or ``"none"`` (no
+    selection: every row of its causal window); ``mlp_types[i]`` is
+    ``"dense"`` (gated FFN of width ``d_ff``) or ``"sparse"``
     (``n_routed`` sigmoid-routed experts of width ``moe_d_ff``, ``top_k`` a
     token, plus one shared expert).  ``n_held`` > 0 makes this the share
     of ONE chip of an expert-parallel deployment: the router keeps all
     ``n_routed`` outputs and the layer holds, and computes, experts
     ``[held_first, held_first + n_held)`` only.
+
+    The rest of the block's parts are data too, each off by default (the
+    `glm_moe_dsa` block): ``windows[i]`` > 0 gives layer i a causal window
+    of that many rows (its rows then live in a per-stream ring, not in
+    pages); ``n_kv_groups`` makes ``W_kvb`` one key and value per group of
+    query heads; ``n_noise_heads`` (one per group) makes the attention
+    differential, ``O_i - sigmoid(h W_lam)_i O_noise(group i)``;
+    ``output_gate`` multiplies the heads' outputs by ``sigmoid(h W_gate)``
+    before ``W_o``; ``ffn_act`` is ``"silu"`` or ``"polynorm"``; ``residual
+    "mhc"`` carries ``mhc_streams`` residual streams mixed around every
+    sublayer (manifold-constrained hyper-connections, ``mhc_iters``
+    Sinkhorn iterations); ``rope_factor`` > 1 gives the full layers YaRN
+    frequencies and ``swa_rope_theta`` the window layers their own theta.
 
     `apply` is the whole-sequence form in plain differentiable
     `jax.numpy` (the selection is a constant of the backward pass);
@@ -476,44 +542,87 @@ class LatentSparseDecoder(LayerConfig):
     routed_scale: float = 1.0
     held_first: int = 0
     n_held: int = 0                      # 0: every routed expert
+    windows: tuple[int, ...] = ()        # per layer; () or 0: no window
+    n_kv_groups: int = 0
+    n_noise_heads: int = 0
+    output_gate: bool = False
+    ffn_act: str = "silu"
+    polynorm_scale: float = 1.0
+    polynorm_clamp: float = 0.5
+    residual: str = "plain"
+    mhc_streams: int = 1
+    mhc_iters: int = 0
+    hidden_clamp: float = 0.0
+    rope_factor: float = 1.0
+    rope_original: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    swa_rope_theta: float = 0.0
 
     EXPECTS = "rnn"
     REGULARIZED = ()
 
     def __post_init__(self):
         super().__post_init__()
-        for f in ("indexer_types", "mlp_types"):
+        for f in ("indexer_types", "mlp_types", "windows"):
             object.__setattr__(self, f, tuple(getattr(self, f)))
         if len(self.indexer_types) != len(self.mlp_types):
             raise ValueError("indexer_types and mlp_types name the same "
                              "layers: their lengths differ")
-        if self.indexer_types and self.indexer_types[0] != "full":
-            raise ValueError("the first layer must hold an indexer "
-                             "(\"full\"): a shared layer has no selection "
-                             "before it to reuse")
-        bad = (set(self.indexer_types) - {"full", "shared"}
-               | set(self.mlp_types) - {"dense", "sparse"})
+        if self.windows and len(self.windows) != len(self.indexer_types):
+            raise ValueError("windows names the same layers as "
+                             "indexer_types: their lengths differ")
+        for i, ix in enumerate(self.indexer_types):
+            if ix == "shared" and "full" not in self.indexer_types[:i]:
+                raise ValueError(
+                    f"layer {i} is \"shared\": a shared layer attends the "
+                    "selection of a \"full\" layer before it, and there is "
+                    "none")
+        bad = (set(self.indexer_types) - set(INDEXER_KINDS)
+               | set(self.mlp_types) - {"dense", "sparse"}
+               | {self.ffn_act} - {"silu", "polynorm"}
+               | {self.residual} - {"plain", "mhc"})
         if bad:
             raise ValueError(f"unknown layer kinds {sorted(bad)}")
-        if self.qk_rope_head_dim % 2 or (
-                self.index_head_dim < self.qk_rope_head_dim):
+        if self.qk_rope_head_dim % 2 or ("full" in self.indexer_types and (
+                self.index_head_dim < self.qk_rope_head_dim)):
             raise ValueError("qk_rope_head_dim must be even and at most "
                              "index_head_dim")
+        g = self.n_kv_groups or self.n_heads
+        if (self.n_heads - self.n_noise_heads) % g or (
+                self.n_noise_heads not in (0, g)):
+            raise ValueError(
+                f"{self.n_heads} query heads, {self.n_noise_heads} of them "
+                f"noise heads, over {g} key/value groups: the signal heads "
+                "must split evenly over the groups, with one noise head "
+                "per group or none")
+        if any(w and ix != "none" for w, ix in zip(self.windows,
+                                                   self.indexer_types)):
+            raise ValueError("a window layer selects nothing: its "
+                             "indexer_types entry must be \"none\"")
 
     def _held(self) -> int:
         return self.n_held or self.n_routed
+
+    @property
+    def stream_width(self) -> int:
+        """Width of a row between the layers: the residual streams side by
+        side (``mhc``), or the model width."""
+        return self.d_model * (self.mhc_streams if self.residual == "mhc"
+                               else 1)
 
     def blocks(self) -> tuple:
         shared = {f.name: getattr(self, f.name)
                   for f in dataclasses.fields(LatentBlock)
                   if f.name in {g.name for g in dataclasses.fields(self)}
                   and f.name != "name"}
+        windows = self.windows or (0,) * len(self.indexer_types)
         return tuple(
             LatentBlock(name=f"{self.name}.layer{i:02d}",
                         path=(self.name, f"layer{i:02d}"),
-                        indexer=ix, ffn=mlp, **shared)
-            for i, (ix, mlp) in enumerate(zip(self.indexer_types,
-                                              self.mlp_types)))
+                        indexer=ix, ffn=mlp, window=int(w), **shared)
+            for i, (ix, mlp, w) in enumerate(zip(
+                self.indexer_types, self.mlp_types, windows)))
 
     def output_type(self, itype: InputType) -> InputType:
         if itype.size != self.d_model:
@@ -534,11 +643,24 @@ class LatentSparseDecoder(LayerConfig):
         def mat(k, *shape):
             return wi.init(k, shape, fan_in=shape[-2], fan_out=shape[-1])
 
+        g_, hs = self.n_kv_groups or h_, h_ - self.n_noise_heads
+
         def ffn(k, width, lead=()):
             kg, ku, kd = jax.random.split(k, 3)
-            return {"Wg": mat(kg, *lead, d, width),
-                    "Wu": mat(ku, *lead, d, width),
-                    "Wd": mat(kd, *lead, width, d)}
+            out = {"Wg": mat(kg, *lead, d, width),
+                   "Wu": mat(ku, *lead, d, width),
+                   "Wd": mat(kd, *lead, width, d)}
+            if self.ffn_act == "polynorm":
+                # PolyNorm's weights start at 1/3 each, its bias at 0
+                out["poly_w"] = jnp.full(lead + (3,), 1 / 3, jnp.float32)
+                out["poly_b"] = jnp.zeros(lead, jnp.float32)
+            return out
+
+        def mhc(k):
+            s = self.mhc_streams
+            w, m = s * d, s * (2 + s)
+            return {"gamma": ones(w), "phi": mat(k, w, m),
+                    "alpha": ones(3), "b": jnp.zeros((m,), jnp.float32)}
 
         params = {"norm_f": ones(d)}
         for cfg, k in zip(self.blocks(),
@@ -549,8 +671,17 @@ class LatentSparseDecoder(LayerConfig):
                 "Wqa": mat(k1, d, dq), "q_norm": ones(dq),
                 "Wqb": mat(k2, dq, h_ * (dn + dr)),
                 "Wkva": mat(k3, d, lk + dr), "kv_norm": ones(lk),
-                "Wkvb": mat(k4, lk, h_ * (dn + dv)),
-                "Wo": mat(k5, h_ * dv, d)}}
+                "Wkvb": mat(k4, lk, g_ * (dn + dv)),
+                "Wo": mat(k5, hs * dv, d)}}
+            # the parts GLM's block lacks draw from keys of their own, so
+            # its weights are the same whether they exist or not
+            kx = jax.random.split(jax.random.fold_in(k, 1), 4)
+            if self.n_noise_heads:
+                lp["attn"]["W_lam"] = mat(kx[0], d, hs)
+            if self.output_gate:
+                lp["attn"]["W_gate"] = mat(kx[1], d, hs * dv)
+            if self.residual == "mhc":
+                lp["mhc_attn"], lp["mhc_ffn"] = mhc(kx[2]), mhc(kx[3])
             if cfg.indexer == "full":
                 k1, k2, k3 = jax.random.split(ki, 3)
                 lp["indexer"] = {
@@ -572,14 +703,16 @@ class LatentSparseDecoder(LayerConfig):
 
     def apply(self, params, state, x, *, training=False, rng=None):
         from deeplearning4j_tpu.ops.latent import (
-            LatentRows, latent_block, rms_norm, sequence_attend,
+            LatentRows, final_rows, first_rows, latent_block,
+            sequence_attend,
         )
 
         def one(x):
             rows = LatentRows(sequence_attend(), jnp.arange(x.shape[0]))
+            x = first_rows(self, x)
             for cfg in self.blocks():
                 x = latent_block(cfg, params[cfg.path[-1]], x, rows)
-            return rms_norm(x, params["norm_f"], self.rms_eps)
+            return final_rows(self, params, x)
 
         # one sequence at a time: the grouped product has no batch rule
         return jax.lax.map(one, x), state

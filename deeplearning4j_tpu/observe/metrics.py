@@ -842,12 +842,21 @@ def _declare_core(reg: MetricsRegistry) -> None:
     reg.counter("dl4jtpu_dsa_prefill_tiles_total",
                 "Tiles of the latent prefill's score plane times head "
                 "groups that the dsa_prefill_attn kernel ran (state=run: "
-                "the selection keeps a pair of the tile) and skipped "
-                "(state=skipped: it keeps none, so no copy and no "
-                "product), per layer and chunk; counted on the device "
-                "into the array the programs carry, read at scrape.  "
-                "run over run + skipped is the share of the dense plane "
-                "still computed")
+                "the selection, the causal mask or the window keeps a "
+                "pair of the tile) and skipped (state=skipped: it keeps "
+                "none, so no copy and no product), per layer and chunk, "
+                "by the layer's kind (kind=full: its keys are the whole "
+                "context; kind=window: its ring and the chunk's rows); "
+                "counted on the device into the array the programs carry, "
+                "read at scrape.  run over run + skipped is the share of "
+                "the dense plane still computed")
+    reg.counter("dl4jtpu_latent_rows_attended_total",
+                "Latent rows the decode steps' layers that select nothing "
+                "attended, summed over the layers of each kind: kind=full "
+                "(the slot's whole context, seq_len + 1, read in place by "
+                "latent_paged_attn) and kind=window (the ring's "
+                "min(seq_len + 1, window)); counted on the device into "
+                "the array the programs carry, read at scrape")
     reg.gauge("dl4jtpu_kv_pages_used",
               "KV pool pages currently owned by live streams "
               "(page 0, the scratch page, never counts)")

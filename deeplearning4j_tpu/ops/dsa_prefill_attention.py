@@ -47,6 +47,16 @@ Bytes per layer and chunk at ctx 12,288: keys and values 0.81 GB, read once
 per query block (4 x) less the dead tiles; the mask 25 MB read once per
 head group (8 x), 1/16 of the key and value bytes.
 
+Grouped layers (GDLA, ``cfg.groups`` < heads): the keys and values are made
+per GROUP, group-major (`_expand` with the group count), the queries are put in group
+order (each group's signal heads, then its noise head) and both are
+zero-padded to whole lanes (192 -> 256 at the `motif3_mixed_sat` shapes); a
+grid step then attends ONE group's query heads over that group's one key
+and value block (``head_steps``), and the output goes back to head order.
+The mask may be a plain causal plane (a full layer) or a window band over a
+ring padded to a whole tile and the chunk (a window layer): the dead tiles
+are skipped the same way.
+
 Selection (``impl=None``): ``pallas`` on a TPU where the shapes tile
 (`select_impl`), ``xla`` elsewhere; `carry` makes it and its result says
 it (a bitmap, or None).  Tests run the kernel on the CPU with
@@ -64,7 +74,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deeplearning4j_tpu.ops.latent import attend_expanded, blocked, expand_latents
+from deeplearning4j_tpu.ops.latent import (
+    attend_expanded, attend_groups, blocked, expand_groups, expand_latents,
+    group_heads, ungroup_heads,
+)
 
 #: finite stand-in for -inf inside the kernel (see ops/paged_attention.py)
 _MASK = -1e30
@@ -89,10 +102,17 @@ def head_group(heads: int) -> int:
     return math.gcd(heads, HEAD_GROUP)
 
 
+def grouped(cfg) -> bool:
+    """Fewer key/value groups than query heads (GDLA): a grid step then
+    attends ONE group's query heads over that group's keys and values."""
+    return getattr(cfg, "groups", cfg.n_heads) != cfg.n_heads
+
+
 def select_impl(cfg, c: int, ctx: int, impl: str | None = None) -> str:
     """``impl`` where given, else ``pallas`` on a TPU where a chunk of ``c``
     queries over ``ctx`` rows tiles for the compiled kernel and ``xla``
-    elsewhere."""
+    elsewhere (a grouped layer's query and key width is padded to whole
+    lanes, so any tiles)."""
     if impl:
         return impl
     from deeplearning4j_tpu.runtime.backend import backend
@@ -100,7 +120,8 @@ def select_impl(cfg, c: int, ctx: int, impl: str | None = None) -> str:
     b = tile_rows(c)
     d = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     tiles = (c % b == 0 and ctx % b == 0 and b % _LANES == 0
-             and d % _LANES == 0 and cfg.v_head_dim % _LANES == 0)
+             and (d % _LANES == 0 or grouped(cfg))
+             and cfg.v_head_dim % _LANES == 0)
     return "pallas" if backend().is_tpu and tiles else "xla"
 
 
@@ -123,10 +144,11 @@ def live_tiles(mask):
                    axis=(1, 3)).astype(jnp.int32)
 
 
-def tile_counts(live, heads: int):
+def tile_counts(live, heads: int, kv_groups: int = 0):
     """The (tile, head group) steps of one call that the kernel runs and
-    skips: int32 ``[run, skipped]``."""
-    groups = heads // head_group(heads)
+    skips: int32 ``[run, skipped]``; a grouped layer (``kv_groups``) has
+    one head group per key/value group."""
+    groups = kv_groups or heads // head_group(heads)
     run = jnp.sum(live, dtype=jnp.int32)
     return jnp.stack([run, live.size - run]) * groups
 
@@ -144,7 +166,7 @@ def _resident(live):
 
 def _kernel(live_ref, at_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
             acc_ref, m_ref, l_ref, *, heads: int, d: int, dv: int,
-            scale: float, n_k: int):
+            scale: float, n_k: int, kv_heads: int | None = None):
     i, j = pl.program_id(0), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -157,7 +179,10 @@ def _kernel(live_ref, at_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
     def _tile():
         keep = mask_ref[...].astype(jnp.int32) > 0
         for h in range(heads):
-            s = lax.dot_general(q_ref[:, h * d:(h + 1) * d], k_ref[h], _NT,
+            # the key/value head this query head reads: its own, or the
+            # step's one group (``kv_heads`` 1)
+            kh = h if kv_heads is None else h * kv_heads // heads
+            s = lax.dot_general(q_ref[:, h * d:(h + 1) * d], k_ref[kh], _NT,
                                 preferred_element_type=jnp.float32)
             # a row that keeps nothing here while its max is still the
             # stand-in adds exp(0) per entry; the first row entry it keeps
@@ -169,7 +194,7 @@ def _kernel(live_ref, at_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
             alpha = jnp.exp(m_prev - m_new)
             l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
             acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
-                p.astype(v_ref.dtype), v_ref[h],
+                p.astype(v_ref.dtype), v_ref[kh],
                 preferred_element_type=jnp.float32)
             m_ref[h] = m_new
 
@@ -180,50 +205,68 @@ def _kernel(live_ref, at_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
                 acc_ref[h] / l_ref[h]).astype(o_ref.dtype)
 
 
-def _vmem_bytes(b: int, hg: int, d: int, dv: int, itemsize: int) -> int:
+def _vmem_bytes(b: int, hg: int, d: int, dv: int, itemsize: int,
+                kvb: int | None = None) -> int:
     """Double-buffered blocks, scratch, and one head's f32 temporaries
     (scores, weights, the mask widened to int32, and what Mosaic keeps of
-    them beside each other)."""
-    blocks = b * hg * (2 * d + 2 * dv) * itemsize + b * b       # q k v o mask
+    them beside each other); ``kvb`` key/value heads a step (``hg``)."""
+    kvb = hg if kvb is None else kvb
+    blocks = (b * (hg + kvb) * (d + dv) * itemsize           # q o, k v
+              + b * b)                                       # mask
     scratch = hg * b * (dv + 2 * _LANES) * 4
     return 2 * blocks + scratch + 8 * b * b * 4
 
 
-def _expand(cfg, latents, wkvb):
+def _expand(cfg, latents, wkvb, heads: int | None = None,
+            d_pad: int = 0):
     """`ops/latent.expand_latents` head-major: ``(k (H, n, nope + rope),
-    v (H, n, v))``, the layout the kernel's blocks read."""
+    v (H, n, v))``, the layout the kernel's blocks read; ``heads`` the
+    key/value heads where they are not the query heads (a grouped layer's
+    groups), keys zero-padded to ``d_pad`` where given."""
     dn, dv, lk = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
-    n, h_ = latents.shape[0], cfg.n_heads
+    n, h_ = latents.shape[0], heads or cfg.n_heads
     with jax.named_scope("dsa_attend"):
         kv = jnp.einsum("nc,chd->hnd", latents[:, :lk],
                         wkvb.astype(latents.dtype).reshape(lk, h_, dn + dv))
         k_r = latents[None, :, lk:lk + cfg.qk_rope_head_dim]
-        k = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_r, (h_,) + k_r.shape[1:])],
-            axis=-1)
+        parts = [kv[..., :dn], jnp.broadcast_to(k_r, (h_,) + k_r.shape[1:])]
+        if d_pad:
+            parts.append(jnp.zeros(
+                (h_, n, d_pad - dn - cfg.qk_rope_head_dim), latents.dtype))
+        k = jnp.concatenate(parts, axis=-1)
     return k, kv[..., dn:]
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _pallas(q, k, v, mask, live, *, scale: float, interpret: bool):
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "head_steps"))
+def _pallas(q, k, v, mask, live, *, scale: float, interpret: bool,
+            head_steps: int | None = None):
+    """``head_steps``: query heads per grid step where one key/value group
+    serves them (k, v (G, ctx, w)); None: `head_group` heads a step, each
+    over its own key and value (k, v (H, ctx, w))."""
     c, heads, d = q.shape
     ctx, dv = k.shape[1], v.shape[2]
-    b, hg = tile_rows(c), head_group(heads)
+    b = tile_rows(c)
+    hg = head_group(heads) if head_steps is None else head_steps
+    kvb = hg if head_steps is None else 1
     if c % b or ctx % b:
         raise ValueError(f"a chunk of {c} over a context of {ctx} does not "
                          f"tile into {b} x {b}")
     nq, nk = c // b, ctx // b
     kv_block = lambda w: pl.BlockSpec(
-        (hg, b, w), lambda i, g, j, live, at: (g, at[i, j], 0))
+        (kvb, b, w), lambda i, g, j, live, at: (g, at[i, j], 0))
     kwargs = {}
     if not interpret:
-        need = _vmem_bytes(b, hg, d, dv, k.dtype.itemsize)
+        need = _vmem_bytes(b, hg, d, dv, k.dtype.itemsize,
+                           None if head_steps is None else kvb)
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             **({"vmem_limit_bytes": need + (16 << 20)}
                if need > _VMEM_SCOPED_DEFAULT else {}))
     return pl.pallas_call(
-        functools.partial(_kernel, heads=hg, d=d, dv=dv, scale=scale, n_k=nk),
+        functools.partial(_kernel, heads=hg, d=d, dv=dv, scale=scale, n_k=nk,
+                          **({} if head_steps is None else
+                             {"kv_heads": kvb})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(nq, heads // hg, nk),
@@ -255,6 +298,10 @@ def dsa_prefill_attention(cfg, q, latents, wkvb, mask, live, *,
     kernel -> (C, H * dv) in q's dtype, scores scaled by
     ``cfg.softmax_scale``."""
     if live is None:
+        if grouped(cfg):
+            k, v = expand_groups(cfg, latents, wkvb)
+            return blocked(lambda qb, mb: attend_groups(cfg, qb, k, v, mb),
+                           XLA_QUERY_BLOCK, q, mask)
         k, v = expand_latents(cfg, latents, wkvb)
         return blocked(lambda qb, mb: attend_expanded(cfg, qb, k, v, mb),
                        XLA_QUERY_BLOCK, q, mask)
@@ -262,5 +309,25 @@ def dsa_prefill_attention(cfg, q, latents, wkvb, mask, live, *,
         from deeplearning4j_tpu.runtime.backend import backend
 
         interpret = not backend().is_tpu
+    if grouped(cfg):
+        return _grouped(cfg, q, latents, wkvb, mask, live, interpret)
     return _pallas(q, *_expand(cfg, latents, wkvb), mask, live,
                    scale=float(cfg.softmax_scale), interpret=interpret)
+
+
+def _grouped(cfg, q, latents, wkvb, mask, live, interpret: bool):
+    """A grouped layer through the kernel: the queries in group order (each
+    group's heads side by side, `ops/latent.group_heads`) and, with the
+    keys, zero-padded to whole lanes; the output back in head order."""
+    c, heads, d = q.shape
+    d_pad = -(-d // _LANES) * _LANES
+    qg = group_heads(cfg, q)                          # (C, G, per group, d)
+    per = qg.shape[2]
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, d_pad - d)))
+    out = _pallas(qg.reshape(c, heads, d_pad),
+                  *_expand(cfg, latents, wkvb, cfg.groups, d_pad), mask, live,
+                  scale=float(cfg.softmax_scale), interpret=interpret,
+                  head_steps=per)
+    dv = cfg.v_head_dim
+    return ungroup_heads(cfg, out.reshape(c, cfg.groups, per, dv)).reshape(
+        c, heads * dv)

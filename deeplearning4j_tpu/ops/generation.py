@@ -56,7 +56,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
 from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
 from deeplearning4j_tpu.ops.attention import mha
 from deeplearning4j_tpu.ops.hybrid import final_norm, hybrid_block
-from deeplearning4j_tpu.ops.latent import latent_block, rms_norm
+from deeplearning4j_tpu.ops.latent import final_rows, first_rows, latent_block
 from deeplearning4j_tpu.quant.qtensor import QuantizedTensor
 
 #: what `_plan` returns: the stack's layers (each block knows where its
@@ -70,8 +70,9 @@ def cache_rows(cfg) -> dict:
     """What one token caches in a block: pool name -> the row's shape.  A
     `TransformerEncoderBlock` caches a key and a value of ``(heads,
     head_dim)`` each; a `LatentBlock` or a `HybridBlock` states its own
-    widths (a hybrid layer other than the full one caches nothing per
-    token: what it keeps is per stream, `slot_rows`)."""
+    widths (a hybrid layer other than the full one, and a latent window
+    layer, cache nothing per token: what they keep is per stream,
+    `slot_rows`)."""
     if isinstance(cfg, (LatentBlock, HybridBlock)):
         return {name: (width,) for name, width in cfg.cache_rows.items()}
     row = (cfg.n_heads, cfg.d_model // cfg.n_heads)
@@ -81,8 +82,10 @@ def cache_rows(cfg) -> dict:
 def slot_rows(cfg) -> dict:
     """What a block keeps per STREAM (per decode slot), whatever its
     length: name -> (shape, "f32" | "kv"); "kv" is the pool's row type.
-    Only a `HybridBlock` keeps anything so."""
-    return cfg.slot_rows if isinstance(cfg, HybridBlock) else {}
+    A `HybridBlock`'s Mamba state and window ring, a window `LatentBlock`'s
+    ring of latent rows."""
+    return (cfg.slot_rows if isinstance(cfg, (LatentBlock, HybridBlock))
+            else {})
 
 
 def block_params(params, cfg):
@@ -132,8 +135,9 @@ def _plan(model):
         raise ValueError(
             "generate() supports three stacks: [Embedding, "
             "PositionalEncoding?, TransformerEncoderBlock*, head], "
-            "[Embedding, LatentSparseDecoder, head] and [Embedding, "
-            "HybridDecoder]; layer "
+            "[Embedding, LatentSparseDecoder, head] (latent attention: "
+            "selected, grouped or windowed; plain or multi-stream "
+            "residual) and [Embedding, HybridDecoder]; layer "
             f"{type(layers[i]).__name__} at position {i} is not supported"
         )
     head = layers[-1]
@@ -220,6 +224,15 @@ def _ln(lp, x):
 
 
 def embed_tokens(stack, params, toks, positions, dt):
+    """Token ids -> the first block's input rows (`_embed`), copied into
+    each residual stream where a latent decoder keeps several."""
+    x = _embed(stack, params, toks, positions, dt)
+    if isinstance(stack.final, LatentSparseDecoder):
+        x = first_rows(stack.final, x)
+    return x
+
+
+def _embed(stack, params, toks, positions, dt):
     """Token ids -> the first block's input: the embedding gather through
     the LAYER's semantics (its activation included) plus position.
     ``positions=None`` is a whole prompt from position 0 (the layer's own
@@ -248,7 +261,8 @@ def block(cfg, lp, x, attend):
     ``attend(q, k, v)`` takes the (..., H, Dh) projections of these rows
     and returns their attention output, same shape; which K/V it runs
     against — and where it keeps the rows it was handed — is the caller's
-    closure.  A `LatentBlock`: x is (n, D) rows and ``attend`` an
+    closure.  A `LatentBlock`: x is (n, D) rows — (n, S x D), the residual
+    streams side by side, in a decoder with ``mhc`` — and ``attend`` an
     `ops/latent.LatentRows` — the rows' positions, the closure
     ``attend(cfg, q, latent, index, wkvb)`` and what the expert layers
     count.  Either way the block's output rows come back."""
@@ -323,8 +337,7 @@ def _head_logits(stack, params, h):
             preferred_element_type=jnp.float32)
     head, lp = stack.head, params[stack.head.name]
     if stack.final is not None:
-        h = rms_norm(h, params[stack.final.name]["norm_f"],
-                     stack.final.rms_eps)
+        h = final_rows(stack.final, params[stack.final.name], h)
     if isinstance(head, ChunkedSoftmaxOutputLayer):
         return head.logits(lp, h)
     y = h @ lp["W"].astype(h.dtype)

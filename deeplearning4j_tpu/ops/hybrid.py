@@ -39,7 +39,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.nn.conf.hybrid import CONV, RING, SHARED_KV, SSM
-from deeplearning4j_tpu.ops.latent import blocked, layer_norm, rms_norm
+from deeplearning4j_tpu.ops.latent import (
+    blocked, layer_norm, rms_norm, window_keep,
+)
 
 HybridRows = collections.namedtuple("HybridRows", "mamba attend memory")
 
@@ -144,13 +146,6 @@ def split_kv(rows, kp: int, hd: int):
             rows[..., kw:2 * kw].reshape(lead + (kp, 2 * hd)))
 
 
-def window_mask(q_pos, k_pos, window: int):
-    """bool (nq, nk): key position within the query's causal window of
-    ``window`` positions (itself included)."""
-    back = q_pos[:, None] - k_pos[None, :]
-    return (back >= 0) & (back < window)
-
-
 def diff_combine(cfg, m, o):
     """(n, P, 2, 2 hd) softmax-weighted sums -> (n, P * 2 hd) f32: the
     second softmax's share taken away at lambda, each head's RMSNorm."""
@@ -242,7 +237,7 @@ def sequence_forward(dec, params, x):
         span = cfg.window if cfg.kind == "swa" else t
         return blocked(
             lambda qb, pb: attend_pairs(qb, k, v,
-                                        window_mask(pb, pos, span)),
+                                        window_keep(pb, pos, span)),
             QUERY_BLOCK, q, pos)
 
     rows = HybridRows(mamba, attend, [])
@@ -315,7 +310,7 @@ def prefill_rows(pools, at, slot, start, real, *, fresh: bool, dt,
             # chunk rows [bi bq, bi bq + bq + w) (ring first)
             first = bi * bq
             k_pos = start - w + first + jnp.arange(bq + w)
-            mask = (window_mask(start + first + jnp.arange(bq), k_pos, w)
+            mask = (window_keep(start + first + jnp.arange(bq), k_pos, w)
                     & (k_pos >= 0)[None, :])
             return attend_pairs(
                 lax.dynamic_slice_in_dim(q, first, bq),

@@ -25,6 +25,8 @@ far more often than the rounding of the activations does.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -34,6 +36,40 @@ def gated_ffn(h, wg, wu, wd):
     """``(silu(h Wg) * (h Wu)) Wd`` in h's type."""
     dt = h.dtype
     return (jax.nn.silu(h @ wg.astype(dt)) * (h @ wu.astype(dt))) @ wd.astype(dt)
+
+
+class PolyNorm(NamedTuple):
+    """The ``poly_norm`` activation (Motif): ``scale * (w0 N(g^3) + w1
+    N(g^2) + w2 N(g) + clamp(b, +-clamp))`` with ``N`` the RMS
+    normalisation over the row's width, in f32.  An FFN that uses it holds
+    ``poly_w`` (3,) and ``poly_b`` () beside its matrices (an expert stack:
+    one of each per expert)."""
+
+    scale: float
+    clamp: float
+    eps: float
+
+    def __call__(self, g, w, b):
+        """g (m, f) f32; w (m, 3) or (3,); b (m,) or ()."""
+        def norm(v):
+            return v * lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + self.eps)
+
+        w, b = w.astype(jnp.float32), b.astype(jnp.float32)
+        return self.scale * (w[..., 0:1] * norm(g * g * g)
+                             + w[..., 1:2] * norm(g * g)
+                             + w[..., 2:3] * norm(g)
+                             + jnp.clip(b, -self.clamp, self.clamp)[..., None])
+
+
+def act_ffn(h, f, act: PolyNorm):
+    """``(act(h Wg) * (h Wu)) Wd`` of one FFN ``f`` with its own activation
+    weights; the products take h's type and accumulate in f32, and what
+    lies between them is f32 -> (n, d) f32."""
+    dt = h.dtype
+    mm = lambda a, w: jnp.dot(a.astype(dt), w.astype(dt),
+                              preferred_element_type=jnp.float32)
+    g = act(mm(h, f["Wg"]), f["poly_w"], f["poly_b"])
+    return mm(g * mm(h, f["Wu"]), f["Wd"])
 
 
 def route(h, router, bias, *, top_k: int, scale: float):
@@ -49,7 +85,7 @@ def route(h, router, bias, *, top_k: int, scale: float):
 
 
 def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
-            row_tile: int | None = None):
+            row_tile: int | None = None, act: PolyNorm | None = None):
     """The held experts' part of the layer plus the shared expert.
 
     h: (n, d) rows; ``lp``: ``router`` (d, n_routed), ``router_bias``
@@ -64,7 +100,11 @@ def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
     count, so a chip that holds 16 of 256 experts multiplies about a
     sixteenth of the ``n x top_k`` rows and still drops none when they all
     land here.  ``row_tile=None`` is one static pass over all of them: the
-    differentiable form, and the cheaper one for a handful of rows."""
+    differentiable form, and the cheaper one for a handful of rows.
+
+    ``act`` None: the SiLU experts, y in h's type.  A `PolyNorm`: each
+    expert's own activation weights on its rows, the gate and up products
+    accumulated in f32 and the activation taken in f32; y is f32."""
     n, d = h.shape
     ex = lp["experts"]
     n_held = ex["Wg"].shape[0]
@@ -80,15 +120,32 @@ def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
         gate = jnp.where(held, gates, 0.0).reshape(-1)[order]
         ends = jnp.cumsum(sizes[:n_held])
 
+        m = n * top_k
+        pad = 0 if row_tile is None or m <= row_tile else -m % row_tile
+        if act is not None:
+            # the held expert of each sorted row (past the held groups: any)
+            expert_of = jnp.pad(jnp.minimum(group[order], n_held - 1),
+                                (0, pad))
+
         def tile(start, order_t, gate_t, routed):
             """Rows [start, start + len) of the sorted assignments."""
             hi = jnp.clip(ends - start, 0, order_t.shape[0])
             sizes_t = hi - jnp.concatenate([jnp.zeros(1, hi.dtype), hi[:-1]])
             src = order_t // top_k                           # row of each
             rows = h[src]
-            g = lax.ragged_dot(rows, wg, sizes_t)
-            u = lax.ragged_dot(rows, wu, sizes_t)
-            y = lax.ragged_dot(jax.nn.silu(g) * u, wd, sizes_t)
+            if act is None:
+                g = lax.ragged_dot(rows, wg, sizes_t)
+                u = lax.ragged_dot(rows, wu, sizes_t)
+                y = lax.ragged_dot(jax.nn.silu(g) * u, wd, sizes_t)
+            else:
+                e = lax.dynamic_slice_in_dim(expert_of, start,
+                                             order_t.shape[0])
+                f32 = dict(preferred_element_type=jnp.float32)
+                g = act(lax.ragged_dot(rows, wg, sizes_t, **f32),
+                        ex["poly_w"][e], ex["poly_b"][e])
+                u = lax.ragged_dot(rows, wu, sizes_t, **f32)
+                y = lax.ragged_dot((g * u).astype(h.dtype), wd, sizes_t,
+                                   **f32)
             # rows past the held groups belong to no group: whatever the
             # grouped product left there is dropped with their zero gate
             y = jnp.where(gate_t[:, None] > 0.0,
@@ -96,11 +153,9 @@ def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
             return routed.at[src].add(y)
 
         routed = jnp.zeros((n, d), jnp.float32)
-        m = n * top_k
         if row_tile is None or m <= row_tile:
             routed = tile(0, order, gate, routed)
         else:
-            pad = -m % row_tile
             order_p = jnp.concatenate([order, jnp.zeros(pad, order.dtype)])
             gate_p = jnp.concatenate([gate, jnp.zeros(pad, gate.dtype)])
 
@@ -112,8 +167,11 @@ def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
 
             routed = lax.fori_loop(0, -(-ends[-1] // row_tile), body, routed)
         sh = lp["shared"]
-        out = routed.astype(h.dtype) + gated_ffn(h, sh["Wg"], sh["Wu"],
-                                                  sh["Wd"])
+        if act is None:
+            out = routed.astype(h.dtype) + gated_ffn(h, sh["Wg"], sh["Wu"],
+                                                      sh["Wd"])
+        else:
+            out = routed + act_ffn(h, sh, act)
     if count_rows is None:
         counts = sizes
     else:

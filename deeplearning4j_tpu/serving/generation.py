@@ -137,19 +137,29 @@ from deeplearning4j_tpu.ops.hybrid import (
     prefill_rows,
     step_rows,
 )
+from deeplearning4j_tpu.nn.conf.attention import LATENT_RING, LatentBlock
+from deeplearning4j_tpu.nn.conf.hybrid import HybridDecoder
 from deeplearning4j_tpu.ops.dsa_prefill_attention import (
     carry,
     dsa_prefill_attention,
     tile_counts,
+    tile_rows,
 )
 from deeplearning4j_tpu.ops.latent import (
     MOE_ROW_TILE,
     LatentRows,
+    absorb_queries,
+    absorbed_values,
     attend_gathered,
+    attend_latent_rows,
     blocked,
     index_scores,
     kth_largest,
     topk_mask,
+    window_keep,
+)
+from deeplearning4j_tpu.ops.latent_paged_attention import (
+    latent_paged_attention,
 )
 from deeplearning4j_tpu.ops.paged_attention import paged_attention_chunk
 from deeplearning4j_tpu.ops.shared_kv_attention import (
@@ -259,8 +269,17 @@ ROW_LANES = 128
 
 #: the latent prefill kernel's tiles, counted ON THE DEVICE into the same
 #: array, by state: ``run`` (a tile the selection keeps any pair of, times
-#: head groups) and ``skipped`` (one it keeps none of: no copy, no product)
+#: head groups) and ``skipped`` (one it keeps none of: no copy, no product),
+#: and by the layer's ``kind``: ``full`` (its keys are the whole context)
+#: or ``window`` (a ring and the chunk's own rows)
 DSA_PREFILL_TILES_FAMILY = "dl4jtpu_dsa_prefill_tiles_total"
+LAYER_KINDS = ("full", "window")
+
+#: latent rows the decode steps' layers without a selection attended,
+#: counted ON THE DEVICE into the same array, by ``kind``: a full layer's
+#: are the slot's context (seq_len + 1), a window layer's its ring's
+#: min(seq_len + 1, window); summed over the layers of each kind
+LATENT_ROWS_FAMILY = "dl4jtpu_latent_rows_attended_total"
 
 #: query rows per block of a prefill chunk's indexer scores (latent) and
 #: attention (hybrid): bounds the f32 scores a program holds to (heads,
@@ -622,15 +641,23 @@ class GenerationEngine:
         # where each named pool sits among the program state's arrays
         self._state_at = {name: i for i, name in enumerate(
             list(self.kv.rows) + list(self.kv.slot_rows))}
+        self._hybrid = isinstance(stack.final, HybridDecoder)
         # expert layers: their place in the device counts; a stack with an
-        # indexer: the row of its prefill kernel's tiles
+        # indexer, or with latent layers that select nothing: the row of
+        # its prefill kernel's tiles; the latter: one more, of the latent
+        # rows the decode steps attended
         self._moe_index = {
             b.name: i for i, b in enumerate(
                 b for b in stack.blocks if getattr(b, "ffn", "") == "sparse")}
         self._dsa_layers = sum(getattr(b, "indexer", "") == "full"
                                for b in stack.blocks)
-        self._tiles_row = (len(self._moe_index) if self._dsa_layers
+        self._plain_latent = any(getattr(b, "indexer", "") == "none"
+                                 for b in stack.blocks)
+        self._tiles_row = (len(self._moe_index)
+                           if self._dsa_layers or self._plain_latent
                            else None)
+        self._rows_row = (self._tiles_row + 1 if self._plain_latent
+                          else None)
         self._device_counts = self._fresh_device_counts()
         self._device_flushed = None
         self._dsa_topk = max((b.index_topk for b in stack.blocks
@@ -640,7 +667,8 @@ class GenerationEngine:
         self._dsa_selected = 0
         # a hybrid stack's state, read and skipped (`HYBRID_COUNT_FAMILIES`)
         self._window = max((b.window for b in stack.blocks
-                            if getattr(b, "kind", "") == "swa"), default=0)
+                            if getattr(b, "kind", "") == "swa"
+                            or isinstance(b, LatentBlock)), default=0)
         self._shared_rows = 0
         self._window_rows = 0
         self._prefill_rows = 0
@@ -725,7 +753,8 @@ class GenerationEngine:
                 f"speculative decoding (spec_k {self.spec_k}) needs rejected "
                 "draft rows rolled back, and this stack keeps recurrent "
                 f"state per stream ({sorted(self.kv.slot_rows)}): a scan "
-                "that took in a rejected token cannot be truncated")
+                "that took in a rejected token, or a ring row it overwrote, "
+                "cannot be truncated")
         if self.spec_k > 0:
             self.drafter = speculative.make_drafter(
                 cfg.spec_drafter or speculative.drafter_from_env(),
@@ -1032,7 +1061,7 @@ class GenerationEngine:
         # cheap even with the decode loop live.  A K/V pool's programs
         # are keyed by the prompt's bucket, row pools' by the chunk's index
         make = (self._make_prefill if self.kv.kv_layout
-                else self._make_hybrid_prefill_chunk if self.kv.slot_rows
+                else self._make_hybrid_prefill_chunk if self._hybrid
                 else self._make_prefill_chunk)
         with self._mu:
             fn = self._prefill_fns.get(key)
@@ -1083,7 +1112,7 @@ class GenerationEngine:
         self._count_selection(np.arange(1, t_p + 1))
         with self._span("generation.prefill_readback"):
             got = np.asarray(out[-1]).reshape(-1)
-        if self.kv.slot_rows:
+        if self._hybrid:
             # the rows the cross-decoder skipped, by the rows the last
             # chunk reports it ran
             self._prefill_rows += t_p
@@ -1117,12 +1146,18 @@ class GenerationEngine:
     def _fresh_device_counts(self):
         """What the programs count on the device, int32: a row per expert
         layer (its assignments to each held expert, then those to experts
-        held elsewhere) and, for a stack with an indexer, one more:
-        the prefill kernel's tiles ``[run, skipped]`` (`_tiles_row`)."""
-        rows = len(self._moe_index) + (self._tiles_row is not None)
+        held elsewhere) and, for a latent stack, one more: the prefill
+        kernel's tiles ``[run, skipped]`` of its full layers, then of its
+        window layers (`_tiles_row`); with layers that select nothing, one
+        more: the latent rows the decode steps attended ``[full, window]``
+        (`_rows_row`)."""
+        rows = (len(self._moe_index) + (self._tiles_row is not None)
+                + (self._rows_row is not None))
         if not rows:
             return None
         width = self._stack.final._held() + 1 if self._moe_index else 2
+        if self._plain_latent:
+            width = max(width, 2 * len(LAYER_KINDS))
         return jnp.zeros((rows, width), jnp.int32)
 
     def _revive_state(self, wait: bool = False) -> bool:
@@ -1181,17 +1216,20 @@ class GenerationEngine:
         n_state = len(self._program_state())
         bq = PREFILL_QUERY_BLOCK
         interp = self.config.attention_interpret
+        names = list(self._state_at)
 
         def prefill_chunk(params, *rest):
             state = rest[:n_state]
-            page_row, toks, prompt_len, seed, temp, top_k = rest[n_state:]
+            page_row, toks, prompt_len, seed, temp, top_k, *at_slot = rest[
+                n_state:]
             # the programs address a layer's pages in the pool flattened
             # over (layer, page), so none slices a layer out of it
-            pools = dict(zip(self.kv.rows, state))
+            pools = dict(zip(names, state))
             positions = start + jnp.arange(c_rows)
             x = embed_tokens(stack, params, toks, positions,
                              _act_dtype(self.model))
             carried = []
+            masks = {}
 
             def through_pages(name, at, rows):
                 """Write the chunk's rows of one layer into the stream's
@@ -1205,7 +1243,53 @@ class GenerationEngine:
                 pools[name] = flat.reshape(pool.shape)
                 return flat[pages[: ctx // ps]].reshape((ctx,) + tail[1:])
 
+            def plain(cfg, q, latent, wkvb):
+                """A layer that selects nothing: a full one attends the
+                stream's whole context through its pages; a window one
+                its ring, as the chunk before left it, and the chunk's own
+                rows, the ring written after from the real rows only."""
+                at = self._pool_index[cfg.name]
+                w = cfg.window
+                if not w:
+                    context = through_pages("latent", at["latent"], latent)
+                    key_pos = jnp.arange(ctx)
+                else:
+                    # ring row r holds position start - w + r (a chunk
+                    # starts at a multiple of the window); the ring is
+                    # padded in front to whole kernel tiles
+                    li = at[LATENT_RING]
+                    ring = pools[LATENT_RING][li, at_slot[0]]
+                    new = latent.astype(ring.dtype)
+                    lead = -(-w // tile_rows(c_rows)) * tile_rows(c_rows) - w
+                    context = jnp.concatenate(
+                        [jnp.zeros((lead,) + ring.shape[1:], ring.dtype),
+                         ring, new])
+                    key_pos = jnp.concatenate([
+                        jnp.full(lead, -1, jnp.int32),
+                        start - w + jnp.arange(w, dtype=jnp.int32),
+                        positions.astype(jnp.int32)])
+                    e = start + _real_rows(prompt_len, start, c_rows)
+                    p_r = e - w + jnp.mod(jnp.arange(w) - e, w)
+                    pools[LATENT_RING] = pools[LATENT_RING].at[
+                        li, at_slot[0]].set(jnp.where(
+                            (p_r >= start)[:, None],
+                            new[jnp.clip(p_r - start, 0, c_rows - 1)], ring))
+                if w not in masks:
+                    masks[w] = carry(cfg, window_keep(
+                        positions, key_pos, w) & (key_pos >= 0)[None, :],
+                        self.config.attention_impl)
+                mask, live = masks[w]
+                if live is not None:
+                    col = 2 * LAYER_KINDS.index("window" if w else "full")
+                    counts[0] = counts[0].at[self._tiles_row,
+                                             col:col + 2].add(
+                        tile_counts(live, cfg.n_heads, cfg.groups))
+                return dsa_prefill_attention(cfg, q, context, wkvb, mask,
+                                             live, interpret=interp)
+
             def attend(cfg, q, latent, index, wkvb):
+                if cfg.indexer == "none":
+                    return plain(cfg, q, latent, wkvb)
                 at = self._pool_index[cfg.name]
                 context = through_pages("latent", at["latent"], latent)
                 if index is not None:
@@ -1308,7 +1392,7 @@ class GenerationEngine:
 
     # -- the decode step's `attend`, by what the pool holds ------------------
     def _kv_step_attend(self, c: int, pool: list, at: "_StepRows",
-                        counts_to):
+                        counts_to, counts):
         """Keys and values: layer ``li``'s rows into the pool (the int8
         twin and its scales included), then the chunk against it through
         the paged kernel.  The pool is read and written in place
@@ -1344,7 +1428,7 @@ class GenerationEngine:
         return lambda li: functools.partial(attend, li)
 
     def _row_step_attend(self, c: int, pool: list, at: "_StepRows",
-                         counts_to):
+                         counts_to, counts):
         """Latent rows and indexer keys: per layer the c rows of every
         slot are written into the latent pool, a full layer scores the
         slot's WHOLE context with the indexer (its cached keys, through
@@ -1353,7 +1437,15 @@ class GenerationEngine:
         layer gathers just the selected latent rows through the page
         table and attends them with ``W_kvb`` folded into the query and
         the output.  A layer's rows are addressed in the pool flattened
-        over (layer, page, row), so none slices a layer out of it."""
+        over (layer, page, row), so none slices a layer out of it.
+
+        A layer that selects nothing attends ABSORBED queries
+        (`ops/latent.absorb_queries`): a full one the slot's whole context
+        in the pool, through the ``latent_paged_attn`` kernel, which reads
+        each slot's live pages in place (one row per slot); a window one
+        its ring, whose row ``position mod window`` it writes first, over
+        ``min(position + 1, window)`` rows.
+        The rows each kind attended are counted into the device counts."""
         ps = self.kv.page_size
         n_slots, mp = self.config.slots, self.config.max_pages_per_seq
         cap, n = mp * ps, n_slots * c
@@ -1376,7 +1468,41 @@ class GenerationEngine:
             pool[at_pool[name]] = flat.reshape(stored.shape)
             return flat, layer * per_layer
 
+        def plain(cfg, q, latent, wkvb):
+            if c != 1:
+                raise NotImplementedError(
+                    "a latent layer that selects nothing decodes one row a "
+                    "slot: no verify chunk (speculation) reaches it")
+            held = self._pool_index[cfg.name]
+            w = cfg.window
+            qc = absorb_queries(cfg, q, wkvb, pool[0].dtype)
+            if w:
+                ring_at = self._state_at[LATENT_RING]
+                li = held[LATENT_RING]
+                rings = pool[ring_at].at[li, jnp.arange(n_slots),
+                                         at.positions % w].set(
+                    latent.astype(pool[ring_at].dtype))
+                pool[ring_at] = rings
+                lens = jnp.minimum(at.positions + 1, w)
+                o_lat = attend_latent_rows(
+                    qc, rings[li], jnp.arange(w)[None, :] < lens[:, None],
+                    cfg.kv_lora_rank)
+            else:
+                write("latent", held["latent"], latent)
+                lens = at.attend_lens.reshape(n)
+                o_lat = latent_paged_attention(
+                    qc, pool[at_pool["latent"]], page_tbl, lens,
+                    layer=held["latent"], lk=cfg.kv_lora_rank,
+                    impl=self.config.attention_impl,
+                    interpret=self.config.attention_interpret)
+            col = LAYER_KINDS.index("window" if w else "full")
+            counts[0] = counts[0].at[self._rows_row, col].add(
+                jnp.sum(jnp.where(at.active, lens, 0)))
+            return absorbed_values(cfg, o_lat, wkvb)
+
         def attend(cfg, q, latent, index, wkvb):
+            if cfg.indexer == "none":
+                return plain(cfg, q, latent, wkvb)
             held = self._pool_index[cfg.name]
             cached, base = write("latent", held["latent"], latent)
             if index is not None:
@@ -1406,7 +1532,7 @@ class GenerationEngine:
         return lambda li: rows
 
     def _hybrid_step_attend(self, c: int, pool: list, at: "_StepRows",
-                            counts_to):
+                            counts_to, counts):
         """A hybrid stack's state, one row per slot (`ops/hybrid.step_rows`
         over the pools by name): the full layer's row lands where the
         step's write guard put it, and it and every cross layer read the
@@ -1454,7 +1580,7 @@ class GenerationEngine:
         n_pool = len(self.kv.pool())
         n_state = len(self._program_state())
         step_attend = (self._kv_step_attend if self.kv.kv_layout
-                       else self._hybrid_step_attend if self.kv.slot_rows
+                       else self._hybrid_step_attend if self._hybrid
                        else self._row_step_attend)
         # a per-slot value, once for each of the slot's c chunk rows
         rows = lambda a: jnp.repeat(a, c, axis=0)
@@ -1493,7 +1619,8 @@ class GenerationEngine:
             counts, counts_to = self._counts_sink(state)
             x = self._run_blocks(params, x, step_attend(
                 c, pool, _StepRows(page_tbl, page_of, row_of, attend_lens,
-                                   pos_idx, rows(active)), counts_to))
+                                   pos_idx, rows(active)), counts_to,
+                counts))
             logits = _head_logits(stack, params, x)
             keys = _slot_keys(
                 rows(seeds),
@@ -1932,7 +2059,7 @@ class GenerationEngine:
         self._slot_steps += n_live
         self._rows_attended += rows
         self._pages_attended += int((-(-attended // ps)).sum())
-        if self.kv.slot_rows:
+        if self._hybrid:
             self._shared_rows += rows
             self._window_rows += int(np.minimum(live + 1, self._window).sum())
         if self._dsa_layers:
@@ -2449,6 +2576,7 @@ class GenerationEngine:
                        "prefill_rows_skipped_cross": self._cross_skipped},
             "moe": self._moe_stats(counts),
             "dsa_prefill_tiles": self._tiles_stats(counts),
+            "latent": self._latent_stats(counts),
             "tokens_generated": self._tokens_out,
             "tokens_per_s": round(self.tokens_per_s(), 4),
             "streams": {"settled": settled, "outcomes": outcomes},
@@ -2509,7 +2637,24 @@ class GenerationEngine:
         if counts is None or self._tiles_row is None:
             return None
         run, skipped = counts[self._tiles_row, :2].tolist()
+        if self._rows_row is not None:
+            run += int(counts[self._tiles_row, 2])
+            skipped += int(counts[self._tiles_row, 3])
         return {"run": run, "skipped": skipped}
+
+    def _latent_stats(self, counts) -> Optional[dict]:
+        """A stack with latent layers that select nothing: per kind of
+        layer (`LAYER_KINDS`), the rows its decode steps attended and the
+        prefill kernel's tiles run and skipped, process totals (the rows
+        in int32 on the device: read modulo 2^32)."""
+        if counts is None or self._rows_row is None:
+            return None
+        rows = counts[self._rows_row] % 2**32
+        tiles = counts[self._tiles_row]
+        return {kind: {"rows_attended": int(rows[i]),
+                       "tiles_run": int(tiles[2 * i]),
+                       "tiles_skipped": int(tiles[2 * i + 1])}
+                for i, kind in enumerate(LAYER_KINDS)}
 
     def health_summary(self) -> dict:
         """Compact generation block for `InferenceServer.health()` —
@@ -2611,10 +2756,22 @@ class GenerationEngine:
                     b_fam.inc(n, branch=branch)
             if dev is not None and self._tiles_row is not None:
                 tiles = reg.counter(DSA_PREFILL_TILES_FAMILY)
-                for state, d in zip(("run", "skipped"),
-                                    dev_delta[self._tiles_row, :2]):
+                kinds = LAYER_KINDS if self._rows_row is not None else (
+                    "full",)
+                for i, kind in enumerate(kinds):
+                    for state, d in zip(("run", "skipped"),
+                                        dev_delta[self._tiles_row,
+                                                  2 * i:2 * i + 2]):
+                        if d > 0:
+                            tiles.inc(int(d), kind=kind, state=state)
+            if dev is not None and self._rows_row is not None:
+                rows = reg.counter(LATENT_ROWS_FAMILY)
+                # int32 on the device: a total that wrapped since the last
+                # read still gives its delta modulo 2^32
+                for kind, d in zip(LAYER_KINDS,
+                                   dev_delta[self._rows_row, :2] % 2**32):
                     if d > 0:
-                        tiles.inc(int(d), state=state)
+                        rows.inc(int(d), kind=kind)
             if dev is not None and self._moe_index:
                 blocks = [b for b in self._stack.blocks
                           if b.name in self._moe_index]

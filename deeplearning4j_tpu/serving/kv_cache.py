@@ -30,7 +30,9 @@ the int8 scales and the paged kernel that only that layout has.
 A stack that keeps state per STREAM, whatever the stream's length, states
 it as SLOT POOLS (``slot_rows=``): for each named state the layers that
 hold one, the decode slots, its shape and its type — a Mamba layer's scan
-state and conv inputs, a window layer's ring of its last keys and values::
+state and conv inputs, a window layer's ring of its last keys and values, a
+latent window layer's ring of its last latent rows (beside a ``latent`` row
+pool that only the full layers fill)::
 
     <name>_slots:  (layers holding it, slots, *shape)   f32 | bf16
 

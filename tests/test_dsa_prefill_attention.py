@@ -121,3 +121,76 @@ def test_kernel_is_the_xla_form_and_counts_its_tiles(case):
         kv = np.asarray(f32(lat))[ci * C + 5, :LK] @ np.asarray(f32(wkvb))
         own = kv.reshape(heads, 192 + 256)[:, 192:].reshape(-1)
         assert np.abs(got[5] - own).max() <= 2.0 ** -8 * np.abs(own).max()
+
+
+# -- grouped keys and values (GDLA), a causal plane and a window band ------------------
+
+def _grouped_cfg():
+    """10 query heads (8 signal + 2 noise) over 2 key/value groups, heads of
+    nope 48 + rope 16 (padded to 128 lanes inside the kernel), values 128."""
+    return types.SimpleNamespace(
+        n_heads=10, groups=2, signal_heads=8, n_noise_heads=2,
+        qk_nope_head_dim=48, qk_rope_head_dim=16, v_head_dim=128,
+        kv_lora_rank=LK, softmax_scale=64 ** -0.5)
+
+
+def _window_context(ci, w=64):
+    """A window layer's context as the engine lays it out: the ring padded
+    in front to whole tiles (128 - w rows that no query keeps), the ring's
+    w rows (positions ci C - w ...), then the chunk's C rows; -> the key
+    positions and the (C, 128 + C) keep mask of a w-row window."""
+    pad = 128 - w
+    keys = np.concatenate([np.full(pad, -1), ci * C - w + np.arange(w),
+                           ci * C + np.arange(C)])
+    back = (ci * C + np.arange(C))[:, None] - keys[None, :]
+    return (back >= 0) & (back < w) & (keys >= 0)[None, :]
+
+
+# (mask, chunk index, bitmap, [run, skipped]) — counts are tiles x 2 groups
+GROUPED = {
+    "causal_chunk2": (lambda ci: _causal(ci), 2,
+                      [[1, 1, 1, 1, 1, 0], [1, 1, 1, 1, 1, 1]], [22, 2]),
+    # block 0 holds the pad and the ring: query block 0 (positions 0-127 of
+    # the chunk) reads it and itself, block 1 only itself and block 0 of
+    # the chunk's rows
+    "window_chunk1": (lambda ci: _window_context(ci), 1,
+                      [[1, 1, 0], [0, 1, 1]], [8, 4]),
+    "window_chunk0": (lambda ci: _window_context(ci), 0,
+                      [[0, 1, 0], [0, 1, 1]], [6, 6]),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED))
+def test_grouped_kernel_is_the_xla_form_and_counts_its_tiles(case):
+    make, ci, bitmap, counts = GROUPED[case]
+    cfg = _grouped_cfg()
+    rng = np.random.default_rng(len(case) + 7)
+    mask = make(ci)
+    assert mask.any(axis=1).all()
+    q = jnp.asarray(rng.standard_normal((C, 10, 64)), jnp.bfloat16)
+    lat = jnp.asarray(rng.integers(-1, 2, (mask.shape[1], LK + 16)),
+                      jnp.bfloat16)
+    wkvb = jnp.asarray(rng.integers(-2, 3, (LK, 2 * (48 + 128))) / 8,
+                       jnp.bfloat16)
+    m = jnp.asarray(mask)
+    mask8, live = dpa.carry(cfg, m, "pallas")
+    assert np.asarray(live).tolist() == bitmap
+    assert np.asarray(dpa.tile_counts(live, 10, 2)).tolist() == counts
+    got = np.asarray(dpa.dsa_prefill_attention(
+        cfg, q, lat, wkvb, mask8, live, interpret=True).astype(jnp.float32))
+    f32 = lambda a: a.astype(jnp.float32)
+    want = np.asarray(dpa.dsa_prefill_attention(
+        cfg, f32(q), f32(lat), f32(wkvb), *dpa.carry(cfg, m, "xla")))
+    assert got.shape == (C, 10 * 128) and not np.isnan(got).any()
+    assert np.abs(got - want).max() <= 2.0 ** -8 * np.abs(want).max()
+    # by hand: noise head 8 and signal head 3 read group 0's key and value,
+    # signal head 4 group 1's
+    lat32, w32, q32 = (np.asarray(f32(a)) for a in (lat, wkvb, q))
+    for head, g in ((8, 0), (3, 0), (4, 1)):
+        kv = lat32[:, :LK] @ w32[:, g * 176:(g + 1) * 176]
+        k = np.concatenate([kv[:, :48], lat32[:, LK:LK + 16]], -1)
+        s = np.where(mask, q32[:, head] @ k.T * 64 ** -0.5, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        o = (p / p.sum(-1, keepdims=True)) @ kv[:, 48:]
+        np.testing.assert_allclose(want[:, head * 128:(head + 1) * 128], o,
+                                   atol=1e-4)

@@ -204,6 +204,61 @@ def test_dsa_prefill_attn_is_one_kernel_at_glm52_longdoc_sat(ci):
         ((2048, 64 * 256), jnp.bfloat16)]
 
 
+def _motif3_layer():
+    """The `motif3_mixed_sat` cell's attention widths: 80 query heads (64
+    signal + 16 noise) over 16 key/value groups, nope 128 + rope 64,
+    values 128, latent 512."""
+    import types
+
+    return types.SimpleNamespace(
+        n_heads=80, groups=16, signal_heads=64, n_noise_heads=16,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        kv_lora_rank=512, softmax_scale=192 ** -0.5)
+
+
+@pytest.mark.parametrize("ctx", [2048, 32768, 2560],
+                         ids=["full_chunk0", "full_chunk15", "window"])
+def test_grouped_dsa_prefill_attn_is_one_kernel_at_motif3_mixed_sat(ctx):
+    """The cell's prefill chunks: 2,048 queries over the full layer's
+    context (chunk 0 and chunk 15, rows stored 640 wide) and over a window
+    layer's ring padded to a tile plus the chunk (2,560 rows of 576), each
+    in ONE Mosaic call named `dsa_prefill_attn` (the busy-share reader
+    finds it by that name), bf16 in and out, back in head order."""
+    cfg = _motif3_layer()
+    width = 576 if ctx == 2560 else 640
+    exported = lower_for_tpu(
+        lambda q, lat, wkvb, mask: dpa.dsa_prefill_attention(
+            cfg, q, lat, wkvb, *dpa.carry(cfg, mask, "pallas"),
+            interpret=False),
+        sds((2048, 80, 192), jnp.bfloat16), sds((ctx, width), jnp.bfloat16),
+        sds((512, 16 * 256), jnp.bfloat16), sds((2048, ctx), jnp.bool_))
+    text = exported.mlir_module()
+    assert text.count("@tpu_custom_call") == 1
+    assert "dsa_prefill_attn" in text
+    assert [(a.shape, a.dtype) for a in exported.out_avals] == [
+        ((2048, 80 * 128), jnp.bfloat16)]
+
+
+def test_latent_paged_attn_is_one_kernel_at_motif3_mixed_sat():
+    """The cell's decode step: 32 slots of 80 absorbed queries (576 wide)
+    over the full layer's latent pool (16,897 pages of 64 rows stored 640
+    wide) in ONE Mosaic call named `latent_paged_attn`, f32 latent outputs."""
+    from deeplearning4j_tpu.ops import latent_paged_attention as lpa
+
+    exported = lower_for_tpu(
+        lambda qc, pool, tbl, lens: lpa.latent_paged_attention(
+            qc, pool, tbl, lens, layer=0, lk=512, impl="pallas",
+            interpret=False),
+        sds((32, 80, 576), jnp.bfloat16),
+        sds((1, 16897, 64, 640), jnp.bfloat16), sds((32, 528), jnp.int32),
+        sds((32,), jnp.int32))
+    text = exported.mlir_module()
+    assert text.count("@tpu_custom_call") == 1
+    assert "latent_paged_attn" in text
+    assert [(a.shape, a.dtype) for a in exported.out_avals] == [
+        ((32, 80, 512), jnp.float32)]
+
+
 @pytest.mark.parametrize("m", [1, 8, 256])
 def test_dequant_matmul(m):
     lower_for_tpu(
