@@ -839,6 +839,17 @@ def _declare_core(reg: MetricsRegistry) -> None:
                 "The held assignments by layer and expert (expert = "
                 "its index among all routed experts): the load each "
                 "held expert saw")
+    reg.counter("dl4jtpu_moe_experts_fetched_total",
+                "Held experts with at least one row in a decode step's "
+                "expert layer, summed over the layers and steps: the "
+                "experts whose weights the step's grouped products fetch "
+                "(the held_gmm kernel on a TPU reads only these); counted "
+                "on the device into the array the programs carry, read "
+                "at scrape")
+    reg.counter("dl4jtpu_moe_expert_layer_steps_total",
+                "Expert layers times decode steps counted into "
+                "dl4jtpu_moe_experts_fetched_total: that over this times "
+                "the held experts is the share of them fetched")
     reg.counter("dl4jtpu_dsa_prefill_tiles_total",
                 "Tiles of the latent prefill's score plane times head "
                 "groups that the dsa_prefill_attn kernel ran (state=run: "

@@ -409,7 +409,8 @@ def sequence_attend(block_q: int = 256):
 #: the caller's side of a latent block: its ``attend`` closure, the
 #: ``positions`` (n,) of the rows it hands in and, for the expert layers,
 #: which rows to count (``count_rows``, `moe_ffn`), where a layer's
-#: assignment counts go (``counts_to(cfg, counts)``) and the grouped
+#: assignment counts and the number of held experts its grouped products
+#: fetched go (``counts_to(cfg, counts, fetched)``) and the grouped
 #: product's ``moe_row_tile``
 LatentRows = collections.namedtuple(
     "LatentRows", "attend positions count_rows counts_to moe_row_tile",
@@ -548,11 +549,11 @@ def _ffn(cfg, lp, u, rows: LatentRows, dt, bt):
         y = (act_ffn(h, f, act) if act is not None else
              gated_ffn(h, f["Wg"], f["Wu"], f["Wd"]))
         return y.astype(bt)
-    y, counts = moe_ffn(h, f, first=cfg.held_first, top_k=cfg.top_k,
-                        scale=cfg.routed_scale, count_rows=rows.count_rows,
-                        row_tile=rows.moe_row_tile, act=act)
+    y, counts, fetched = moe_ffn(
+        h, f, first=cfg.held_first, top_k=cfg.top_k, scale=cfg.routed_scale,
+        count_rows=rows.count_rows, row_tile=rows.moe_row_tile, act=act)
     if rows.counts_to is not None:
-        rows.counts_to(cfg, counts)
+        rows.counts_to(cfg, counts, fetched)
     return y.astype(bt)
 
 

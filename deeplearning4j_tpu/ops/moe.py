@@ -6,10 +6,12 @@ same router.  `moe_ffn` is what one of those chips computes for the rows
 it is handed: route each row over ALL ``n_routed`` experts (the router
 keeps its published width), keep the assignments that fall on held
 experts, sort them by expert, run one grouped product over the held
-experts' stacked matrices (`lax.ragged_dot`), weight by the gates, and
-add the shared expert, which every chip computes alike.  Dropless: the
-grouped product has room for every assignment, so a router that sends
-every row to one held expert costs time and never a row.  What the
+experts' stacked matrices (`lax.ragged_dot`; a decode step's few rows on a
+TPU: `held_gmm`, which fetches only the experts that have rows), weight
+by the gates, and add the shared expert, which every chip computes
+alike.  Dropless: the grouped product has room for every assignment, so a
+router that sends every row to one held expert costs time and never a
+row.  What the
 absent experts would have added is not computed and not stood in for:
 the partial sum is the layer's result on this chip, and the sum of all
 chips' routed parts plus ONE shared expert is the uncut layer
@@ -30,6 +32,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from deeplearning4j_tpu.ops.held_expert_matmul import held_gmm
 
 
 def gated_ffn(h, wg, wu, wd):
@@ -72,6 +76,12 @@ def act_ffn(h, f, act: PolyNorm):
     return mm(g * mm(h, f["Wu"]), f["Wd"])
 
 
+def _on_tpu() -> bool:
+    from deeplearning4j_tpu.runtime.backend import backend
+
+    return backend().is_tpu
+
+
 def route(h, router, bias, *, top_k: int, scale: float):
     """Rows h: (n, d) -> (expert ids (n, k) int32, gates (n, k) f32)."""
     with jax.named_scope("moe_route"):
@@ -104,7 +114,13 @@ def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
 
     ``act`` None: the SiLU experts, y in h's type.  A `PolyNorm`: each
     expert's own activation weights on its rows, the gate and up products
-    accumulated in f32 and the activation taken in f32; y is f32."""
+    accumulated in f32 and the activation taken in f32; y is f32.
+
+    The engine's static pass (``row_tile`` given, ``n x top_k`` within it:
+    a decode step's handful of rows, most held experts without one) runs
+    its three products through `held_gmm` on a TPU, which fetches only the
+    held experts that have rows; ``fetched`` is their number (int32), None
+    from the tiled pass, whose tiles give rows to every held expert."""
     n, d = h.shape
     ex = lp["experts"]
     n_held = ex["Wg"].shape[0]
@@ -121,7 +137,10 @@ def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
         ends = jnp.cumsum(sizes[:n_held])
 
         m = n * top_k
-        pad = 0 if row_tile is None or m <= row_tile else -m % row_tile
+        static = row_tile is None or m <= row_tile
+        pad = 0 if static else -m % row_tile
+        grouped = (held_gmm if static and row_tile is not None and _on_tpu()
+                   else lax.ragged_dot)
         if act is not None:
             # the held expert of each sorted row (past the held groups: any)
             expert_of = jnp.pad(jnp.minimum(group[order], n_held - 1),
@@ -134,18 +153,17 @@ def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
             src = order_t // top_k                           # row of each
             rows = h[src]
             if act is None:
-                g = lax.ragged_dot(rows, wg, sizes_t)
-                u = lax.ragged_dot(rows, wu, sizes_t)
-                y = lax.ragged_dot(jax.nn.silu(g) * u, wd, sizes_t)
+                g = grouped(rows, wg, sizes_t)
+                u = grouped(rows, wu, sizes_t)
+                y = grouped(jax.nn.silu(g) * u, wd, sizes_t)
             else:
                 e = lax.dynamic_slice_in_dim(expert_of, start,
                                              order_t.shape[0])
                 f32 = dict(preferred_element_type=jnp.float32)
-                g = act(lax.ragged_dot(rows, wg, sizes_t, **f32),
+                g = act(grouped(rows, wg, sizes_t, **f32),
                         ex["poly_w"][e], ex["poly_b"][e])
-                u = lax.ragged_dot(rows, wu, sizes_t, **f32)
-                y = lax.ragged_dot((g * u).astype(h.dtype), wd, sizes_t,
-                                   **f32)
+                u = grouped(rows, wu, sizes_t, **f32)
+                y = grouped((g * u).astype(h.dtype), wd, sizes_t, **f32)
             # rows past the held groups belong to no group: whatever the
             # grouped product left there is dropped with their zero gate
             y = jnp.where(gate_t[:, None] > 0.0,
@@ -153,8 +171,10 @@ def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
             return routed.at[src].add(y)
 
         routed = jnp.zeros((n, d), jnp.float32)
-        if row_tile is None or m <= row_tile:
+        fetched = None
+        if static:
             routed = tile(0, order, gate, routed)
+            fetched = jnp.count_nonzero(sizes[:n_held]).astype(jnp.int32)
         else:
             order_p = jnp.concatenate([order, jnp.zeros(pad, order.dtype)])
             gate_p = jnp.concatenate([gate, jnp.zeros(pad, gate.dtype)])
@@ -179,4 +199,4 @@ def moe_ffn(h, lp, *, first: int, top_k: int, scale: float, count_rows=None,
                             n_held + 1)
         counts = jnp.bincount(counted.reshape(-1),
                               length=n_held + 2)[:n_held + 1].astype(jnp.int32)
-    return out, counts
+    return out, counts, fetched

@@ -259,6 +259,12 @@ DSA_COUNT_FAMILIES = ("dl4jtpu_dsa_rows_scored_total",
 #: the held ones by layer and expert
 MOE_ASSIGNMENTS_FAMILY = "dl4jtpu_moe_assignments_total"
 MOE_EXPERT_FAMILY = "dl4jtpu_moe_expert_assignments_total"
+#: what the decode steps' grouped expert products fetched, counted ON THE
+#: DEVICE into the same array: the held experts with rows, summed over the
+#: expert layers of every step (`moe_ffn`'s ``fetched``), and those
+#: expert layer-steps
+MOE_FETCH_FAMILIES = ("dl4jtpu_moe_experts_fetched_total",
+                      "dl4jtpu_moe_expert_layer_steps_total")
 
 #: a row pool stores its rows at a multiple of this many values (the TPU's
 #: lanes): the device lays a [rows, 576] array out COLUMN-major to spare the
@@ -645,7 +651,8 @@ class GenerationEngine:
         # expert layers: their place in the device counts; a stack with an
         # indexer, or with latent layers that select nothing: the row of
         # its prefill kernel's tiles; the latter: one more, of the latent
-        # rows the decode steps attended
+        # rows the decode steps attended; expert layers: last, the row of
+        # the experts the decode steps fetched
         self._moe_index = {
             b.name: i for i, b in enumerate(
                 b for b in stack.blocks if getattr(b, "ffn", "") == "sparse")}
@@ -658,6 +665,10 @@ class GenerationEngine:
                            else None)
         self._rows_row = (self._tiles_row + 1 if self._plain_latent
                           else None)
+        self._fetch_row = (len(self._moe_index)
+                           + (self._tiles_row is not None)
+                           + (self._rows_row is not None)
+                           if self._moe_index else None)
         self._device_counts = self._fresh_device_counts()
         self._device_flushed = None
         self._dsa_topk = max((b.index_topk for b in stack.blocks
@@ -1150,9 +1161,11 @@ class GenerationEngine:
         kernel's tiles ``[run, skipped]`` of its full layers, then of its
         window layers (`_tiles_row`); with layers that select nothing, one
         more: the latent rows the decode steps attended ``[full, window]``
-        (`_rows_row`)."""
+        (`_rows_row`); with expert layers, last: ``[experts fetched, expert
+        layer-steps]`` of the decode steps (`_fetch_row`)."""
         rows = (len(self._moe_index) + (self._tiles_row is not None)
-                + (self._rows_row is not None))
+                + (self._rows_row is not None)
+                + (self._fetch_row is not None))
         if not rows:
             return None
         width = self._stack.final._held() + 1 if self._moe_index else 2
@@ -1178,15 +1191,19 @@ class GenerationEngine:
             x = block(cfg, block_params(params, cfg), x, attend_of(li))
         return x
 
-    def _counts_sink(self, state):
+    def _counts_sink(self, state, decode: bool = False):
         """The device counts a program carries — the last of its donated
         arguments ``state`` where the stack counts any — as a one-element
         list (empty without) and the ``counts_to`` that adds an expert
-        layer's assignment counts into it."""
+        layer's assignment counts into it and, in a ``decode`` step, the
+        held experts its grouped products fetched."""
         counts = [state[-1]] if self._device_counts is not None else []
 
-        def counts_to(cfg, new):
+        def counts_to(cfg, new, fetched=None):
             counts[0] = counts[0].at[self._moe_index[cfg.name]].add(new)
+            if decode and fetched is not None:
+                counts[0] = counts[0].at[self._fetch_row, :2].add(
+                    jnp.stack([fetched, jnp.int32(1)]))
 
         return counts, counts_to
 
@@ -1616,7 +1633,7 @@ class GenerationEngine:
             attend_lens = jnp.where(active[:, None],
                                     jnp.minimum(pos2 + 1, cap), 0)
             pool = list(state[:n_pool])
-            counts, counts_to = self._counts_sink(state)
+            counts, counts_to = self._counts_sink(state, decode=True)
             x = self._run_blocks(params, x, step_attend(
                 c, pool, _StepRows(page_tbl, page_of, row_of, attend_lens,
                                    pos_idx, rows(active)), counts_to,
@@ -2625,10 +2642,13 @@ class GenerationEngine:
     def _moe_stats(self, counts) -> Optional[dict]:
         if counts is None or not self._moe_index:
             return None
+        fetched, layer_steps = counts[self._fetch_row, :2].tolist()
         counts = counts[:len(self._moe_index)]
         return {"assignments_held": int(counts[:, :-1].sum()),
                 "assignments_elsewhere": int(counts[:, -1].sum()),
-                "expert_assignments": counts[:, :-1].tolist()}
+                "expert_assignments": counts[:, :-1].tolist(),
+                "experts_fetched": fetched,
+                "expert_layer_steps": layer_steps}
 
     def _tiles_stats(self, counts) -> Optional[dict]:
         """The latent prefill kernel's (tile, head group) steps, process
@@ -2786,6 +2806,10 @@ class GenerationEngine:
                         total.inc(int(row[:-1].sum()), held="true")
                     if row[-1] > 0:
                         total.inc(int(row[-1]), held="false")
+                for family, d in zip(MOE_FETCH_FAMILIES,
+                                     dev_delta[self._fetch_row, :2]):
+                    if d > 0:
+                        reg.counter(family).inc(int(d))
         except Exception as e:
             log.debug("decode count flush failed: %s", e)
 

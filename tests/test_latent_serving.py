@@ -131,7 +131,7 @@ def _share(lp, first, n):
 def test_expert_shares_of_all_chips_plus_one_shared_expert_are_the_layer():
     lp = _moe_params(jax.random.key(0))
     h = jax.random.normal(jax.random.key(1), (12, 16))
-    whole, counts = moe.moe_ffn(h, lp, first=0, top_k=2, scale=2.5)
+    whole, counts, _ = moe.moe_ffn(h, lp, first=0, top_k=2, scale=2.5)
     shared = moe.gated_ffn(h, lp["shared"]["Wg"], lp["shared"]["Wu"],
                            lp["shared"]["Wd"])
     routed = sum(moe.moe_ffn(h, _share(lp, first, 2), first=first, top_k=2,
@@ -148,8 +148,8 @@ def test_expert_layer_is_dropless_when_the_router_sends_every_row_to_one(
     lp["router_bias"] = jnp.zeros(8).at[5].set(10.0).at[1].set(5.0)
     h = jax.random.normal(jax.random.key(3), (24, 16))
     held = _share(lp, 4, 2)                           # holds experts 4, 5
-    y, counts = moe.moe_ffn(h, held, first=4, top_k=2, scale=2.5,
-                            row_tile=row_tile)
+    y, counts, _ = moe.moe_ffn(h, held, first=4, top_k=2, scale=2.5,
+                               row_tile=row_tile)
     # every row chose experts 5 (held) and 1 (elsewhere): all 24 on one
     assert counts.tolist() == [0, 24, 24]
     s = jax.nn.sigmoid(h @ lp["router"])
@@ -166,10 +166,10 @@ def test_expert_layer_in_tiles_is_the_single_pass_and_counts_marked_rows():
     lp = _share(_moe_params(jax.random.key(4)), 2, 4)
     h = jax.random.normal(jax.random.key(5), (20, 16))
     marked = jnp.arange(20) < 13
-    one, c_one = moe.moe_ffn(h, lp, first=2, top_k=2, scale=2.5,
-                             count_rows=marked)
-    tiled, c_tiled = moe.moe_ffn(h, lp, first=2, top_k=2, scale=2.5,
-                                 count_rows=marked, row_tile=8)
+    one, c_one, _ = moe.moe_ffn(h, lp, first=2, top_k=2, scale=2.5,
+                                count_rows=marked)
+    tiled, c_tiled, _ = moe.moe_ffn(h, lp, first=2, top_k=2, scale=2.5,
+                                    count_rows=marked, row_tile=8)
     assert float(jnp.max(jnp.abs(one - tiled))) < 1e-5
     assert c_one.tolist() == c_tiled.tolist()
     assert int(c_one.sum()) == 13 * 2

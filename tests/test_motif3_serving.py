@@ -267,8 +267,8 @@ def test_polynorm_expert_shares_of_all_chips_plus_one_shared_are_the_layer(
     act = moe.PolyNorm(0.5, 0.5, 1e-5)
     lp = _moe_params(jax.random.key(0))
     h = jax.random.normal(jax.random.key(1), (12, 16))
-    whole, counts = moe.moe_ffn(h, lp, first=0, top_k=2, scale=2.0, act=act,
-                                row_tile=row_tile)
+    whole, counts, _ = moe.moe_ffn(h, lp, first=0, top_k=2, scale=2.0,
+                                   act=act, row_tile=row_tile)
     shared = moe.act_ffn(h, lp["shared"], act)
     routed = sum(moe.moe_ffn(
         h, dict(lp, experts={k: w[first:first + 2]

@@ -259,6 +259,28 @@ def test_latent_paged_attn_is_one_kernel_at_motif3_mixed_sat():
         ((32, 80, 512), jnp.float32)]
 
 
+@pytest.mark.parametrize("m, g, k, n, out", [
+    (256, 48, 4096, 1280, jnp.float32),    # motif3: gate and up
+    (256, 48, 1280, 4096, jnp.float32),    # motif3: down
+    (64, 16, 6144, 2048, None),            # glm52: gate and up, bf16 out
+    (64, 16, 2048, 6144, None),            # glm52: down
+], ids=["motif3_up", "motif3_down", "glm52_up", "glm52_down"])
+def test_held_gmm_is_one_kernel_at_the_decode_steps(m, g, k, n, out):
+    """A decode step's grouped expert product (32 or 8 slots x top-8 rows
+    over the held experts' stack) in ONE Mosaic call named `held_gmm`."""
+    from deeplearning4j_tpu.ops import held_expert_matmul as hgm
+
+    exported = lower_for_tpu(
+        lambda x, w, s: hgm.held_gmm(x, w, s, out, interpret=False),
+        sds((m, k), jnp.bfloat16), sds((g, k, n), jnp.bfloat16),
+        sds((g,), jnp.int32))
+    text = exported.mlir_module()
+    assert text.count("@tpu_custom_call") == 1
+    assert "held_gmm" in text
+    assert [(a.shape, a.dtype) for a in exported.out_avals] == [
+        ((m, n), out or jnp.bfloat16)]
+
+
 @pytest.mark.parametrize("m", [1, 8, 256])
 def test_dequant_matmul(m):
     lower_for_tpu(
